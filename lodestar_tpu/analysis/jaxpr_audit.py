@@ -30,12 +30,12 @@ Rules over the (recursively walked) equation graph:
 - ``jaxpr-mxu-precision``        a ``dot_general`` anywhere in an audited
   entry that does not carry the full MXU precision contract: an explicit
   f32 ``preferred_element_type`` AND ``precision=HIGHEST`` on both
-  operands.  The limb representation's exactness proofs assume f32
+  operands, unless both operands are already bf16.  The limb representation's exactness proofs assume f32
   accumulation; without the contract XLA may evaluate f32 dots through
   bf16 operands inside fusions (the pre-MXU-rewrite pathology that once
   banned dots from ops/limbs.py entirely) — silently rounding 16-bit
   digit products.  Every live dot must route through ``limbs._dot_f32``
-  or ``fused_core._m_dot``, which both carry the contract.
+  (f32 operands, HIGHEST) or ``fused_core._m_dot`` (bf16 operands).
 - ``jaxpr-unstable-cache-key``   a Python scalar captured as a traced
   constant (rank-0 const), or a constant set that differs between bucket
   sizes.  Captured scalars make the executable hostage to a Python value
@@ -265,21 +265,26 @@ def all_eqns(closed_jaxpr) -> List:
 # schema tag folded into the fingerprint alongside a hash of this module's
 # own source (so editing the trace inputs or extraction logic invalidates
 # the cache automatically, no manual bump required)
-_CACHE_VERSION = 4  # v4: pallas_call kernel records (pallas_audit layer)
+_CACHE_VERSION = 5  # v5: dot census records bf16 operands; const census by content
 
 
 def _eqn_site(eqn) -> Tuple[str, int]:
     """User-source (file, line) of an equation, '' / 0 when unavailable —
-    same mapping the limb-interval findings use, so the known-bad fixture
-    can pin violations to its ``# VIOLATION`` lines."""
-    try:
-        from jax._src import source_info_util
+    the innermost traceback frame outside jax/jaxlib.  Shared with the
+    limb-interval findings, so the known-bad fixtures can pin violations
+    to their ``# VIOLATION`` lines."""
+    import jax
+    import jaxlib
 
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is not None:
-            return frame.file_name, frame.start_line
-    except Exception:
-        pass
+    tb = getattr(eqn.source_info, "traceback", None)
+    if tb is None:
+        return "", 0
+    skip = tuple(
+        os.path.dirname(m.__file__) + os.sep for m in (jax, jaxlib)
+    )
+    for frame in tb.frames:
+        if not frame.file_name.startswith(skip):
+            return frame.file_name, frame.line_num
     return "", 0
 
 
@@ -296,8 +301,8 @@ def _precision_is_highest(precision) -> bool:
 
 def _dot_general_census(eqns: List) -> List[list]:
     """One row per distinct dot_general call site:
-    [file, line, precision_is_highest, preferred_element_type_name].
-    preferred name is "" when the dot carries none."""
+    [file, line, precision_is_highest, preferred_element_type_name,
+    both_operands_bf16].  preferred name is "" when the dot carries none."""
     rows, seen = [], set()
     for eqn in eqns:
         if eqn.primitive.name != "dot_general":
@@ -314,10 +319,14 @@ def _dot_general_census(eqns: List) -> List[list]:
                 pref_name = np.dtype(pref).name
             except TypeError:
                 pref_name = str(pref)
-        key = (fname, line, prec_ok, pref_name)
+        bf16 = all(
+            str(getattr(v.aval, "dtype", "")) == "bfloat16"
+            for v in eqn.invars
+        )
+        key = (fname, line, prec_ok, pref_name, bf16)
         if key not in seen:
             seen.add(key)
-            rows.append([fname, line, prec_ok, pref_name])
+            rows.append([fname, line, prec_ok, pref_name, bf16])
     return rows
 
 
@@ -597,15 +606,18 @@ def _check_callbacks(name: str, bucket: int, art: dict) -> List[Violation]:
 
 def _check_mxu_precision(name: str, bucket: int, art: dict) -> List[Violation]:
     """jaxpr-mxu-precision: every dot_general in the audited graph must
-    carry the full precision contract (f32 preferred_element_type AND
-    precision=HIGHEST).  Absence is a violation even where the default
-    would happen to be exact — the contract is explicitness, so the
-    exactness argument is local to the call site and a backend/flag change
-    can never reintroduce the bf16-operand pass silently."""
+    carry the full precision contract: an f32 preferred_element_type, and
+    precision=HIGHEST unless both operands are already bf16.  A bf16 x
+    bf16 -> f32 dot (fused_core._m_dot) has nothing left to round, and
+    Mosaic refuses an fp32 contract precision on it.  Absence is a
+    violation even where the default would happen to be exact — the
+    contract is explicitness, so the exactness argument is local to the
+    call site and a backend/flag change can never reintroduce the
+    bf16-operand pass silently."""
     out: List[Violation] = []
-    for fname, line, prec_ok, pref_name in art.get("dot_generals", []):
+    for fname, line, prec_ok, pref_name, bf16 in art.get("dot_generals", []):
         problems = []
-        if not prec_ok:
+        if not (prec_ok or bf16):
             problems.append("precision is not HIGHEST on both operands")
         if pref_name != "float32":
             problems.append(
@@ -630,15 +642,25 @@ def _check_mxu_precision(name: str, bucket: int, art: dict) -> List[Violation]:
 
 
 def _const_census(closed_jaxpr) -> List[list]:
-    """Sorted multiset of [shape, dtype-name] over the trace's constants
-    (JSON-native so cached and fresh censuses compare equal)."""
-    out = []
+    """Sorted set of distinct [shape, dtype-name, content digest] over the
+    trace's constants (JSON-native so cached and fresh censuses compare
+    equal).  Distinct by content: JAX 0.9 makes a fresh constant for every
+    ``jnp.asarray`` of the same numpy table, so a deeper product tree at a
+    larger bucket holds more copies of the same values — not a different
+    constant set."""
+    import numpy as np
+
+    out = set()
     for c in closed_jaxpr.consts:
         shape = getattr(c, "shape", None)
-        shape = [int(s) for s in shape] if shape is not None else ["?"]
+        shape = tuple(int(s) for s in shape) if shape is not None else ("?",)
         dt = getattr(getattr(c, "dtype", None), "name", type(c).__name__)
-        out.append([shape, dt])
-    return sorted(out)
+        try:
+            digest = hashlib.sha1(np.asarray(c).tobytes()).hexdigest()[:16]
+        except Exception:  # noqa: BLE001 — opaque const: shape/dtype only
+            digest = ""
+        out.add((shape, dt, digest))
+    return [[list(shape), dt, digest] for shape, dt, digest in sorted(out)]
 
 
 def _check_cache_keys(

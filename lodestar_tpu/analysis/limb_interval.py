@@ -118,15 +118,9 @@ class _Analyzer:
     # -- source mapping ---------------------------------------------------
     @staticmethod
     def _eqn_site(eqn) -> Tuple[str, int]:
-        try:
-            from jax._src import source_info_util
+        from .jaxpr_audit import _eqn_site
 
-            frame = source_info_util.user_frame(eqn.source_info)
-            if frame is not None:
-                return frame.file_name, frame.start_line
-        except Exception:
-            pass
-        return "", 0
+        return _eqn_site(eqn)
 
     # -- env --------------------------------------------------------------
     @staticmethod
@@ -398,7 +392,7 @@ class _Analyzer:
             return [TOP]
         if name == "dot_general":
             return [self._dot_interval(eqn, ins)]
-        if name in ("pjit", "closed_call", "core_call", "remat",
+        if name in ("jit", "closed_call", "core_call", "remat",
                     "remat_call", "custom_jvp_call", "custom_vjp_call",
                     "custom_jvp_call_jaxpr", "checkpoint"):
             closed = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")

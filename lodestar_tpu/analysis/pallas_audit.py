@@ -422,6 +422,13 @@ def _subtree_has_dma(jaxpr) -> bool:
     return False
 
 
+def _block_dim(b) -> int:
+    """One block-shape entry as an int: a plain int, a ``Blocked`` /
+    ``Element`` wrapper (its ``block_size``), or None / ``Squeezed`` (1)."""
+    size = getattr(b, "block_size", b)
+    return size if isinstance(size, int) else 1
+
+
 def _pallas_record(eqn, axis_sizes: Dict[str, int]) -> dict:
     gm = eqn.params.get("grid_mapping")
     kj = eqn.params.get("jaxpr")
@@ -436,10 +443,9 @@ def _pallas_record(eqn, axis_sizes: Dict[str, int]) -> dict:
             grid = [str(g) for g in gm.grid]
         for bm in getattr(gm, "block_mappings", ()):
             try:
-                sds = bm.array_shape_dtype
+                sds = bm.array_aval
                 blocks.append({
-                    "block": [1 if b is None else int(b)
-                              for b in bm.block_shape],
+                    "block": [_block_dim(b) for b in bm.block_shape],
                     "array": [int(d) for d in sds.shape],
                     "dtype": str(getattr(sds.dtype, "name", sds.dtype)),
                     "space": str(getattr(bm.transformed_block_aval,

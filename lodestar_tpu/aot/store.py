@@ -27,7 +27,7 @@ Key schema (one entry per fully-resolved program identity)::
 - **bucket** — the padded batch size (one program per bucket);
 - **device** — the executor's pinned ordinal (``cpu:2``) or
   ``default``; executables are per-ordinal, exactly like the
-  ``jit(device=d)`` programs they replace;
+  per-device programs they replace;
 - **jax version + ops content-hash** — the PR 4 jaxpr-artifact
   fingerprint scheme one level lower: any change to ``lodestar_tpu/ops``
   or the jax install makes every old entry *skew*, evicted on first
@@ -573,8 +573,10 @@ class AotExecutableStore:
             self.release_writer()
 
     def load(self, entry: str, bucket: int, device: str,
-             topology: Optional[str] = None):
-        """Load one executable, or None.  Every miss class is distinct
+             topology: Optional[str] = None, devices=None):
+        """Load one executable, or None.  ``devices`` are the devices it
+        executes on (default: the first local device — a one-chip
+        program).  Every miss class is distinct
         and journaled: absent (plain miss), checksum/deserialize failure
         (``aot.corrupt`` + quarantine), jax/ops fingerprint mismatch
         (``aot.skew`` + evict).  Never raises; never takes the writer
@@ -607,10 +609,14 @@ class AotExecutableStore:
             return None
         t0 = time.perf_counter()
         try:
+            import jax
             from jax.experimental import serialize_executable as se
 
             blob, in_tree, out_tree = pickle.loads(payload)
-            fn = se.deserialize_and_load(blob, in_tree, out_tree)
+            fn = se.deserialize_and_load(
+                blob, in_tree, out_tree,
+                execution_devices=list(devices or jax.devices()[:1]),
+            )
         except Exception as e:  # noqa: BLE001 — a poisoned pickle/XLA blob
             self._quarantine(key, rec, what="deserialize", error=str(e))
             return None

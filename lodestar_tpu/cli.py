@@ -127,11 +127,6 @@ def build_parser() -> argparse.ArgumentParser:
             "(0 = the largest --bls-buckets entry)",
         )
         p.add_argument(
-            "--bls-cache-dir", default=None,
-            help="persistent XLA compilation cache directory "
-            "(default: $LODESTAR_TPU_JAX_CACHE or repo-local .jax_cache)",
-        )
-        p.add_argument(
             "--bls-aot-store", default=None, metavar="DIR",
             help="durable AOT executable store: fully-compiled XLA "
             "executables persisted across restarts (populate with "
@@ -523,13 +518,22 @@ def _make_verifier(args):
         try:
             import jax
 
-            choice = "tpu" if jax.default_backend() not in ("cpu",) else "native"
+            choice = "tpu" if jax.default_backend() == "tpu" else "native"
         except Exception:
             choice = "native"
     if choice == "tpu":
+        import jax
+
+        backend = jax.default_backend()
+        if backend != "tpu":
+            # an explicit TPU request never degrades to CPU programs (the
+            # fused kernels would run in Pallas interpret mode)
+            raise SystemExit(
+                f"--bls-verifier tpu: JAX found no TPU (backend {backend!r})"
+            )
         from .crypto.bls.tpu_verifier import TpuBlsVerifier, configure_persistent_cache
 
-        configure_persistent_cache(getattr(args, "bls_cache_dir", None))
+        configure_persistent_cache()
         from .aot import configure_aot_store
 
         aot_store = configure_aot_store(getattr(args, "bls_aot_store", None))

@@ -94,35 +94,34 @@ def _sharded_default(n_devices: int) -> bool:
 
 
 _CACHE_CONFIGURED = False
+_REPO = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+)
 
 
-def configure_persistent_cache(
-    cache_dir: Optional[str] = None, min_compile_secs: float = 1.0
-) -> str:
-    """Wire the persistent XLA compilation cache (idempotent).
+def configure_persistent_cache(min_compile_secs: float = 1.0) -> str:
+    """Wire the persistent XLA compilation cache (idempotent) — the one
+    place this repo sets it.
 
     The batched-verify programs cost minutes of TPU compile cold; the
-    cache brings a process restart down to seconds.  Lived in bench.py
-    until round 6 — but the node pays the same cold compile on its first
-    block import, so the wiring belongs to the verifier.  Resolution:
-    explicit arg > LODESTAR_TPU_JAX_CACHE env > repo-local .jax_cache.
-    """
+    cache brings a process restart down to seconds.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read it and its
+    value is left alone; otherwise the cache lives at the fixed
+    ``<repo>/.jax_cache`` (the path is part of the cache key, so it must
+    not move between runs).  Returns the directory in use."""
     global _CACHE_CONFIGURED
-    if cache_dir is None:
-        cache_dir = os.environ.get("LODESTAR_TPU_JAX_CACHE")
-    if cache_dir is None:
-        repo = os.path.dirname(
-            os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-        )
-        cache_dir = os.path.join(repo, ".jax_cache")
-    if not _CACHE_CONFIGURED:
-        import jax
+    import jax
 
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        _REPO, ".jax_cache"
+    )
+    if not _CACHE_CONFIGURED:
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            jax.config.update("jax_compilation_cache_dir", cache_dir)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", min_compile_secs)
         # flight recorder: compile/cache-load durations land in the
         # always-on journal, so a wedged/cold compile is visible in any
-        # diagnostic bundle (the evidence BENCH_r05 died without)
+        # diagnostic bundle
         install_jax_monitoring(JOURNAL)
         # performance observatory: the same monitoring feed also keeps
         # the persistent compile ledger (cold/warm_load/hit per entry ×
@@ -159,6 +158,25 @@ def _entry_name(key) -> str:
 #: unaffected, and ``close()`` keeps its per-instance semantics.
 _PROGRAM_MEMO: dict = {}
 _PROGRAM_MEMO_LOCK = threading.Lock()
+#: kernel function -> its one ``jax.jit`` wrapper.  Sharing the wrapper
+#: shares its trace and lowering: a second executor's program for the
+#: same bucket costs only the backend compile for its own device.
+_JITTED: dict = {}
+
+
+def _fused_split(*args):
+    """The fused split program: device Miller product + subgroup verdict,
+    final exponentiation on the host."""
+    from ...ops import fused_verify as fv
+
+    f, ok = fv.miller_product_fused(*args, interpret=False)
+    return f.a, ok
+
+
+def _fused_full(*args):
+    from ...ops import fused_verify as fv
+
+    return fv.verify_signature_sets_fused(*args, interpret=False)
 
 
 class PendingVerdict:
@@ -334,9 +352,9 @@ class DeviceExecutor:
     scheduler reads for least-loaded placement, and the health record the
     self-healing pool steers around.
 
-    Each executor's programs are plain single-device ``jax.jit(...,
-    device=d)`` compilations — the fused Pallas kernels stay single-chip
-    programs (no Mosaic cross-chip lowering risk), and any bucket size
+    Each executor's programs are single-device compilations whose inputs
+    carry ``SingleDeviceSharding(d)`` — the fused Pallas kernels stay
+    single-chip programs (no Mosaic cross-chip lowering risk), and any bucket size
     runs on any device count because batches are never sharded, only
     placed."""
 
@@ -621,7 +639,8 @@ class TpuBlsVerifier:
     def _aot_load_mesh(self, bucket: int):
         """AOT-store lookup for the mesh program (mesh{k}-keyed)."""
         return self._aot_load_program(
-            self._mesh_entry_name(), bucket, self._mesh_ex.name
+            self._mesh_entry_name(), bucket, self._mesh_ex.name,
+            self.devices,
         )
 
     def _mesh_fn(self, n: int):
@@ -656,12 +675,10 @@ class TpuBlsVerifier:
                 )
                 kernel = factory(mesh, fused=fused,
                                  combine=self.sharded_combine)
+                fn = jax.jit(kernel).lower(*self._abstract_args(n)).compile()
                 store = self._get_aot_store()
                 if store is not None:
-                    fn = jax.jit(kernel).lower(*self._abstract_args(n)).compile()
                     store.save(self._mesh_entry_name(), n, ex.name, fn)
-                else:
-                    fn = jax.jit(kernel)
             with _PROGRAM_MEMO_LOCK:
                 fn = _PROGRAM_MEMO.setdefault(mk, fn)
             ex.compiled[key] = fn
@@ -671,31 +688,41 @@ class TpuBlsVerifier:
         """Python kernel callable for a (n, host_final_exp, fused) key."""
         n, host_final_exp, fused = key
         if fused:
-            from ...ops import fused_verify as fv
-
-            if host_final_exp:
-                def kernel(*args):
-                    f, ok = fv.miller_product_fused(*args, interpret=False)
-                    return f.a, ok
-            else:
-                def kernel(*args):
-                    return fv.verify_signature_sets_fused(*args, interpret=False)
-            return kernel
+            return _fused_split if host_final_exp else _fused_full
         return (
             bv.miller_product_kernel if host_final_exp
             else bv.verify_signature_sets_kernel
         )
 
-    def _jit(self, key, executor: DeviceExecutor):
+    def _device(self, executor: DeviceExecutor):
+        """The device an executor's programs run on: its pinned device,
+        else the first device of the verifier's platform (the default
+        backend when unset)."""
         import jax
 
+        if executor.device is not None:
+            return executor.device
+        return jax.devices(self.platform)[0]
+
+    def _compile(self, key, executor: DeviceExecutor):
+        """Lower + compile one program for the executor's own device.  The
+        abstract inputs carry a SingleDeviceSharding, so the executable
+        (and every numpy batch it is later called with) lands on that
+        chip, not on device 0."""
+        import jax
+        from jax.sharding import SingleDeviceSharding
+
+        sharding = SingleDeviceSharding(self._device(executor))
+        args = [
+            jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+            for a in self._abstract_args(key[0])
+        ]
         kernel = self._kernel(key)
-        device = executor.device
-        if device is None and self.platform is not None:
-            device = jax.devices(self.platform)[0]
-        if device is not None:
-            return jax.jit(kernel, device=device)
-        return jax.jit(kernel)
+        with _PROGRAM_MEMO_LOCK:
+            jitted = _JITTED.get(kernel)
+            if jitted is None:
+                jitted = _JITTED[kernel] = jax.jit(kernel)
+        return jitted.lower(*args).compile()
 
     def _memo_key(self, key, executor: DeviceExecutor):
         """Device identity for the process-level memo: a pinned executor
@@ -716,7 +743,8 @@ class TpuBlsVerifier:
             store.configure()
         return store if store.enabled else None
 
-    def _aot_load_program(self, entry: str, bucket: int, device: str):
+    def _aot_load_program(self, entry: str, bucket: int, device: str,
+                          devices):
         """One store lookup: a hit is ledgered as the ``aot_load`` kind
         (flagging the enclosing attribution window when dispatch owns
         one, recording directly from warmup otherwise).  Shared by the
@@ -727,7 +755,7 @@ class TpuBlsVerifier:
         if store is None:
             return None
         t0 = time.perf_counter()
-        fn = store.load(entry, bucket, device)
+        fn = store.load(entry, bucket, device, devices=devices)
         if fn is not None:
             COMPILE_LEDGER.note_aot_load(
                 time.perf_counter() - t0, entry=entry, bucket=bucket,
@@ -737,7 +765,8 @@ class TpuBlsVerifier:
 
     def _aot_load(self, key, bucket: int, ex: DeviceExecutor):
         """Per-device store lookup for a (n, host_final_exp, fused) key."""
-        return self._aot_load_program(_entry_name(key), bucket, ex.name)
+        return self._aot_load_program(_entry_name(key), bucket, ex.name,
+                                      [self._device(ex)])
 
     def _aot_save(self, key, bucket: int, ex: DeviceExecutor, compiled) -> None:
         """Best-effort persist of a freshly-compiled executable (the
@@ -768,15 +797,8 @@ class TpuBlsVerifier:
                         f"load-only verifier: no stored executable for "
                         f"{_entry_name(key)} bucket {n} on {ex.name}"
                     )
-                store = self._get_aot_store()
-                if store is not None:
-                    # store enabled: compile AOT (same cost — the call
-                    # would compile anyway) so the executable is a real
-                    # Compiled we can serialize for the next process
-                    fn = self._jit(key, ex).lower(*self._abstract_args(n)).compile()
-                    self._aot_save(key, n, ex, fn)
-                else:
-                    fn = self._jit(key, ex)
+                fn = self._compile(key, ex)
+                self._aot_save(key, n, ex, fn)
             with _PROGRAM_MEMO_LOCK:
                 fn = _PROGRAM_MEMO.setdefault(mk, fn)
             ex.compiled[key] = fn
@@ -1121,9 +1143,7 @@ class TpuBlsVerifier:
                     with COMPILE_LEDGER.attribute(
                         _entry_name(key), bucket=b, device=ex.name
                     ):
-                        ex.compiled[key] = self._jit(key, ex).lower(
-                            *self._abstract_args(b)
-                        ).compile()
+                        ex.compiled[key] = self._compile(key, ex)
                     with _PROGRAM_MEMO_LOCK:
                         _PROGRAM_MEMO[mk] = ex.compiled[key]
                     # persist for the NEXT process (best-effort; the

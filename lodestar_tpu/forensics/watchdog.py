@@ -11,7 +11,7 @@ a deadline.
 
 A stall is the silent failure mode of an asynchronous device pipeline:
 jax dispatch returns immediately, so a wedged Mosaic program (or a hung
-device tunnel) produces no exception anywhere — the verdict simply
+device) produces no exception anywhere — the verdict simply
 never resolves and the pool's flusher blocks forever.  The watchdog
 turns that silence into evidence: a journal ERROR event, a
 ``lodestar_bls_watchdog_stalls_total{device}`` increment, and one

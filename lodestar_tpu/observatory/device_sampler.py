@@ -84,6 +84,7 @@ class DeviceSampler:
         self.ticks = 0
         self.work_seconds = 0.0  # sampler's own wall time, summed per tick
         self._started_at: Optional[float] = None
+        self._stopped_at: Optional[float] = None
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
 
@@ -190,11 +191,12 @@ class DeviceSampler:
             return round(sum(wins) / len(wins), 4) if wins else None
 
     def overhead_ratio(self) -> Optional[float]:
-        """Sampler work seconds / elapsed wall seconds since start() —
-        the measured cost of leaving the sampler on."""
+        """Sampler work seconds / elapsed wall seconds since start() (up
+        to stop(), once stopped) — the measured cost of leaving the
+        sampler on."""
         if self._started_at is None:
             return None
-        elapsed = time.monotonic() - self._started_at
+        elapsed = (self._stopped_at or time.monotonic()) - self._started_at
         return round(self.work_seconds / elapsed, 6) if elapsed > 0 else None
 
     def snapshot(self) -> Dict[str, Any]:
@@ -236,6 +238,7 @@ class DeviceSampler:
             return self
         self._stop.clear()
         self._started_at = time.monotonic()
+        self._stopped_at = None
         self._thread = threading.Thread(
             target=self._run, daemon=True, name="observatory-sampler"
         )
@@ -252,6 +255,7 @@ class DeviceSampler:
         t, self._thread = self._thread, None
         if t is not None:
             t.join(timeout=5)
+            self._stopped_at = time.monotonic()
 
 
 #: process-wide sampler slot (cli wires one in; None until then)

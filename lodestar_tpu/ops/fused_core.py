@@ -65,9 +65,10 @@ from .pallas_tower import (
 # configuration
 # ---------------------------------------------------------------------------
 
-BLK = 512  # grid block rows: one Mosaic compile per kernel, any batch size
-# (512 rows ~ 13 MB scoped VMEM in the mul kernels — close to but under the
-# 16 MB limit; halves the per-block constant DMA vs 256)
+BLK = 128  # grid block rows: one Mosaic compile per kernel, any batch size
+# (Mosaic compile time grows faster than linearly in the block: for v5e,
+# _fq2pow16mul_k takes ~123 s to compile at 512 rows and ~10 s at 128,
+# which is what keeps a cold warmup of every bucket inside minutes)
 
 # Hard ceiling for digits entering any kernel: the entry normalization
 # (_fold50 at bound 22) is f32-exact only below 2^22.
@@ -285,15 +286,15 @@ _MC_CONSTS = (
 
 def _m_dot(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
     """bf16 x bf16 -> f32 matmul; exact when both sides are integers
-    <= 2^8 and output sums < 2^24.  Carries the full MXU precision
-    contract (preferred_element_type pins the f32 accumulator; HIGHEST is
-    a no-op for bf16 operands but keeps every live dot_general uniform
-    under the jaxpr-mxu-precision rule)."""
+    <= 2^8 and output sums < 2^24.  preferred_element_type pins the f32
+    accumulator, which is the whole exactness contract for bf16 operands.
+    No ``precision``: Mosaic refuses an fp32 contract precision on bf16
+    operands ("Bad lhs type"), and the jaxpr-mxu-precision rule accepts a
+    bf16 x bf16 -> f32 dot as compliant."""
     return jax.lax.dot_general(
         x.astype(_BF),
         w.astype(_BF),
         (((1,), (0,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
 

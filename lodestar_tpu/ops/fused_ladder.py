@@ -51,8 +51,10 @@ from .fused_points import (
 
 NL = 50
 
-# operand-heavy kernels: halve the block to stay inside scoped VMEM
-LAD_BLK = 256
+# operand-heavy kernels: at 256 rows _lad2_k/_lad3_k overflow v5e's 16 MB
+# scoped VMEM (the (blk, 2500) MXU intermediates) and take 200-330 s each
+# to compile; at 64 rows they fit and compile in ~20 s
+LAD_BLK = 64
 
 
 def _ld(ref):

@@ -41,7 +41,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-from jax.experimental import shard_map as _shard_map
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec
 
@@ -122,8 +121,8 @@ def ring_all_gather(
         out_shape=jax.ShapeDtypeStruct(
             (n_shards,) + f_local.shape, f_local.dtype
         ),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
             pltpu.SemaphoreType.DMA,         # local seed copy
             pltpu.SemaphoreType.DMA((2,)),   # send, double-buffered
@@ -137,16 +136,8 @@ def ring_all_gather(
 def _compiler_params():
     """Collective kernels on real hardware need a shared collective_id so
     Mosaic allocates matching system semaphores across the mesh; the
-    interpret-mode discharge rules ignore it.  Older/newer jax spellings
-    differ, so resolve defensively and fall back to None (interpret mode
-    and tests never need it)."""
-    try:
-        return pltpu.TPUCompilerParams(collective_id=0)
-    except Exception:
-        try:
-            return dict(mosaic=dict(collective_id=0))
-        except Exception:  # pragma: no cover
-            return None
+    interpret-mode discharge rules ignore it."""
+    return pltpu.CompilerParams(collective_id=0)
 
 
 def fq12_combine_ring_dma(
@@ -171,12 +162,12 @@ def ring_combine_fn(mesh: Mesh, *, interpret: bool = False):
     def body(f):
         return fq12_combine_ring_dma(f[0], n, interpret=interpret)
 
-    return _shard_map.shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=PartitionSpec(MESH_AXIS),
         out_specs=PartitionSpec(),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -188,10 +179,10 @@ def all_gather_combine_fn(mesh: Mesh):
     def body(f):
         return fq12_combine_all_gather(f[0])
 
-    return _shard_map.shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=PartitionSpec(MESH_AXIS),
         out_specs=PartitionSpec(),
-        check_rep=False,
+        check_vma=False,
     )

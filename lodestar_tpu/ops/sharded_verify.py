@@ -54,7 +54,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from . import tower as tw
@@ -204,12 +203,12 @@ def _local_body(fused: bool, interpret: bool, combine: str, n_shards: int):
 
 def _wrap(mesh: Mesh, body):
     spec = PartitionSpec(MESH_AXIS)
-    return _shard_map.shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec,) * 7,
         out_specs=(PartitionSpec(), PartitionSpec()),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -262,12 +261,12 @@ def verify_signature_sets_sharded(mesh: Mesh, fused: bool = False,
         return (full_body(*args),)
 
     spec = PartitionSpec(MESH_AXIS)
-    wrapped = _shard_map.shard_map(
+    wrapped = jax.shard_map(
         scalar_body,
         mesh=mesh,
         in_specs=(spec,) * 7,
         out_specs=(PartitionSpec(),),
-        check_rep=False,
+        check_vma=False,
     )
 
     def fn(*args):

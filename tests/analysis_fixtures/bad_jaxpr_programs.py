@@ -21,7 +21,7 @@ def stacked_18_lanes(x):
 def f64_leak(x):
     """float64 escaping the sanctioned f32 limb format (only expressible
     under an x64 context — the test wraps the trace in
-    jax.experimental.enable_x64)."""
+    jax.enable_x64)."""
     return x.astype(jnp.float64) * 2
 
 

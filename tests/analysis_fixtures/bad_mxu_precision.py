@@ -56,8 +56,8 @@ def half_highest(x):
 
 
 def full_contract(x):
-    """GOOD: the complete MXU precision contract, as limbs._dot_f32 and
-    fused_core._m_dot emit it."""
+    """GOOD: the complete MXU precision contract, as limbs._dot_f32
+    emits it."""
     return lax.dot_general(
         x,
         jnp.asarray(_ACC),
@@ -67,13 +67,27 @@ def full_contract(x):
     )
 
 
+def bf16_exact(x):
+    """GOOD: bf16 x bf16 with an f32 accumulator, as fused_core._m_dot
+    emits it — no operand is left to round, so no precision is needed."""
+    return lax.dot_general(x.astype(jnp.bfloat16), jnp.asarray(_ACC, jnp.bfloat16), _DN, preferred_element_type=jnp.float32)
+
+
+def bf16_lhs_only(x):
+    """A bf16 lhs against an f32 rhs without HIGHEST: the f32 side may
+    still be rounded, so the bf16 exemption does not apply."""
+    return lax.dot_general(x.astype(jnp.bfloat16), jnp.asarray(_ACC), _DN, preferred_element_type=jnp.float32)  # VIOLATION
+
+
 BAD_PROGRAMS = [
     (bare_dot, [(4, NLIMBS)]),
     (preferred_only, [(4, NLIMBS)]),
     (highest_only, [(4, NLIMBS)]),
     (half_highest, [(4, NLIMBS)]),
+    (bf16_lhs_only, [(4, NLIMBS)]),
 ]
 
 GOOD_PROGRAMS = [
     (full_contract, [(4, NLIMBS)]),
+    (bf16_exact, [(4, NLIMBS)]),
 ]

@@ -29,8 +29,8 @@ def build():
         return pl.pallas_call(
             _kernel,
             out_shape=jax.ShapeDtypeStruct((16, 128), jnp.float32),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-            out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
             scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
             interpret=True,
         )(x)

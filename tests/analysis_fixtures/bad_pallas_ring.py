@@ -9,7 +9,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
-from jax.experimental import shard_map
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -38,14 +37,14 @@ def build():
         return pl.pallas_call(
             _kernel,
             out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-            out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
             scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
             interpret=True,
         )(x)
 
     mesh = Mesh(np.array(jax.devices()[:N]), (AXIS,))
-    fn = shard_map.shard_map(
+    fn = jax.shard_map(
         inner, mesh=mesh, in_specs=P(AXIS), out_specs=P(AXIS),
-        check_rep=False)
+        check_vma=False)
     return fn, (jax.ShapeDtypeStruct((N * 8, 128), jnp.float32),)

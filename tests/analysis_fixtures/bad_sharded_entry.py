@@ -11,7 +11,6 @@ consulted for fixtures).
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 from lodestar_tpu.ops import limbs as fl
@@ -39,9 +38,9 @@ def make_no_collective_entry(mesh):
         return (jnp.sum(x),)
 
     def fn(x):
-        return _shard_map.shard_map(
+        return jax.shard_map(
             body, mesh=mesh, in_specs=(P(MESH_AXIS),), out_specs=(P(),),
-            check_rep=False,
+            check_vma=False,
         )(x)[0]
 
     return fn
@@ -59,9 +58,9 @@ def make_local_final_exp_entry(mesh):
         return (jnp.sum(g),)
 
     def fn(x):
-        return _shard_map.shard_map(
+        return jax.shard_map(
             body, mesh=mesh, in_specs=(P(MESH_AXIS),), out_specs=(P(),),
-            check_rep=False,
+            check_vma=False,
         )(x)[0]
 
     return fn

@@ -15,7 +15,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import shard_map as sm
 from jax.sharding import PartitionSpec as P
 
 from lodestar_tpu.ops import pallas_ring as pr
@@ -59,9 +58,9 @@ def test_ring_gather_lands_chunks_at_original_index():
     def body(x):
         return pr.ring_all_gather(x[0], 2, interpret=True)
 
-    out = sm.shard_map(
+    out = jax.shard_map(
         body, mesh=mesh, in_specs=P(MESH_AXIS), out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(f)
     assert np.array_equal(np.asarray(out), np.asarray(f))
 

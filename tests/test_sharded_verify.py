@@ -275,7 +275,7 @@ class TestShardedDispatch:
         class FakeStore:
             enabled = True
 
-            def load(self, entry, bucket, device, topology=None):
+            def load(self, entry, bucket, device, topology=None, devices=None):
                 calls.append((entry, bucket, device))
                 return None
 
@@ -546,7 +546,6 @@ class TestCombineOracleEquivalence:
     @pytest.mark.parametrize("combine", ["all_gather", "ring"])
     def test_combine_matches_bigint_oracle(self, combine):
         import jax
-        from jax.experimental import shard_map as sm
         from jax.sharding import PartitionSpec as P
 
         from lodestar_tpu.ops import sharded_verify as sv
@@ -566,8 +565,8 @@ class TestCombineOracleEquivalence:
             return (sv.fq12_combine_all_gather(f),)
 
         fn = jax.jit(
-            sm.shard_map(body, mesh=mesh, in_specs=(P(sv.MESH_AXIS),),
-                         out_specs=(P(),), check_rep=False)
+            jax.shard_map(body, mesh=mesh, in_specs=(P(sv.MESH_AXIS),),
+                          out_specs=(P(),), check_vma=False)
         )
         got = self._canon(fn(arr)[0])
         assert got == self._oracle_comps(expected)

@@ -217,7 +217,7 @@ class TestJaxprAuditor:
 
         from analysis_fixtures import bad_jaxpr_programs as bad
 
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             jx = jax.make_jaxpr(bad.f64_leak)(
                 jax.ShapeDtypeStruct((4, 50), jnp.float32)
             )

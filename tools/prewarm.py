@@ -16,7 +16,7 @@ What one run does:
   runs its ``warmup()``, which walks memo -> AOT store -> persistent
   cache -> compile per (bucket, ordinal) and persists every freshly
   materialized executable back into the store (per-ordinal fan-out: one
-  serialized executable per device, exactly like the ``jit(device=d)``
+  serialized executable per device, exactly like the per-device
   programs they replace);
 - reports per-entry outcomes plus the store's hit/miss/save counters.
 
