@@ -480,10 +480,6 @@ class BlsBatchPool:
                                 else min(batch_deadline, deadline)
                             )
                         if self.metrics:
-                            # deprecated laneless alias kept one release
-                            self.metrics.bls_pool_queue_wait_seconds.observe(
-                                now - t_enq
-                            )
                             self.metrics.bls_queue_wait_seconds.labels(
                                 lane=_lane_name(lane)
                             ).observe(now - t_enq)
@@ -643,8 +639,7 @@ class BlsBatchPool:
 
     def _publish_flush_metrics(self, busy: float, wall: float, sets_done: int = 0) -> None:
         """End-of-flush snapshots: the overlap ratio this flush achieved,
-        the previously-orphaned verifier stage_seconds / pool
-        inflight_peak counters (ISSUE 2 satellite 1), and the north-star
+        the pool's inflight_peak, and the north-star
         throughput of this flush — per chip AND whole-mesh (ISSUE 7
         satellite 2: roadmap item 1's success metric needs the mesh
         headline to exist before the sharded kernel lands)."""
@@ -658,10 +653,6 @@ class BlsBatchPool:
             n_dev = max(1, getattr(self.verifier, "n_devices", 1))
             self.metrics.bls_sets_per_sec_per_chip.set(sets_done / wall / n_dev)
             self.metrics.bls_sets_per_sec_mesh.set(sets_done / wall)
-        stage_seconds = getattr(self.verifier, "stage_seconds", None)
-        if stage_seconds:
-            for stage, secs in stage_seconds.items():
-                self.metrics.bls_verifier_stage_seconds.labels(stage=stage).set(secs)
         # drop visibility: ring-buffer evictions would otherwise be the
         # one thing the observability stack is silent about
         self.metrics.tracing_spans_dropped_total.set(TRACER.dropped)

@@ -251,13 +251,13 @@ class PendingVerdict:
             return all(results)
         if self._f is not None:
             return self._verifier._host_final_exp_verdict(self._f, self._ok)
-        # fused on-device verdict: the bool() read is the sync; the
-        # span plays the final_exp role on this path's timeline
+        # fused on-device verdict: the bool() read is the sync, a pure
+        # device wait with no host work behind it
         t0_ns = TRACER.now()
         value = bool(self._out)
         if TRACER.enabled:
             TRACER.add_span(
-                "bls.final_exp", "bls", t0_ns,
+                "bls.readback", "bls", t0_ns,
                 cid=current_batch_id(), on_device=True,
             )
         return value
@@ -408,7 +408,8 @@ class TpuBlsVerifier:
     ``metrics``: optional Metrics registry; per-stage histograms
     (bls_pool_pack_seconds / bls_pool_dispatch_seconds is pool-side /
     bls_pool_final_exp_seconds) are observed when present.  The plain
-    ``stage_seconds`` dict accumulates the same figures unconditionally.
+    ``stage_seconds`` dict accumulates the same figures unconditionally
+    (pack / dispatch / readback / final_exp / warmup).
     """
 
     def __init__(
@@ -511,7 +512,8 @@ class TpuBlsVerifier:
         self.native_fallbacks = 0    # verdicts served by the host-native tier
         self.sharded_batches = 0     # batches dispatched as one mesh program
         self.sharded_fallbacks = 0   # sharded-tier hops down to the pool tier
-        self.stage_seconds = {"pack": 0.0, "dispatch": 0.0, "final_exp": 0.0, "warmup": 0.0}
+        self.stage_seconds = {"pack": 0.0, "dispatch": 0.0, "readback": 0.0,
+                              "final_exp": 0.0, "warmup": 0.0}
         # rate limit for the automatic diagnostic bundles the self-healing
         # events write (one per reason per cooldown — a persistently sick
         # fleet must not fill the scratch disk)
@@ -1289,19 +1291,42 @@ class TpuBlsVerifier:
         t.start()
         return t
 
+    def _observe_stage(self, stage: str, dt: float) -> None:
+        with self._stats_lock:
+            self.stage_seconds[stage] += dt
+        if self.metrics:
+            self.metrics.bls_verifier_stage_duration_seconds.labels(
+                stage=stage
+            ).observe(dt)
+
     def _host_final_exp_verdict(self, f_digits, ok) -> bool:
         """Reduce the device Miller product to canonical bytes and run the
         final exponentiation + is-one check on the host (native C first,
-        bigint oracle as fallback).  The ``bool(ok)`` read is the device
-        sync point, so this stage's timing covers readback + final exp."""
+        bigint oracle as fallback).
+
+        Two stages: ``readback`` is the ``bool(ok)`` read (the device sync
+        point) plus the digit copy to the host; ``final_exp`` is the limb
+        conversion and the exponentiation, and runs (and is recorded) only
+        when the device's subgroup checks passed."""
+        t0 = time.perf_counter()
+        t0_ns = TRACER.now()
+        live = False
+        try:
+            live = bool(ok)
+            if live:
+                f = np.asarray(f_digits, dtype=np.float64)  # (6, 2, 50)
+        finally:
+            self._observe_stage("readback", time.perf_counter() - t0)
+            if TRACER.enabled:
+                TRACER.add_span("bls.readback", "bls", t0_ns,
+                                cid=current_batch_id(), ok=live)
+        if not live:
+            return False
         t0 = time.perf_counter()
         t0_ns = TRACER.now()
         try:
-            if not bool(ok):
-                return False
             with self._stats_lock:
                 self.host_final_exps += 1
-            f = np.asarray(f_digits, dtype=np.float64)  # (6, 2, 50)
             comps = []
             for i in range(6):
                 for j in range(2):
@@ -1323,13 +1348,9 @@ class TpuBlsVerifier:
             return final_exponentiation(fq12).is_one()
         finally:
             dt = time.perf_counter() - t0
-            with self._stats_lock:
-                self.stage_seconds["final_exp"] += dt
+            self._observe_stage("final_exp", dt)
             if self.metrics:
                 self.metrics.bls_pool_final_exp_seconds.observe(dt)
-                self.metrics.bls_verifier_stage_duration_seconds.labels(
-                    stage="final_exp"
-                ).observe(dt)
             if TRACER.enabled:
                 TRACER.add_span("bls.final_exp", "bls", t0_ns,
                                 cid=current_batch_id())
@@ -1699,7 +1720,15 @@ class TpuBlsVerifier:
         pubkey bytes, or an aggregate's concatenated member bytes) and the
         misses convert jacobian->affine through ONE Montgomery batch
         inversion per family (curve.to_affine_batch) instead of one bigint
-        inversion per set."""
+        inversion per set.
+
+        Each part runs as one pass and, when tracing, leaves a child span
+        of ``bls.pack`` under the batch's cid: ``bls.pack.pubkeys``
+        (cache lookups and G1 aggregation of the misses),
+        ``bls.pack.signatures`` (cache lookups and G2 decompression),
+        ``bls.pack.affine``, ``bls.pack.limbs`` and
+        ``bls.pack.hash_to_field``; the RLC bits and the mask are
+        ``bls.pack``'s own time."""
         t0 = time.perf_counter()
         t0_ns = TRACER.now()
         hits = misses = 0
@@ -1711,11 +1740,12 @@ class TpuBlsVerifier:
             sig_vals: List[Optional[tuple]] = [None] * n
             pk_miss: List[tuple] = []   # (index, jacobian point, cache key | None)
             sig_miss: List[tuple] = []
-            msgs: List[bytes] = []
+            # -- pubkeys: single keys cache by their compressed bytes,
+            #    aggregates by the concatenation of member bytes (the same
+            #    committee re-aggregates every epoch) ----------------------
+            t_ns = TRACER.now()
+            members = 0
             for i, s in enumerate(sets):
-                # -- pubkey: single keys cache by their compressed bytes,
-                #    aggregates by the concatenation of member bytes (the
-                #    same committee re-aggregates every epoch) -------------
                 if isinstance(s, SingleSignatureSet):
                     pk_key = s.pubkey._raw
                     if pk_key is not None:
@@ -1730,11 +1760,18 @@ class TpuBlsVerifier:
                     hits += 1
                 else:
                     misses += 1
+                    if not isinstance(s, SingleSignatureSet):
+                        members += len(s.pubkeys)
                     pk = get_aggregated_pubkey(s)
                     if pk.is_infinity():
                         return self._pack_reject()
                     pk_miss.append((i, pk.point, pk_key))
-                # -- signature --------------------------------------------
+            if TRACER.enabled:
+                TRACER.add_span("bls.pack.pubkeys", "bls", t_ns,
+                                cid=current_batch_id(), members=members)
+            # -- signatures ----------------------------------------------
+            t_ns = TRACER.now()
+            for i, s in enumerate(sets):
                 raw = s.signature
                 hit = cache.get(b"S" + raw) if cache.enabled else None
                 if hit is not None:
@@ -1751,8 +1788,12 @@ class TpuBlsVerifier:
                     if sig_pt.is_infinity():
                         return self._pack_reject()
                     sig_miss.append((i, sig_pt, b"S" + raw))
-                msgs.append(s.signing_root)
+            if TRACER.enabled:
+                TRACER.add_span("bls.pack.signatures", "bls", t_ns,
+                                cid=current_batch_id(),
+                                decompressed=len(sig_miss))
             # one Montgomery batch inversion per coordinate family
+            t_ns = TRACER.now()
             for aff, missed in (
                 (to_affine_batch([pt for _, pt, _ in pk_miss]), pk_miss),
                 (to_affine_batch([pt for _, pt, _ in sig_miss]), sig_miss),
@@ -1767,6 +1808,10 @@ class TpuBlsVerifier:
                         sig_vals[i] = val
                     if key is not None:
                         cache.put(key, val)
+            if TRACER.enabled:
+                TRACER.add_span("bls.pack.affine", "bls", t_ns,
+                                cid=current_batch_id())
+            t_ns = TRACER.now()
             pk_ints: List[int] = [c for v in pk_vals for c in v]
             sig_ints: List[int] = [c for v in sig_vals for c in v]
             # one batched byte->limb conversion per family
@@ -1778,13 +1823,21 @@ class TpuBlsVerifier:
             sig_y = np.zeros((b, 2, fl.NLIMBS), dtype=fl.NP_DTYPE)
             pk_x[:n], pk_y[:n] = pk_limbs[:, 0], pk_limbs[:, 1]
             sig_x[:n], sig_y[:n] = sig_limbs[:, 0], sig_limbs[:, 1]
+            msgs = [s.signing_root for s in sets]
             # padding lanes: copy lane 0 (valid coords keep the algebra
             # non-degenerate; the mask keeps them out of the verdict)
             if b > n:
                 pk_x[n:], pk_y[n:] = pk_x[0], pk_y[0]
                 sig_x[n:], sig_y[n:] = sig_x[0], sig_y[0]
                 msgs += [b""] * (b - n)
+            if TRACER.enabled:
+                TRACER.add_span("bls.pack.limbs", "bls", t_ns,
+                                cid=current_batch_id())
+            t_ns = TRACER.now()
             msg_u = htc.hash_to_field_limbs(msgs)
+            if TRACER.enabled:
+                TRACER.add_span("bls.pack.hash_to_field", "bls", t_ns,
+                                cid=current_batch_id())
             # fresh odd 64-bit RLC coefficients, expanded to bit planes in
             # one vectorized shift instead of a per-(coeff, bit) Python loop
             coeffs = np.frombuffer(secrets.token_bytes(8 * b), dtype=np.uint64)

@@ -119,7 +119,9 @@ class Metrics:
         )
         self.bls_pool_final_exp_seconds = r.histogram(
             "lodestar_bls_pool_final_exp_seconds",
-            "device readback + host final exponentiation per dispatch",
+            "host final exponentiation per dispatch (limb conversion + "
+            "C final exp; the device readback is the readback stage of "
+            "bls_verifier_stage_duration_seconds)",
             buckets=(0.0005, 0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1),
         )
         self.bls_pool_inflight_depth = r.gauge(
@@ -127,13 +129,6 @@ class Metrics:
             "merged batches concurrently in flight on the device pipeline",
         )
         # span-derived pipeline observability (docs/observability.md)
-        self.bls_pool_queue_wait_seconds = r.histogram(
-            "lodestar_bls_pool_queue_wait_seconds",
-            "DEPRECATED (one release, round 11): laneless queue-wait "
-            "histogram on ad-hoc buckets — use bls_queue_wait_seconds "
-            "(per lane, SLO-ladder buckets)",
-            buckets=(0.0005, 0.001, 0.005, 0.01, 0.02, 0.05, 0.1, 0.5, 1),
-        )
         self.bls_pool_overlap_ratio = r.gauge(
             "lodestar_bls_pool_overlap_ratio",
             "sum of in-flight batch busy time / flush wall time "
@@ -142,13 +137,6 @@ class Metrics:
         self.bls_pool_inflight_peak = r.gauge(
             "lodestar_bls_pool_inflight_peak",
             "highest in-flight depth the pipeline has reached",
-        )
-        self.bls_verifier_stage_seconds = r.gauge(
-            "lodestar_bls_verifier_stage_seconds",
-            "DEPRECATED (one release, round 11): cumulative wall seconds "
-            "per stage as a last-write gauge snapshot at flush — use the "
-            "per-dispatch histogram bls_verifier_stage_duration_seconds",
-            labels=("stage",),
         )
         # multi-chip executor pool + pack-side caches (round 8)
         self.bls_device_inflight = r.gauge(
@@ -204,8 +192,7 @@ class Metrics:
             "lodestar_bls_queue_wait_seconds",
             "per-job pool buffer wait by QoS lane, on the firehose SLO "
             "bucket ladder — p50/p99 here, in firehose reports, and in "
-            "bls.queue_wait spans agree to one bucket "
-            "(replaces the deprecated laneless bls_pool_queue_wait_seconds)",
+            "bls.queue_wait spans agree to one bucket",
             buckets=SLO_LATENCY_BUCKETS_S,
             labels=("lane",),
         )
@@ -219,9 +206,9 @@ class Metrics:
         )
         self.bls_verifier_stage_duration_seconds = r.histogram(
             "lodestar_bls_verifier_stage_duration_seconds",
-            "per-call verifier stage duration (pack/dispatch/final_exp) — "
-            "the histogram the deprecated bls_verifier_stage_seconds gauge "
-            "snapshot could never be",
+            "per-call verifier stage duration: pack, dispatch (the "
+            "enqueue), readback (device sync + copy of the Miller "
+            "product), final_exp (host final exponentiation alone)",
             buckets=(0.0005, 0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1, 5),
             labels=("stage",),
         )

@@ -9,7 +9,8 @@ This module turns both into answers:
 - :func:`attribute_spans` — decompose every merged batch's end-to-end
   latency into the six-way split ``queue / pack / device_compute /
   collective_combine / final_exp / pipeline_bubble``.  Host spans alone
-  give queue/pack/final_exp and the dispatch wall; merged device events
+  give queue/pack/final_exp and the device window (``bls.dispatch``
+  through the end of ``bls.readback``); merged device events
   (clock-remapped by xprof) refine the dispatch wall into real device
   compute vs collective communication; whatever the stages cannot
   explain is the pipeline bubble, never silently dropped.
@@ -64,7 +65,10 @@ COLLECTIVE_RE = re.compile(
 _SPAN_TO_STAGE = {
     "bls.queue_wait": "queue",
     "bls.pack": "pack",
-    "bls.dispatch": "device_compute",  # refined by device events when present
+    # the device window: from the enqueue to the end of the readback wait,
+    # refined by device events when present
+    "bls.dispatch": "device_compute",
+    "bls.readback": "device_compute",
     "bls.final_exp": "final_exp",
 }
 
@@ -185,8 +189,9 @@ def attribute_spans(
             stage = _SPAN_TO_STAGE.get(s["name"])
             if stage and stage != "device_compute":
                 stages[stage] = max(stages[stage], s["dur_us"] / 1e6)
+        device_spans = dispatch + [s for s in spans if s["name"] == "bls.readback"]
         d0 = min(s["ts_us"] for s in dispatch)
-        d1 = max(s["ts_us"] + s["dur_us"] for s in dispatch)
+        d1 = max(s["ts_us"] + s["dur_us"] for s in device_spans)
         in_window_comm = [
             c for iv in dev_comm if (c := _clip(iv[0], iv[1], d0, d1))
         ]
@@ -196,8 +201,8 @@ def attribute_spans(
         combine_s = _union_us(in_window_comm) / 1e6
         compute_s = _union_us(in_window_compute) / 1e6
         if combine_s + compute_s <= 0.0:
-            # no device evidence: the host-side dispatch wall IS the
-            # device estimate (it includes the readback wait)
+            # no device evidence: the host-side window from the enqueue
+            # to the end of the readback wait IS the device estimate
             compute_s = (d1 - d0) / 1e6
         stages["device_compute"] = compute_s
         stages["collective_combine"] = combine_s

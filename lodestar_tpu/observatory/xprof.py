@@ -4,11 +4,14 @@
 ``jax.profiler`` answers what the devices did; the SpanTracer answers
 what the node *meant* — but they live in different files on different
 clocks.  :class:`ProfileCapture` brackets N dispatch flushes with a
-``jax.profiler`` trace, parses the trace-viewer dump it leaves behind
-(stdlib-only: the ``.trace.json.gz`` under ``plugins/profile``), remaps
-the profiler timebase onto the tracer's monotonic clock, and merges both
-into ONE Perfetto-loadable Chrome trace: host spans at pid 0 (the
-existing ``tracing.export`` convention), device processes at
+``jax.profiler`` trace, reads the ``.xplane.pb`` it leaves under
+``plugins/profile`` (``jax.profiler.ProfileData``: the ``XLA Ops`` line
+of every device plane, with each operation's name scope where its stats
+carry one), puts the profiler timebase on the tracer's monotonic clock
+through one annotation entered at ``start_trace`` whose monotonic time
+is recorded as it is entered, and merges both into ONE
+Perfetto-loadable Chrome trace: host spans at pid 0 (the existing
+``tracing.export`` convention), device processes at
 ``DEVICE_PID_BASE + index``, and the clock mapping recorded in
 ``otherData.device_clock`` so ``tools/check_trace.py --require-device``
 can audit the merge.
@@ -26,7 +29,7 @@ Windows are armed three ways (all land here):
 The capture never initializes a JAX backend on its own: the default
 start/stop functions import jax lazily and only run once a window is
 actually armed, and tests inject fake start/stop functions that write
-synthetic trace-viewer fixtures — zero compiles.
+synthetic ``.xplane.pb`` fixtures — zero compiles.
 
 ``BlsBatchPool._flush`` calls :func:`notify_flush` (module level, no-op
 until :func:`configure_capture` wires a capture) at the end of every
@@ -38,7 +41,6 @@ thread so the event loop never blocks on profile IO.
 from __future__ import annotations
 
 import glob
-import gzip
 import json
 import os
 import tempfile
@@ -61,51 +63,103 @@ DEVICE_PID_BASE = 1000
 DEFAULT_TOLERANCE_US = 50_000.0
 
 
-# -- trace-viewer ingestion (stdlib only) -----------------------------------
+#: the annotation entered right after ``start_trace``: its monotonic
+#: time, recorded as it is entered, anchors the profiler's clock
+CLOCK_MARK = "lodestar.xprof.clock"
+
+#: the line of a device plane whose events are the device's operations
+OPS_LINE = "XLA Ops"
+
+#: event stats that carry an operation's name scope (its ``op_name``
+#: metadata, e.g. ``jit(_fused_split)/miller_loop/while``)
+SCOPE_STATS = ("tf_op", "name_scope", "op_name")
 
 
-def find_trace_files(profile_dir: str) -> List[str]:
-    """The trace-viewer dumps under a ``jax.profiler`` output dir —
-    ``<dir>/plugins/profile/<run>/<host>.trace.json.gz`` per the
-    TensorBoard layout, with a recursive fallback for layout drift."""
-    pats = [
-        os.path.join(profile_dir, "plugins", "profile", "*", "*.trace.json.gz"),
-        os.path.join(profile_dir, "plugins", "profile", "*", "*.trace.json"),
-    ]
-    out: List[str] = []
-    for pat in pats:
-        out.extend(glob.glob(pat))
+# -- profile ingestion (.xplane.pb) -----------------------------------------
+
+
+def find_profile_files(profile_dir: str) -> List[str]:
+    """The ``.xplane.pb`` files under a ``jax.profiler`` output dir —
+    ``<dir>/plugins/profile/<run>/<host>.xplane.pb`` per the TensorBoard
+    layout, with a recursive fallback for layout drift."""
+    out = glob.glob(
+        os.path.join(profile_dir, "plugins", "profile", "*", "*.xplane.pb")
+    )
     if not out:
-        for ext in ("*.trace.json.gz", "*.trace.json"):
-            out.extend(
-                glob.glob(os.path.join(profile_dir, "**", ext), recursive=True)
-            )
+        out = glob.glob(os.path.join(profile_dir, "**", "*.xplane.pb"),
+                        recursive=True)
     return sorted(set(out))
 
 
-def load_trace_events(path: str) -> List[Dict[str, Any]]:
-    """traceEvents of one trace-viewer dump (gzip or plain JSON)."""
-    opener = gzip.open if path.endswith(".gz") else open
-    with opener(path, "rt") as f:  # type: ignore[operator]
-        doc = json.load(f)
-    events = doc.get("traceEvents", doc) if isinstance(doc, dict) else doc
-    return [ev for ev in events if isinstance(ev, dict)]
+def op_scope(event) -> Optional[str]:
+    """The name scope a device operation's stats carry, if any."""
+    for key, value in event.stats:
+        if key in SCOPE_STATS and isinstance(value, str) and value:
+            return value
+    return None
+
+
+def xspace_events(data, pid_base: int = 0) -> Tuple[List[Dict[str, Any]], Optional[float]]:
+    """(device events in the merge's form, the clock mark's profiler time
+    in µs or None) of one ``ProfileData``.  Each device plane becomes a
+    process (``pid_base`` + its index, metadata-named); its ``XLA Ops``
+    events keep their HLO instruction name and, in ``args.scope``, the
+    op's name scope."""
+    events: List[Dict[str, Any]] = []
+    mark_us: Optional[float] = None
+    pid = pid_base
+    for plane in data.planes:
+        if plane.name.startswith("/host"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name == CLOCK_MARK:
+                        mark_us = ev.start_ns / 1e3
+            continue
+        if not plane.name.startswith("/device"):
+            continue
+        events.append({"name": "process_name", "ph": "M", "pid": pid,
+                       "tid": 0, "args": {"name": plane.name}})
+        for tid, line in enumerate(plane.lines):
+            if line.name != OPS_LINE:
+                continue
+            for ev in line.events:
+                out: Dict[str, Any] = {
+                    "name": ev.name.split(" = ", 1)[0],
+                    "cat": "device", "ph": "X", "pid": pid, "tid": tid,
+                    "ts": ev.start_ns / 1e3, "dur": ev.duration_ns / 1e3,
+                }
+                scope = op_scope(ev)
+                if scope:
+                    out["args"] = {"scope": scope}
+                events.append(out)
+        pid += 1
+    return events, mark_us
 
 
 def parse_profile_dir(profile_dir: str) -> Dict[str, Any]:
-    """Every device event under ``profile_dir``: ``{"events", "files"}``
-    (files that fail to parse are skipped and named, not fatal —
-    partial device evidence beats none)."""
+    """Every device event under ``profile_dir``: ``{"events", "files",
+    "skipped", "mark_us"}`` (files that fail to parse are skipped and
+    named, not fatal — partial device evidence beats none)."""
+    from jax.profiler import ProfileData
+
     events: List[Dict[str, Any]] = []
     files: List[str] = []
     skipped: List[str] = []
-    for path in find_trace_files(profile_dir):
+    mark_us: Optional[float] = None
+    pid_base = 0
+    for path in find_profile_files(profile_dir):
         try:
-            events.extend(load_trace_events(path))
-            files.append(path)
-        except (OSError, ValueError):
+            evs, mark = xspace_events(ProfileData.from_file(path), pid_base)
+        except Exception:  # noqa: BLE001 — a torn or foreign file
             skipped.append(path)
-    return {"events": events, "files": files, "skipped": skipped}
+            continue
+        events.extend(evs)
+        files.append(path)
+        pid_base += sum(1 for e in evs if e["ph"] == "M")
+        if mark is not None:
+            mark_us = mark
+    return {"events": events, "files": files, "skipped": skipped,
+            "mark_us": mark_us}
 
 
 # -- clock mapping ----------------------------------------------------------
@@ -114,29 +168,28 @@ def parse_profile_dir(profile_dir: str) -> Dict[str, Any]:
 class ClockMap:
     """profiler-timebase µs -> tracer monotonic µs.
 
-    The anchor: the earliest profiler event is assumed to start at the
-    host monotonic instant recorded right after ``start_trace``
-    returned.  ``skew_us`` is how far the remapped device events overrun
-    the host-side capture window ``[host_start, host_stop]`` — a bounded
-    anchor error on a healthy capture, and the failure signal
-    ``check_trace --require-device`` gates on."""
+    The anchor: the clock mark, an annotation entered right after
+    ``start_trace``, whose monotonic instant was recorded as it was
+    entered and whose profiler time the host plane holds.  ``skew_us``
+    is how far the remapped device events overrun the host-side capture
+    window ``[host_start, host_stop]`` — zero on a healthy capture, and
+    the failure signal ``check_trace --require-device`` gates on."""
 
-    def __init__(self, host_start_ns: int, host_stop_ns: int,
-                 device_min_us: float, device_max_us: float):
+    def __init__(self, mark_ns: int, mark_us: float, host_start_ns: int,
+                 host_stop_ns: int, device_min_us: float, device_max_us: float):
         self.host_start_us = host_start_ns / 1e3
         self.host_stop_us = host_stop_ns / 1e3
         self.device_min_us = device_min_us
         self.device_max_us = device_max_us
-        self.offset_us = self.host_start_us - device_min_us
+        self.offset_us = mark_ns / 1e3 - mark_us
 
     def remap(self, ts_us: float) -> float:
         return ts_us + self.offset_us
 
     @property
     def skew_us(self) -> float:
-        device_span = self.device_max_us - self.device_min_us
-        host_span = self.host_stop_us - self.host_start_us
-        return max(0.0, device_span - host_span)
+        return max(0.0, self.host_start_us - self.remap(self.device_min_us),
+                   self.remap(self.device_max_us) - self.host_stop_us)
 
 
 # -- merge ------------------------------------------------------------------
@@ -221,10 +274,19 @@ def merge_host_device(
 # -- the capture controller -------------------------------------------------
 
 
-def _default_start(profile_dir: str) -> None:
+def _default_start(profile_dir: str) -> int:
+    """Start the profiler and enter the clock mark; returns the mark's
+    monotonic ns."""
     import jax
 
-    jax.profiler.start_trace(profile_dir)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1  # keeps user annotations: the clock mark
+    jax.profiler.start_trace(profile_dir, profiler_options=opts)
+    mark_ns = time.monotonic_ns()
+    with jax.profiler.TraceAnnotation(CLOCK_MARK):
+        pass
+    return mark_ns
 
 
 def _default_stop() -> None:
@@ -237,10 +299,12 @@ class ProfileCapture:
     """Arm/capture/merge controller for on-demand profile windows.
 
     ``start_fn(dir)`` / ``stop_fn()`` default to ``jax.profiler``; tests
-    and stub pools inject fakes that write synthetic trace-viewer
-    fixtures.  All state transitions are lock-guarded: ``notify_flush``
-    runs on the event loop, ``_finish`` on a daemon thread, REST/CLI
-    arming on arbitrary threads."""
+    and stub pools inject fakes that write synthetic ``.xplane.pb``
+    fixtures.  ``start_fn`` returns the clock mark's monotonic ns (None:
+    the mark is taken to be entered as ``start_fn`` returns).  All state
+    transitions are lock-guarded: ``notify_flush`` runs on the event
+    loop, ``_finish`` on a daemon thread, REST/CLI arming on arbitrary
+    threads."""
 
     def __init__(
         self,
@@ -269,6 +333,7 @@ class ProfileCapture:
         self._remaining = 0
         self._window_flushes = 0
         self._host_start_ns = 0
+        self._mark_ns = 0
         self._flushes_seen = 0
         self.windows = 0
         self.work_seconds = 0.0
@@ -301,9 +366,10 @@ class ProfileCapture:
 
     def _begin_locked(self, flushes: int) -> None:
         run_dir = os.path.join(self.profile_dir, f"window-{self.windows}")
-        self._start_fn(run_dir)
+        mark_ns = self._start_fn(run_dir)
         self._run_dir = run_dir
         self._host_start_ns = time.monotonic_ns()
+        self._mark_ns = mark_ns if isinstance(mark_ns, int) else self._host_start_ns
         self._state = "capturing"
         self._remaining = flushes
         self._window_flushes = flushes
@@ -370,19 +436,17 @@ class ProfileCapture:
                 if isinstance(ev.get("ts"), (int, float)) and ev.get("ph") != "M"
             ]
             clock = None
-            if dev:
+            if dev and parsed["mark_us"] is not None:
                 tmin = min(float(e["ts"]) for e in dev)
-                tmax = max(
-                    float(e["ts"])
-                    + (e.get("dur") if isinstance(e.get("dur"), (int, float)) else 0.0)
-                    for e in dev
-                )
-                clock = ClockMap(self._host_start_ns, host_stop_ns, tmin, tmax)
+                tmax = max(float(e["ts"]) + float(e["dur"]) for e in dev)
+                clock = ClockMap(self._mark_ns, parsed["mark_us"],
+                                 self._host_start_ns, host_stop_ns, tmin, tmax)
             meta = {
                 "label": label,
                 "flushes": self._window_flushes,
                 "files": [os.path.basename(p) for p in parsed["files"]],
                 "device_events": len(dev),
+                "clock_mark": parsed["mark_us"] is not None,
             }
             merged = merge_host_device(
                 self.tracer, parsed["events"], clock,

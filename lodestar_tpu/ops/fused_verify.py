@@ -19,6 +19,13 @@ count:
 - The Miller loop runs ~12 kernel calls per iteration; the final
   exponentiation ~3 per pow-x window (fused_pairing).
 
+Each stage runs under a ``jax.named_scope`` (``hash_to_g2``,
+``g2_ladder``, ``subgroup_check``, ``cofactor``, ``sig_sum``,
+``g1_ladder``, ``affine``, ``miller_loop``, ``final_exp``): the scope
+lands in every op's ``op_name`` metadata (and names the Pallas custom
+calls), and changes nothing else in the program
+(tests/test_fused_scopes.py).
+
 Inputs/outputs match batch_verify exactly, so TpuBlsVerifier swaps the
 kernels behind the same packing code.
 """
@@ -28,6 +35,7 @@ from __future__ import annotations
 import numpy as np
 
 import jax.numpy as jnp
+from jax import named_scope
 
 from ..crypto.bls.fields import BLS_X
 from . import limbs as fl
@@ -104,7 +112,8 @@ def verify_signature_sets_fused(
     f, ok = miller_product_fused(
         pk_x, pk_y, sig_x, sig_y, msg_u, coeff_bits, mask, interpret
     )
-    product_one = f12_is_one(final_exponentiation(f, interpret), interpret)
+    with named_scope("final_exp"):
+        product_one = f12_is_one(final_exponentiation(f, interpret), interpret)
     return product_one & ok
 
 
@@ -152,90 +161,98 @@ def miller_product_parts(
     sig_jac = point_from_affine(lv(sig_x), lv(sig_y), ns2)
 
     # hash both field draws through SSWU+isogeny, complete-add the halves
-    h_pre = hash_to_g2_pre_cofactor(lv(msg_u), interpret)
-    psi_h = psi(h_pre, interpret)
+    with named_scope("hash_to_g2"):
+        h_pre = hash_to_g2_pre_cofactor(lv(msg_u), interpret)
 
     # --- the merged G2 ladder: 4 lanes per set, one 128-iteration scan ---
-    lanes = [
-        _neg_point(sig_jac),  # subgroup target [z]sig
-        h_pre,  # cofactor term 1
-        _neg_point(psi_h),  # cofactor term 2
-        sig_jac,  # RLC scaling
-    ]
-    stacked = tuple(lstack([lane[i] for lane in lanes], axis=0) for i in range(3))
-    cb = jnp.pad(coeff_bits.astype(jnp.float32), ((0, 0), (0, _NBITS - coeff_bits.shape[-1])))
-    bits = jnp.stack(
-        [
-            jnp.broadcast_to(jnp.asarray(_L0_BITS), (n, _NBITS)),
-            jnp.broadcast_to(jnp.asarray(_L1_BITS), (n, _NBITS)),
-            jnp.broadcast_to(jnp.asarray(_L2_BITS), (n, _NBITS)),
-            cb,
-        ],
-        axis=0,
-    )  # (4, N, 128)
-    from .fused_ladder import point_mul_bits_ladder
+    with named_scope("g2_ladder"):
+        psi_h = psi(h_pre, interpret)
+        lanes = [
+            _neg_point(sig_jac),  # subgroup target [z]sig
+            h_pre,  # cofactor term 1
+            _neg_point(psi_h),  # cofactor term 2
+            sig_jac,  # RLC scaling
+        ]
+        stacked = tuple(lstack([lane[i] for lane in lanes], axis=0) for i in range(3))
+        cb = jnp.pad(coeff_bits.astype(jnp.float32), ((0, 0), (0, _NBITS - coeff_bits.shape[-1])))
+        bits = jnp.stack(
+            [
+                jnp.broadcast_to(jnp.asarray(_L0_BITS), (n, _NBITS)),
+                jnp.broadcast_to(jnp.asarray(_L1_BITS), (n, _NBITS)),
+                jnp.broadcast_to(jnp.asarray(_L2_BITS), (n, _NBITS)),
+                cb,
+            ],
+            axis=0,
+        )  # (4, N, 128)
+        from .fused_ladder import point_mul_bits_ladder
 
-    out = point_mul_bits_ladder(stacked, bits, ns2, interpret=interpret)
-    z_sig = tuple(LV(c.a[0], c.b) for c in out)
-    t1 = tuple(LV(c.a[1], c.b) for c in out)
-    t2 = tuple(LV(c.a[2], c.b) for c in out)
-    sig_scaled = tuple(LV(c.a[3], c.b) for c in out)
+        out = point_mul_bits_ladder(stacked, bits, ns2, interpret=interpret)
+        z_sig = tuple(LV(c.a[0], c.b) for c in out)
+        t1 = tuple(LV(c.a[1], c.b) for c in out)
+        t2 = tuple(LV(c.a[2], c.b) for c in out)
+        sig_scaled = tuple(LV(c.a[3], c.b) for c in out)
 
     # signature subgroup check: psi(sig) == [z]sig (infinity passes)
-    sig_in_g2 = point_eq(psi(sig_jac, interpret), z_sig, ns2, interpret) | point_is_infinity(
-        sig_jac, ns2
-    )
-    subgroup_ok = jnp.all(jnp.where(mask, sig_in_g2, True))
+    with named_scope("subgroup_check"):
+        sig_in_g2 = point_eq(psi(sig_jac, interpret), z_sig, ns2, interpret) | point_is_infinity(
+            sig_jac, ns2
+        )
+        subgroup_ok = jnp.all(jnp.where(mask, sig_in_g2, True))
 
     # finish cofactor clearing: H = t1 + t2 + psi^2([2]H_pre)
-    t3 = psi(psi(point_double(h_pre, ns2), interpret), interpret)
-    h_jac = point_add_complete(
-        point_add_complete(t1, t2, ns2, interpret), t3, ns2, interpret
-    )
+    with named_scope("cofactor"):
+        t3 = psi(psi(point_double(h_pre, ns2), interpret), interpret)
+        h_jac = point_add_complete(
+            point_add_complete(t1, t2, ns2, interpret), t3, ns2, interpret
+        )
 
     # masked tree-sum of scaled signatures
-    inf = point_infinity(ns2, batch_shape=(n,))
-    sig_masked = point_select(mask, sig_scaled, inf, ns2)
-    s_sum = point_sum_tree(sig_masked, ns2)
+    with named_scope("sig_sum"):
+        inf = point_infinity(ns2, batch_shape=(n,))
+        sig_masked = point_select(mask, sig_scaled, inf, ns2)
+        s_sum = point_sum_tree(sig_masked, ns2)
 
     # G1 RLC ladder (unsafe adds: freshly randomized coefficients)
-    pk_jac = point_from_affine(lv(pk_x), lv(pk_y), ns1)
-    pk_scaled = point_mul_bits(
-        pk_jac, coeff_bits.astype(jnp.float32), ns1, complete=False, interpret=interpret
-    )
+    with named_scope("g1_ladder"):
+        pk_jac = point_from_affine(lv(pk_x), lv(pk_y), ns1)
+        pk_scaled = point_mul_bits(
+            pk_jac, coeff_bits.astype(jnp.float32), ns1, complete=False, interpret=interpret
+        )
 
     # --- merged affine conversion: one Fermat scan for every inversion ---
     # every batch-axis splice below rides the offset-0 aligned splice
     # (fused_core.aligned_splice): the trailing (2, 50)/(50,) extents sit
     # below the (8, 128) tile, so a plain concatenate at sublane offset N
     # is exactly the retile Mosaic rejects (BENCH_r05 rc=124)
-    g2_stack = tuple(
-        lconcat([h_jac[i], LV(s_sum[i].a[None], s_sum[i].b)], axis=0)
-        for i in range(3)
-    )
-    zg2 = g2_stack[2]
-    z0, z1 = LV(zg2.a[..., 0, :], zg2.b), LV(zg2.a[..., 1, :], zg2.b)
-    compsq = f_mul(lstack([z0, z1], -2), lstack([z0, z1], -2), interpret)
-    norm = ladd(LV(compsq.a[..., 0, :], compsq.b), LV(compsq.a[..., 1, :], compsq.b))
-    inv_in = lconcat([norm, pk_scaled[2]], axis=0)  # (2N+1, 50)
-    inv_all = fi_inv(inv_in, interpret)
-    ninv2 = LV(inv_all.a[: n + 1], inv_all.b)
-    zinv_g1 = LV(inv_all.a[n + 1 :], inv_all.b)
-    # G2 zinv = conj(z) * norm^-1
-    numer = lstack([z0, lneg(z1)], axis=-2)
-    zinv_g2 = f_mul(numer, LV(jnp.broadcast_to(ninv2.a[..., None, :], numer.a.shape), ninv2.b), interpret)
-    g2_aff_x, g2_aff_y = _affine_with_zinv(g2_stack, zinv_g2, ns2, interpret)
-    pk_aff_x, pk_aff_y = _affine_with_zinv(pk_scaled, zinv_g1, ns1, interpret)
+    with named_scope("affine"):
+        g2_stack = tuple(
+            lconcat([h_jac[i], LV(s_sum[i].a[None], s_sum[i].b)], axis=0)
+            for i in range(3)
+        )
+        zg2 = g2_stack[2]
+        z0, z1 = LV(zg2.a[..., 0, :], zg2.b), LV(zg2.a[..., 1, :], zg2.b)
+        compsq = f_mul(lstack([z0, z1], -2), lstack([z0, z1], -2), interpret)
+        norm = ladd(LV(compsq.a[..., 0, :], compsq.b), LV(compsq.a[..., 1, :], compsq.b))
+        inv_in = lconcat([norm, pk_scaled[2]], axis=0)  # (2N+1, 50)
+        inv_all = fi_inv(inv_in, interpret)
+        ninv2 = LV(inv_all.a[: n + 1], inv_all.b)
+        zinv_g1 = LV(inv_all.a[n + 1 :], inv_all.b)
+        # G2 zinv = conj(z) * norm^-1
+        numer = lstack([z0, lneg(z1)], axis=-2)
+        zinv_g2 = f_mul(numer, LV(jnp.broadcast_to(ninv2.a[..., None, :], numer.a.shape), ninv2.b), interpret)
+        g2_aff_x, g2_aff_y = _affine_with_zinv(g2_stack, zinv_g2, ns2, interpret)
+        pk_aff_x, pk_aff_y = _affine_with_zinv(pk_scaled, zinv_g1, ns1, interpret)
 
     # pair list: (c_i pk_i, H_i) for live lanes, then (-g1, S)
-    neg_x = lv(jnp.asarray(G1_GEN_NEG_AFFINE[0]))
-    neg_y = lv(jnp.asarray(G1_GEN_NEG_AFFINE[1]))
-    xp = lconcat([pk_aff_x, LV(neg_x.a[None], 256)], axis=0)
-    yp = lconcat([pk_aff_y, LV(neg_y.a[None], 256)], axis=0)
-    s_not_inf = ~f2_is_zero(s_sum[2], interpret)
-    pair_mask = aligned_splice([mask, s_not_inf[None]], axis=0)
+    with named_scope("miller_loop"):
+        neg_x = lv(jnp.asarray(G1_GEN_NEG_AFFINE[0]))
+        neg_y = lv(jnp.asarray(G1_GEN_NEG_AFFINE[1]))
+        xp = lconcat([pk_aff_x, LV(neg_x.a[None], 256)], axis=0)
+        yp = lconcat([pk_aff_y, LV(neg_y.a[None], 256)], axis=0)
+        s_not_inf = ~f2_is_zero(s_sum[2], interpret)
+        pair_mask = aligned_splice([mask, s_not_inf[None]], axis=0)
 
-    f = multi_miller_product(xp, yp, g2_aff_x, g2_aff_y, pair_mask, interpret)
+        f = multi_miller_product(xp, yp, g2_aff_x, g2_aff_y, pair_mask, interpret)
     return f, subgroup_ok, jnp.any(mask)
 
 

@@ -260,7 +260,7 @@ class JobItemQueue(Generic[T, R]):
         TPU batch-accumulation seam.  ``with_enqueue_time=True`` returns
         (item, fut, t_enqueue) triples — t_enqueue is the ``time.monotonic()``
         of the push, so the consumer can derive per-job queue-wait spans and
-        histograms (chain/bls_pool feeds lodestar_bls_pool_queue_wait_seconds
+        histograms (chain/bls_pool feeds lodestar_bls_queue_wait_seconds
         and the ``bls.queue_wait`` trace spans from it).
         ``with_meta=True`` returns the full (item, fut, t_enqueue, priority,
         deadline) records the shedding flusher needs.

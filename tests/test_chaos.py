@@ -379,7 +379,7 @@ class TestCheckTraceRequeue:
                 _span("bls.queue_wait", cid),
                 _span("bls.pack", cid),
                 _span("bls.dispatch", cid, device="cpu:0", devices_total=2),
-                _span("bls.final_exp", cid),
+                _span("bls.readback", cid, on_device=True),
             ]
         return events
 

@@ -106,12 +106,17 @@ def test_metrics_endpoint_exposition():
         for family in (
             "lodestar_bls_pool_pack_seconds",
             "lodestar_bls_pool_inflight_depth",
-            "lodestar_bls_pool_queue_wait_seconds",
+            "lodestar_bls_queue_wait_seconds",
             "lodestar_bls_pool_overlap_ratio",
-            "lodestar_bls_verifier_stage_seconds",
+            "lodestar_bls_verifier_stage_duration_seconds",
         ):
             assert family in text, f"missing from exposition: {family}"
-        assert "lodestar_bls_pool_queue_wait_seconds_count 1.0" in text
+        waits = [l for l in text.splitlines()
+                 if l.startswith("lodestar_bls_queue_wait_seconds_count{")]
+        assert sum(float(l.split()[-1]) for l in waits) == 1.0
+        # the laneless histogram and the stage gauge they replaced are gone
+        assert "lodestar_bls_pool_queue_wait_seconds" not in text
+        assert "lodestar_bls_verifier_stage_seconds" not in text
 
     asyncio.run(main())
 

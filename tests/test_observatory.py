@@ -408,9 +408,14 @@ class TestPoolHistograms:
         mesh = [l for l in text.splitlines()
                 if l.startswith("lodestar_bls_sets_per_sec_mesh ")]
         assert mesh and float(mesh[0].split()[1]) > 0
-        # deprecated aliases still exported for one release
-        assert "lodestar_bls_pool_queue_wait_seconds_count 2.0" in text
-        assert "lodestar_bls_verifier_stage_seconds" in text
+        # the per-lane histogram counts every job; the laneless histogram
+        # and the stage gauge it replaced with the stage histogram are gone
+        waits = [l for l in text.splitlines()
+                 if l.startswith("lodestar_bls_queue_wait_seconds_count{")]
+        assert sum(float(l.split()[-1]) for l in waits) == 2.0
+        assert "lodestar_bls_verifier_stage_duration_seconds" in text
+        assert "lodestar_bls_pool_queue_wait_seconds" not in text
+        assert "lodestar_bls_verifier_stage_seconds" not in text
 
     def test_verifier_stage_duration_histogram(self):
         """TpuBlsVerifier.pack observes the per-call stage histogram

@@ -465,7 +465,7 @@ class TestPoolMeshWindow:
                 "args": dict(cid=cid, **a),
             }
             return [mk("bls.queue_wait"), mk("bls.pack"),
-                    mk("bls.dispatch", **disp), mk("bls.final_exp")]
+                    mk("bls.dispatch", **disp), mk("bls.readback", on_device=True)]
 
         # sharded span without mesh_devices
         t = batch(1, device="mesh8", devices_total=8, sharded=True)

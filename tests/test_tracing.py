@@ -175,44 +175,52 @@ class TestChromeExport:
 
 class StageTracedVerifier:
     """Stage-split fake with the TpuBlsVerifier timing shape AND its span
-    emissions: pack blocks the calling thread, the 'device' computes in
-    wall time after the async enqueue, result() syncs then pays the host
-    final-exp cost.  Spans are stamped with the pool-assigned correlation
-    id read from the contextvar — proving the id propagates through
-    asyncio.to_thread into both halves of the flusher."""
+    emissions on the split path: pack blocks the calling thread, result()
+    waits for the 'device' (``bls.readback``) then pays the host final
+    exponentiation (``bls.final_exp``).  Spans are stamped with the
+    pool-assigned correlation id read from the contextvar — proving the id
+    propagates through asyncio.to_thread into both halves of the flusher.
 
-    PACK_S = 0.02
-    DEVICE_S = 0.04
-    FINAL_S = 0.02
+    ``hold_batches=N``: the fake device holds batch k in flight until
+    batch k+1 has packed (k < N-1), so two batches overlap by events, not
+    by wall-clock luck."""
 
-    def __init__(self):
+    STAGE_S = 0.002  # each stage's own time: every span has a duration
+
+    def __init__(self, hold_batches=0):
         self.dispatched = 0
-        self.stage_seconds = {"pack": 0.0, "dispatch": 0.0, "final_exp": 0.0}
+        self.stage_seconds = {"pack": 0.0, "dispatch": 0.0, "readback": 0.0,
+                              "final_exp": 0.0}
+        self._packed = [threading.Event() for _ in range(hold_batches)]
 
     def verify_signature_sets_async(self, sets):
         cid = tracing.current_batch_id()
+        k = self.dispatched
         t0 = TRACER.now()
-        time.sleep(self.PACK_S)
+        time.sleep(self.STAGE_S)
         TRACER.add_span("bls.pack", "bls", t0, cid=cid, sets=len(sets))
-        self.stage_seconds["pack"] += self.PACK_S
+        self.stage_seconds["pack"] += self.STAGE_S
         t0 = TRACER.now()
         self.dispatched += 1
-        ready_at = time.monotonic() + self.DEVICE_S
         TRACER.add_span("bls.dispatch", "bls", t0, cid=cid, bucket=len(sets))
         self.stage_seconds["dispatch"] += 1e-4
+        if k < len(self._packed):
+            self._packed[k].set()
+        hold = self._packed[k + 1] if k + 1 < len(self._packed) else None
         outer = self
 
         class _Pending:
             def result(_self):
-                rem = ready_at - time.monotonic()
-                if rem > 0:
-                    time.sleep(rem)  # device sync
+                cid = tracing.current_batch_id()
                 t0 = TRACER.now()
-                time.sleep(outer.FINAL_S)
-                TRACER.add_span(
-                    "bls.final_exp", "bls", t0, cid=tracing.current_batch_id()
-                )
-                outer.stage_seconds["final_exp"] += outer.FINAL_S
+                if hold is not None:
+                    assert hold.wait(timeout=30), "the next batch never packed"
+                time.sleep(outer.STAGE_S)  # the device's own tail
+                TRACER.add_span("bls.readback", "bls", t0, cid=cid, ok=True)
+                t0 = TRACER.now()
+                time.sleep(outer.STAGE_S)
+                TRACER.add_span("bls.final_exp", "bls", t0, cid=cid)
+                outer.stage_seconds["final_exp"] += outer.STAGE_S
                 return True
 
         return _Pending()
@@ -221,28 +229,28 @@ class StageTracedVerifier:
         return self.verify_signature_sets_async(sets).result()
 
 
+PIPELINE = ("bls.queue_wait", "bls.pack", "bls.dispatch", "bls.readback",
+            "bls.final_exp", "pool.batch")
+
+
 class TestPoolPipelineSpans:
     def test_correlated_pipeline_spans_two_inflight_batches(self, tmp_path):
         """Acceptance: >=2 in-flight batches leave queue-wait / pack /
-        dispatch / final-exp spans with non-zero durations under >=2
-        distinct correlation ids, and the dump passes
-        tools/check_trace.py --require-pipeline."""
+        dispatch / readback / final-exp spans with non-zero durations
+        under >=2 distinct correlation ids, and the dump passes
+        tools/check_trace.py --require-pipeline.  Deterministic: one job
+        per batch (flush_threshold=1) and the fake device holds each
+        batch until the next one has packed."""
 
         async def main():
             tracing.enable(1024)
-            v = StageTracedVerifier()
+            v = StageTracedVerifier(hold_batches=3)
             metrics = create_metrics()
             pool = BlsBatchPool(
-                v, max_buffer_wait=0.004, pipeline_depth=3, metrics=metrics
+                v, max_buffer_wait=0.004, flush_threshold=1,
+                pipeline_depth=3, metrics=metrics,
             )
-            # stagger pushes so the flusher drains three separate batches,
-            # each landing while the previous batch is still packing
-            jobs = [asyncio.create_task(pool.verify_signature_sets([make_set(0)]))]
-            for i in (1, 2):
-                await asyncio.sleep(StageTracedVerifier.PACK_S * 0.9)
-                jobs.append(
-                    asyncio.create_task(pool.verify_signature_sets([make_set(i)]))
-                )
+            jobs = [pool.verify_signature_sets([make_set(i)]) for i in range(3)]
             assert await asyncio.gather(*jobs) == [True] * 3
             assert pool.inflight_peak >= 2
             pool.close()
@@ -254,28 +262,37 @@ class TestPoolPipelineSpans:
         by_name = {}
         for s in spans:
             by_name.setdefault(s.name, []).append(s)
-        for name in ("bls.queue_wait", "bls.pack", "bls.dispatch",
-                     "bls.final_exp", "pool.batch"):
+        for name in PIPELINE:
             assert by_name.get(name), f"missing {name} spans: {sorted(by_name)}"
         cids = {s.cid for s in by_name["bls.pack"]}
-        assert len(cids) >= 2, cids
+        assert len(cids) == 3, cids
         # every batch's stages share its correlation id
         for cid in cids:
             stages = {s.name for s in spans if s.cid == cid}
-            assert {"bls.queue_wait", "bls.pack", "bls.dispatch",
-                    "bls.final_exp", "pool.batch"} <= stages, (cid, stages)
+            assert set(PIPELINE) <= stages, (cid, stages)
         assert all(s.dur_ns > 0 for s in by_name["bls.pack"])
+        # batch N is in flight (its readback open) while batch N+1 packs
+        first, second = sorted(cids)[:2]
+        rb = next(s for s in by_name["bls.readback"] if s.cid == first)
+        pk = next(s for s in by_name["bls.pack"] if s.cid == second)
+        assert rb.ts_ns <= pk.ts_ns + pk.dur_ns <= rb.ts_ns + rb.dur_ns
 
         path = str(tmp_path / "pipeline.json")
         tracing.write_chrome_trace(TRACER, path)
         assert check_trace.main([path, "--require-pipeline", "2"]) == 0
 
-        # satellite 1: the orphaned counters are now gauges, set on flush
+        # flush-time gauges, the per-lane queue wait and the stage
+        # histogram (the deprecated laneless histogram and stage gauge
+        # are gone)
         text = metrics.reg.expose().decode()
-        assert 'lodestar_bls_verifier_stage_seconds{stage="pack"}' in text
         assert "lodestar_bls_pool_inflight_peak" in text
         assert "lodestar_bls_pool_overlap_ratio" in text
-        assert "lodestar_bls_pool_queue_wait_seconds_count" in text
+        assert "lodestar_bls_verifier_stage_duration_seconds" in text
+        waits = [l for l in text.splitlines()
+                 if l.startswith("lodestar_bls_queue_wait_seconds_count{")]
+        assert sum(float(l.split()[-1]) for l in waits) == 3.0
+        assert "lodestar_bls_verifier_stage_seconds" not in text
+        assert "lodestar_bls_pool_queue_wait_seconds" not in text
         try:
             assert metrics.bls_pool_inflight_peak._value.get() >= 2
             assert metrics.bls_pool_overlap_ratio._value.get() > 1.0  # pipelined
@@ -336,6 +353,198 @@ class TestPoolPipelineSpans:
             assert await asyncio.gather(*tasks) == [0, 1]
 
         asyncio.run(main())
+
+
+PACK_PARTS = ("bls.pack.pubkeys", "bls.pack.signatures", "bls.pack.affine",
+              "bls.pack.limbs", "bls.pack.hash_to_field")
+
+
+def make_aggregate(first, n, valid=True):
+    from lodestar_tpu.crypto.bls.api import sign_aggregate
+    from lodestar_tpu.crypto.bls.verifier import AggregatedSignatureSet
+
+    sks = [interop_secret_key(first + j) for j in range(n)]
+    msg = bytes([first % 256, n]) * 16
+    signers = sks if valid else sks[1:]
+    return AggregatedSignatureSet(
+        pubkeys=[sk.to_public_key() for sk in sks],
+        signing_root=msg,
+        signature=sign_aggregate(signers, msg).to_bytes(),
+    )
+
+
+class TestHostSpans:
+    """The spans inside pack and the host tail, on the real
+    TpuBlsVerifier (host work only: nothing is traced or compiled)."""
+
+    def _verifier(self):
+        from lodestar_tpu.crypto.bls.tpu_verifier import TpuBlsVerifier
+
+        return TpuBlsVerifier(platform="cpu", metrics=create_metrics())
+
+    def test_pack_parts_nest_inside_pack_with_its_cid(self):
+        v = self._verifier()
+        tracing.enable(64)
+        token = tracing.set_batch(41)
+        try:
+            assert v.pack([make_set(0), make_aggregate(10, 3),
+                           make_aggregate(20, 2)]) is not None
+        finally:
+            tracing.reset_batch(token)
+        spans = TRACER.spans()
+        pack = next(s for s in spans if s.name == "bls.pack")
+        parts = [s for s in spans if s.name.startswith("bls.pack.")]
+        assert [s.name for s in parts] == list(PACK_PARTS)
+        for s in parts:
+            assert s.cid == pack.cid == 41
+            assert pack.ts_ns <= s.ts_ns
+            assert s.ts_ns + s.dur_ns <= pack.ts_ns + pack.dur_ns
+        # the parts run one after another
+        for a, b in zip(parts, parts[1:]):
+            assert a.ts_ns + a.dur_ns <= b.ts_ns
+        by = {s.name: s for s in parts}
+        # the two aggregates' members were summed; the single key was not
+        assert by["bls.pack.pubkeys"].args == {"members": 5}
+        assert by["bls.pack.signatures"].args == {"decompressed": 3}
+        # a second pack of the same sets hits the cache: nothing summed
+        TRACER.clear()
+        v.pack([make_set(0), make_aggregate(10, 3), make_aggregate(20, 2)])
+        again = {s.name: s for s in TRACER.spans()}
+        assert again["bls.pack.pubkeys"].args == {"members": 0}
+        assert again["bls.pack.signatures"].args == {"decompressed": 0}
+
+    def test_tail_split_records_readback_before_final_exp(self):
+        import numpy as np
+
+        from lodestar_tpu.ops import tower as tw
+
+        v = self._verifier()
+        tracing.enable(64)
+        token = tracing.set_batch(7)
+        try:
+            assert v._host_final_exp_verdict(
+                np.asarray(tw.FQ12_ONE, dtype=np.float32), np.True_) is True
+        finally:
+            tracing.reset_batch(token)
+        rb, fe = TRACER.spans()
+        assert (rb.name, fe.name) == ("bls.readback", "bls.final_exp")
+        assert rb.cid == fe.cid == 7 and rb.args == {"ok": True}
+        assert rb.ts_ns + rb.dur_ns <= fe.ts_ns
+        assert v.host_final_exps == 1
+        assert v.stage_seconds["readback"] > 0 and v.stage_seconds["final_exp"] > 0
+        text = v.metrics.reg.expose().decode()
+        for stage in ("readback", "final_exp"):
+            assert ('lodestar_bls_verifier_stage_duration_seconds_count'
+                    f'{{stage="{stage}"}} 1.0') in text
+        assert "lodestar_bls_pool_final_exp_seconds_count 1.0" in text
+
+    def test_tail_split_skips_final_exp_when_device_checks_fail(self):
+        import numpy as np
+
+        from lodestar_tpu.ops import tower as tw
+
+        v = self._verifier()
+        tracing.enable(64)
+        assert v._host_final_exp_verdict(
+            np.asarray(tw.FQ12_ONE, dtype=np.float32), np.False_) is False
+        (rb,) = TRACER.spans()
+        assert rb.name == "bls.readback" and rb.args == {"ok": False}
+        assert v.host_final_exps == 0 and v.stage_seconds["final_exp"] == 0.0
+        text = v.metrics.reg.expose().decode()
+        assert 'stage_duration_seconds_count{stage="readback"} 1.0' in text
+        assert 'stage_duration_seconds_count{stage="final_exp"}' not in text
+        assert "lodestar_bls_pool_final_exp_seconds_count 0.0" in text
+
+
+def _reference_pack(sets, bucket):
+    """What pack must return, set by set with no cache: the pubkey and
+    signature limbs, hash-to-field draws and mask (the RLC bits are drawn
+    fresh), or None when any set is malformed."""
+    import numpy as np
+
+    from lodestar_tpu.crypto.bls.curve import g2_from_bytes
+    from lodestar_tpu.crypto.bls.verifier import get_aggregated_pubkey
+    from lodestar_tpu.ops import htc
+    from lodestar_tpu.ops import limbs as fl
+
+    n = len(sets)
+    pk_ints, sig_ints = [], []
+    for s in sets:
+        pk = get_aggregated_pubkey(s)
+        if pk.is_infinity():
+            return None
+        x, y = pk.point.to_affine()
+        pk_ints += [x.n, y.n]
+        try:
+            sig = g2_from_bytes(s.signature, subgroup_check=False)
+        except ValueError:
+            return None
+        if sig.is_infinity():
+            return None
+        x, y = sig.to_affine()
+        sig_ints += [x.c0, x.c1, y.c0, y.c1]
+    pk_l = fl.ints_to_limbs(pk_ints).reshape(n, 2, fl.NLIMBS)
+    sig_l = fl.ints_to_limbs(sig_ints).reshape(n, 2, 2, fl.NLIMBS)
+    lanes = list(range(n)) + [0] * (bucket - n)  # padding copies lane 0
+    msgs = [s.signing_root for s in sets] + [b""] * (bucket - n)
+    return (pk_l[lanes, 0], pk_l[lanes, 1], sig_l[lanes, 0], sig_l[lanes, 1],
+            htc.hash_to_field_limbs(msgs), np.arange(bucket) < n)
+
+
+class TestPackEquivalence:
+    """pack's per-part passes return what each set computed alone gives,
+    and reject exactly the batches that hold a malformed set."""
+
+    def _sets(self):
+        return [make_set(1), make_aggregate(30, 3), make_set(2),
+                make_aggregate(40, 2, valid=False), make_set(3, valid=False)]
+
+    def test_arrays_match_the_per_set_reference(self):
+        import numpy as np
+
+        from lodestar_tpu.crypto.bls.tpu_verifier import TpuBlsVerifier
+
+        v = TpuBlsVerifier(platform="cpu")
+        sets = self._sets()
+        want = _reference_pack(sets, v._bucket(len(sets)))
+        for attempt in ("cold", "cached"):
+            got = v.pack(sets)
+            pk_x, pk_y, sig_x, sig_y, msg_u, bits, mask = got
+            for g, w in zip((pk_x, pk_y, sig_x, sig_y, msg_u, mask), want):
+                assert g.dtype == np.asarray(w).dtype, attempt
+                assert np.array_equal(g, w), attempt
+            assert bits.shape == (v._bucket(len(sets)), 64)
+            assert (bits[:, 0] == 1).all()  # odd coefficients
+        assert v.pack_rejected == 0
+
+    @pytest.mark.parametrize("bad", ["sig_bytes", "sig_infinity",
+                                     "pk_infinity", "both"])
+    def test_malformed_set_rejects_the_batch_and_leaves_the_cache(self, bad):
+        from lodestar_tpu.crypto.bls.api import PublicKey
+        from lodestar_tpu.crypto.bls.tpu_verifier import TpuBlsVerifier
+        from lodestar_tpu.crypto.bls.verifier import AggregatedSignatureSet
+
+        v = TpuBlsVerifier(platform="cpu")
+        assert v.pack(self._sets()[:2]) is not None  # something cached
+        cached = dict(v.point_cache._data)
+        sets = self._sets()
+        if bad in ("sig_bytes", "both"):
+            sets[2] = SingleSignatureSet(pubkey=sets[2].pubkey,
+                                         signing_root=sets[2].signing_root,
+                                         signature=b"\x00" * 96)
+        if bad == "sig_infinity":
+            sets[1] = AggregatedSignatureSet(pubkeys=sets[1].pubkeys,
+                                             signing_root=sets[1].signing_root,
+                                             signature=b"\xc0" + b"\x00" * 95)
+        if bad in ("pk_infinity", "both"):
+            pk = sets[3].pubkeys[0]
+            sets[3] = AggregatedSignatureSet(
+                pubkeys=[pk, PublicKey(point=-pk.point)],
+                signing_root=sets[3].signing_root, signature=sets[3].signature)
+        assert _reference_pack(sets, v._bucket(len(sets))) is None
+        assert v.pack(sets) is None
+        assert v.pack_rejected == 1
+        assert dict(v.point_cache._data) == cached
 
 
 class TestDebugEndpoints:

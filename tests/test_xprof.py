@@ -3,13 +3,13 @@ ingestion, clock remapping, host+device merge, per-batch latency
 attribution, and the scaling-loss breakdown.
 
 Deliberately device-free: every test injects fake profiler start/stop
-hooks that write synthetic trace-viewer fixtures (the exact
-``plugins/profile/<run>/<host>.trace.json.gz`` layout ``jax.profiler``
-leaves behind) — zero XLA compiles, and jax is never imported.
+hooks that write synthetic ``.xplane.pb`` fixtures (the exact
+``plugins/profile/<run>/<host>.xplane.pb`` layout ``jax.profiler``
+leaves behind, built with ``ProfileData.text_proto_to_serialized_xspace``)
+— zero XLA compiles.  One test runs the real profiler on the CPU.
 """
 
 import asyncio
-import gzip
 import importlib.util
 import json
 import os
@@ -79,79 +79,132 @@ def _device_fixture_events(base_us=5_000_000.0):
     ]
 
 
-def _write_profile_fixture(run_dir, events, run="run1", host="host",
-                           gz=True):
-    """Write ``events`` in the TensorBoard profile-plugin layout."""
+def _xspace_text(base_us=5_000_000.0, mark_us=None):
+    """A synthetic XSpace in the profiler's own timebase: the clock mark
+    on the host plane (at ``base_us`` unless ``mark_us``), and on the
+    device plane's ``XLA Ops`` line one compute fusion (its name scope in
+    a ``tf_op`` stat) and one collective."""
+    mark_us = base_us if mark_us is None else mark_us
+    return f"""
+planes {{ id: 1 name: "/host:CPU"
+  lines {{ id: 1 name: "python" timestamp_ns: {int(mark_us * 1e3)}
+    events {{ metadata_id: 1 offset_ps: 0 duration_ps: 1000 }} }}
+  event_metadata {{ key: 1 value {{ id: 1 name: "{xprof.CLOCK_MARK}" }} }} }}
+planes {{ id: 2 name: "/device:TPU:0"
+  lines {{ id: 1 name: "XLA Modules" timestamp_ns: {int(base_us * 1e3)}
+    events {{ metadata_id: 3 offset_ps: 0 duration_ps: 4500000000 }} }}
+  lines {{ id: 2 name: "{xprof.OPS_LINE}" timestamp_ns: {int(base_us * 1e3)}
+    events {{ metadata_id: 1 offset_ps: 0 duration_ps: 3000000000
+      stats {{ metadata_id: 1 str_value: "jit(f)/miller_loop/mul" }} }}
+    events {{ metadata_id: 2 offset_ps: 3000000000 duration_ps: 1500000000 }} }}
+  event_metadata {{ key: 1 value {{ id: 1 name: "%fusion.multiply.1 = f32[8] fusion(...)" }} }}
+  event_metadata {{ key: 2 value {{ id: 2 name: "%all-gather.2 = f32[8] all-gather(...)" }} }}
+  event_metadata {{ key: 3 value {{ id: 3 name: "jit_f" }} }}
+  stat_metadata {{ key: 1 value {{ id: 1 name: "tf_op" }} }} }}
+"""
+
+
+def _write_profile_fixture(run_dir, text=None, run="run1", host="host"):
+    """Write an XSpace in the TensorBoard profile-plugin layout."""
+    from jax.profiler import ProfileData
+
     d = os.path.join(run_dir, "plugins", "profile", run)
     os.makedirs(d, exist_ok=True)
-    name = f"{host}.trace.json" + (".gz" if gz else "")
-    path = os.path.join(d, name)
-    doc = json.dumps({"traceEvents": events})
-    if gz:
-        with gzip.open(path, "wt") as f:
-            f.write(doc)
-    else:
-        with open(path, "w") as f:
-            f.write(doc)
+    path = os.path.join(d, f"{host}.xplane.pb")
+    with open(path, "wb") as f:
+        f.write(ProfileData.text_proto_to_serialized_xspace(
+            _xspace_text() if text is None else text))
     return path
 
 
-def _fake_profiler(tmp_path, events=None):
-    """(start_fn, stop_fn, dirs): stop writes the fixture into whatever
-    directory start was last pointed at, like the real profiler."""
+def _fake_profiler(tmp_path):
+    """(start_fn, stop_fn, dirs): start returns the clock mark's
+    monotonic ns, and stop writes the fixture into whatever directory
+    start was last pointed at, like the real profiler."""
     dirs = []
 
     def start(d):
         os.makedirs(d, exist_ok=True)
         dirs.append(d)
+        return time.monotonic_ns()
 
     def stop():
-        _write_profile_fixture(
-            dirs[-1], _device_fixture_events() if events is None else events
-        )
+        _write_profile_fixture(dirs[-1])
 
     return start, stop, dirs
 
 
 class TestIngestion:
-    def test_parse_profile_dir_gz_and_plain(self, tmp_path):
+    def test_parse_profile_dir_reads_every_run(self, tmp_path):
         d = str(tmp_path)
-        _write_profile_fixture(d, _device_fixture_events(), run="a")
-        _write_profile_fixture(d, [{"name": "x", "ph": "X", "pid": 1,
-                                    "tid": 0, "ts": 1.0, "dur": 1.0}],
-                               run="b", gz=False)
+        _write_profile_fixture(d, run="a")
+        _write_profile_fixture(d, _xspace_text(base_us=9_000_000.0), run="b")
         parsed = xprof.parse_profile_dir(d)
         assert len(parsed["files"]) == 2
         assert parsed["skipped"] == []
-        assert len(parsed["events"]) == 4
+        # per file: one process_name + two XLA Ops events; pids stay apart
+        assert len(parsed["events"]) == 6
+        assert sorted({e["pid"] for e in parsed["events"]}) == [0, 1]
+        assert parsed["mark_us"] is not None
 
     def test_corrupt_file_skipped_not_fatal(self, tmp_path):
         d = str(tmp_path)
-        _write_profile_fixture(d, _device_fixture_events(), run="good")
+        _write_profile_fixture(d, run="good")
         bad_dir = os.path.join(d, "plugins", "profile", "bad")
         os.makedirs(bad_dir)
-        bad = os.path.join(bad_dir, "h.trace.json.gz")
+        bad = os.path.join(bad_dir, "h.xplane.pb")
         with open(bad, "wb") as f:
-            f.write(b"not gzip at all")
+            f.write(b"not an xspace at all")
         parsed = xprof.parse_profile_dir(d)
         assert parsed["skipped"] == [bad]
         assert len(parsed["events"]) == 3
 
     def test_recursive_fallback_layout(self, tmp_path):
+        from jax.profiler import ProfileData
+
         nested = tmp_path / "some" / "drifted" / "layout"
         nested.mkdir(parents=True)
-        path = str(nested / "x.trace.json")
-        with open(path, "w") as f:
-            json.dump([{"name": "e", "ph": "X", "pid": 1, "tid": 0,
-                        "ts": 0.0, "dur": 1.0}], f)
-        assert xprof.find_trace_files(str(tmp_path)) == [path]
-        assert len(xprof.load_trace_events(path)) == 1
+        path = str(nested / "x.xplane.pb")
+        with open(path, "wb") as f:
+            f.write(ProfileData.text_proto_to_serialized_xspace(_xspace_text()))
+        assert xprof.find_profile_files(str(tmp_path)) == [path]
+        events, _ = xprof.xspace_events(ProfileData.from_file(path))
+        assert len([e for e in events if e["ph"] == "X"]) == 2
+
+    def test_ops_keep_hlo_name_scope_and_clock_mark(self):
+        from jax.profiler import ProfileData
+
+        data = ProfileData.from_text_proto(_xspace_text(mark_us=4_999_000.0))
+        events, mark_us = xprof.xspace_events(data, pid_base=3)
+        assert mark_us == pytest.approx(4_999_000.0)
+        meta, fusion, gather = events
+        assert meta == {"name": "process_name", "ph": "M", "pid": 3, "tid": 0,
+                        "args": {"name": "/device:TPU:0"}}
+        # only the XLA Ops line: the module event is not an operation
+        assert fusion["name"] == "%fusion.multiply.1"
+        assert fusion["ts"] == pytest.approx(5_000_000.0)
+        assert fusion["dur"] == pytest.approx(3000.0)
+        assert fusion["args"] == {"scope": "jit(f)/miller_loop/mul"}
+        assert gather["name"] == "%all-gather.2" and "args" not in gather
+
+    def test_real_profiler_window_records_the_clock_mark(self, tmp_path):
+        """The default start/stop on this host's JAX (no device plane on
+        the CPU): the mark lands in the host plane, on the monotonic
+        instant the start recorded."""
+        t0 = time.monotonic_ns()
+        mark_ns = xprof._default_start(str(tmp_path))
+        xprof._default_stop()
+        assert t0 <= mark_ns <= time.monotonic_ns()
+        parsed = xprof.parse_profile_dir(str(tmp_path))
+        assert parsed["files"] and parsed["mark_us"] is not None
+        assert [e for e in parsed["events"] if e["ph"] == "X"] == []
 
 
 class TestClockMap:
     def test_offset_and_remap(self):
-        # host window starts at 1e6us; earliest device event at 5e6us
-        clock = xprof.ClockMap(1_000_000_000, 1_200_000_000,
+        # the mark entered at host 1e6us; the profiler stamped it 5e6us
+        clock = xprof.ClockMap(1_000_000_000, 5_000_000.0,
+                               1_000_000_000, 1_200_000_000,
                                5_000_000.0, 5_150_000.0)
         assert clock.offset_us == pytest.approx(-4_000_000.0)
         assert clock.remap(5_000_000.0) == pytest.approx(1_000_000.0)
@@ -159,9 +212,18 @@ class TestClockMap:
         assert clock.skew_us == 0.0
 
     def test_skew_is_device_overrun(self):
-        clock = xprof.ClockMap(1_000_000_000, 1_200_000_000,
+        clock = xprof.ClockMap(1_000_000_000, 5_000_000.0,
+                               1_000_000_000, 1_200_000_000,
                                5_000_000.0, 5_450_000.0)
         assert clock.skew_us == pytest.approx(250_000.0)
+
+    def test_anchor_is_the_mark_not_the_earliest_event(self):
+        # the first device event ran 30ms after the mark: it stays there
+        clock = xprof.ClockMap(1_000_000_000, 5_000_000.0,
+                               1_000_000_000, 1_200_000_000,
+                               5_030_000.0, 5_100_000.0)
+        assert clock.remap(5_030_000.0) == pytest.approx(1_030_000.0)
+        assert clock.skew_us == 0.0
 
 
 class TestMerge:
@@ -175,8 +237,8 @@ class TestMerge:
 
     def test_merge_schema_pids_and_clock_note(self):
         tr, t0 = self._tracer_with_host_span()
-        clock = xprof.ClockMap(t0, t0 + 10_000_000, 5_000_000.0,
-                               5_004_500.0)
+        clock = xprof.ClockMap(t0, 5_000_000.0, t0, t0 + 10_000_000,
+                               5_000_000.0, 5_004_500.0)
         doc = xprof.merge_host_device(tr, _device_fixture_events(), clock)
         assert check_trace.validate(doc) == []
         assert check_trace.validate_device_merge(doc) == []
@@ -200,8 +262,8 @@ class TestMerge:
     def test_skew_beyond_tolerance_fails_validation(self):
         tr, t0 = self._tracer_with_host_span()
         # device span 300ms vs 10ms host window -> huge skew
-        clock = xprof.ClockMap(t0, t0 + 10_000_000, 5_000_000.0,
-                               5_300_000.0)
+        clock = xprof.ClockMap(t0, 5_000_000.0, t0, t0 + 10_000_000,
+                               5_000_000.0, 5_300_000.0)
         doc = xprof.merge_host_device(tr, _device_fixture_events(), clock,
                                       tolerance_us=1000.0)
         errs = check_trace.validate_device_merge(doc)

@@ -20,9 +20,12 @@ and Perfetto actually require to load a file):
 
 ``--require-pipeline [N]`` additionally asserts the dump contains the
 full BLS span taxonomy — ``bls.queue_wait`` / ``bls.pack`` /
-``bls.dispatch`` / ``bls.final_exp`` — with non-zero durations, batch-
+``bls.dispatch`` / ``bls.readback`` — with non-zero durations, batch-
 correlated (same ``args.cid``) for at least N distinct merged batches
-(default 2).  When the dump comes from a multi-device executor pool
+(default 2).  On the split path (a ``bls.readback`` span carrying
+``args.ok``) a batch whose device checks passed (``ok`` true) must also
+carry its host ``bls.final_exp``; the fused path and a batch the device
+already rejected have none.  When the dump comes from a multi-device executor pool
 (any ``bls.dispatch`` span carries ``args.devices_total > 1``) it also
 asserts the dispatches landed on >= 2 distinct ``args.device`` ids — a
 pool that funnels every batch to one chip is a scheduler bug, not a
@@ -59,7 +62,10 @@ import json
 import sys
 from typing import Any, Dict, List
 
-PIPELINE_SPANS = ("bls.queue_wait", "bls.pack", "bls.dispatch", "bls.final_exp")
+PIPELINE_SPANS = ("bls.queue_wait", "bls.pack", "bls.dispatch", "bls.readback")
+#: the split path's host final exponentiation: required of a batch whose
+#: ``bls.readback`` says the device checks passed (``args.ok`` true)
+FINAL_EXP_SPAN = "bls.final_exp"
 #: spans that legitimately END a batch early: a cid whose jobs were shed
 #: by the overload policy (chain/bls_pool deadline shedding) never reaches
 #: pack/dispatch — --require-pipeline must not count it as a broken
@@ -67,7 +73,7 @@ PIPELINE_SPANS = ("bls.queue_wait", "bls.pack", "bls.dispatch", "bls.final_exp")
 SHED_SPAN = "bls.shed"
 #: a failed in-flight batch re-dispatched onto a surviving executor
 #: (self-healing pool, docs/chaos.md).  A requeued cid must STILL satisfy
-#: --require-pipeline — the replay emits fresh dispatch/final_exp spans —
+#: --require-pipeline — the replay emits fresh dispatch/readback spans —
 #: and additionally must show >= 2 dispatch attempts (a requeue span with
 #: no re-dispatch means the recovery path lost the batch)
 REQUEUE_SPAN = "bls.requeue"
@@ -135,6 +141,7 @@ def validate_pipeline(trace: Any, min_batches: int = 2) -> List[str]:
     shed_cids = set()
     requeued_cids = set()
     dispatches_by_cid: Dict[Any, int] = {}
+    final_exp_due = set()  # split-path cids whose device checks passed
     devices_seen = set()
     devices_total = 1
     mesh_covered = False  # a sharded span with mesh_devices >= 2 seen
@@ -153,7 +160,7 @@ def validate_pipeline(trace: Any, min_batches: int = 2) -> List[str]:
             if cid is not None:
                 requeued_cids.add(cid)
             continue
-        if name not in PIPELINE_SPANS:
+        if name not in PIPELINE_SPANS and name != FINAL_EXP_SPAN:
             continue
         args = ev.get("args") or {}
         if name == "bls.dispatch":
@@ -185,12 +192,15 @@ def validate_pipeline(trace: Any, min_batches: int = 2) -> List[str]:
             continue
         if name == "bls.dispatch":
             dispatches_by_cid[cid] = dispatches_by_cid.get(cid, 0) + 1
+        if name == "bls.readback" and args.get("ok") is True:
+            final_exp_due.add(cid)
         stages = by_cid.setdefault(cid, {})
         stages[name] = max(stages.get(name, 0.0), float(ev.get("dur", 0)))
     complete = [
         cid
         for cid, stages in by_cid.items()
         if all(stages.get(s, 0.0) > 0.0 for s in PIPELINE_SPANS)
+        and (cid not in final_exp_due or stages.get(FINAL_EXP_SPAN, 0.0) > 0.0)
     ]
     errors: List[str] = []
     if len(complete) < min_batches:
