@@ -88,13 +88,14 @@ class PublicKey:
     compressed bytes (the native fb_sk_to_pk output) and decompress on first
     curve use, so serialize-only flows never pay Python field math."""
 
-    __slots__ = ("_point", "_raw")
+    __slots__ = ("_point", "_raw", "_xy")
 
     def __init__(self, point: Optional[Point[Fq]] = None, raw: Optional[bytes] = None):
         if point is None and raw is None:
             raise ValueError("PublicKey needs a point or raw bytes")
         self._point = point
         self._raw = raw
+        self._xy: Optional[bytes] = None
 
     @property
     def point(self) -> Point[Fq]:
@@ -102,6 +103,22 @@ class PublicKey:
             # self-produced canonical bytes: skip the subgroup check
             self._point = g1_from_bytes(self._raw, subgroup_check=False)
         return self._point
+
+    def affine_xy(self) -> Optional[bytes]:
+        """96-byte big-endian affine x||y of a decompressed key, the
+        native affine sum's input; memoised, since committees reuse keys
+        across sets.  None for a key not decompressed or at infinity."""
+        if self._xy is None:
+            pt = self._point
+            if pt is None or pt.is_infinity():
+                return None
+            if pt.z.n == 1:  # as decompressed
+                x, y = pt.x.n, pt.y.n
+            else:
+                ax, ay = pt.to_affine()
+                x, y = ax.n, ay.n
+            self._xy = (x << 384 | y).to_bytes(96, "big")
+        return self._xy
 
     @classmethod
     def from_bytes(cls, data: bytes, validate: bool = True) -> "PublicKey":
@@ -176,10 +193,35 @@ def sign_aggregate(sks: Sequence[SecretKey], msg: bytes) -> "Signature":
     return aggregate_signatures([sk.sign(msg, variable_time=True) for sk in sks])
 
 
+def aggregate_affine_pubkeys(pubkeys: Sequence[PublicKey]) -> Optional[PublicKey]:
+    """The sum of decompressed keys, computed natively from their affine
+    coordinates (fb_aggregate_pubkeys_affine: no square root per member,
+    one inversion).  None when a member is not decompressed or is the
+    point at infinity, or without the native library: the caller then
+    takes another path to the same group element."""
+    if not pubkeys or not _native.have_native():
+        return None
+    xys = [pk.affine_xy() for pk in pubkeys]
+    if None in xys:
+        return None
+    out = _native.aggregate_pks_affine(b"".join(xys))
+    if out is None:
+        return None
+    if not out:
+        return PublicKey(Point.infinity(B1))
+    return PublicKey(Point.from_affine(
+        Fq(int.from_bytes(out[:48], "big")), Fq(int.from_bytes(out[48:], "big")), B1))
+
+
 def aggregate_pubkeys(pubkeys: Sequence[PublicKey]) -> PublicKey:
     """Sum in jacobian coords (reference: getAggregatedPubkey,
     chain/bls/utils.ts:5, ~3x faster than affine per interface.ts:31-33).
-    All-raw inputs aggregate natively (fb_aggregate_pubkeys_c)."""
+    Natively when the library is there: from affine coordinates when every
+    member is decompressed (``aggregate_affine_pubkeys``), from compressed
+    bytes when none is (fb_aggregate_pubkeys_c)."""
+    agg = aggregate_affine_pubkeys(pubkeys)
+    if agg is not None:
+        return agg
     if pubkeys and all(pk._raw is not None and pk._point is None for pk in pubkeys):
         out = _native.aggregate_pks([pk._raw for pk in pubkeys])
         if out is not None:

@@ -59,7 +59,7 @@ from .verifier import (
     PointCache,
     SignatureSet,
     SingleSignatureSet,
-    get_aggregated_pubkey,
+    aggregate_set_pubkey,
 )
 
 logger = get_logger("tpu-verifier")
@@ -1728,10 +1728,12 @@ class TpuBlsVerifier:
         ``bls.pack.signatures`` (cache lookups and G2 decompression),
         ``bls.pack.affine``, ``bls.pack.limbs`` and
         ``bls.pack.hash_to_field``; the RLC bits and the mask are
-        ``bls.pack``'s own time."""
+        ``bls.pack``'s own time.  ``native_sets`` on ``bls.pack.pubkeys``
+        counts the aggregates summed natively from their members' affine
+        coordinates (``aggregate_set_pubkey``)."""
         t0 = time.perf_counter()
         t0_ns = TRACER.now()
-        hits = misses = 0
+        hits = misses = native_sets = 0
         try:
             n = len(sets)
             b = self._bucket(n)
@@ -1762,13 +1764,15 @@ class TpuBlsVerifier:
                     misses += 1
                     if not isinstance(s, SingleSignatureSet):
                         members += len(s.pubkeys)
-                    pk = get_aggregated_pubkey(s)
+                    pk, native = aggregate_set_pubkey(s)
+                    native_sets += native
                     if pk.is_infinity():
                         return self._pack_reject()
                     pk_miss.append((i, pk.point, pk_key))
             if TRACER.enabled:
                 TRACER.add_span("bls.pack.pubkeys", "bls", t_ns,
-                                cid=current_batch_id(), members=members)
+                                cid=current_batch_id(), members=members,
+                                native_sets=native_sets)
             # -- signatures ----------------------------------------------
             t_ns = TRACER.now()
             for i, s in enumerate(sets):
@@ -1868,6 +1872,8 @@ class TpuBlsVerifier:
                     self.metrics.bls_pack_cache_hits_total.inc(hits)
                 if misses:
                     self.metrics.bls_pack_cache_misses_total.inc(misses)
+                if native_sets:
+                    self.metrics.bls_pack_native_aggregations_total.inc(native_sets)
             if TRACER.enabled:
                 TRACER.add_span("bls.pack", "bls", t0_ns,
                                 cid=current_batch_id(), sets=len(sets),

@@ -25,6 +25,7 @@ from ...utils.errors import LodestarError
 from .api import (
     PublicKey,
     Signature,
+    aggregate_affine_pubkeys,
     aggregate_pubkeys,
     verify,
     verify_multiple_signatures,
@@ -99,13 +100,24 @@ def get_aggregated_pubkey(s: SignatureSet) -> PublicKey:
     merged batch (the pool's retry-individually path) or re-packed after a
     dispatch failure pays the jacobian sum once.  The memo rides in the
     instance ``__dict__`` so it dies with the set object."""
+    return aggregate_set_pubkey(s)[0]
+
+
+def aggregate_set_pubkey(s: SignatureSet) -> Tuple[PublicKey, bool]:
+    """``get_aggregated_pubkey``, and whether this call summed the set's
+    keys on the native affine path (``aggregate_affine_pubkeys``): False
+    for a single set, a memo hit, or another path."""
     if isinstance(s, SingleSignatureSet):
-        return s.pubkey
+        return s.pubkey, False
     cached = s.__dict__.get("_agg_pubkey")
-    if cached is None:
-        cached = aggregate_pubkeys(s.pubkeys)
-        s.__dict__["_agg_pubkey"] = cached
-    return cached
+    if cached is not None:
+        return cached, False
+    agg = aggregate_affine_pubkeys(s.pubkeys)
+    native = agg is not None
+    if not native:
+        agg = aggregate_pubkeys(s.pubkeys)
+    s.__dict__["_agg_pubkey"] = agg
+    return agg, native
 
 
 class PointCache:

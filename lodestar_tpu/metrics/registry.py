@@ -155,6 +155,11 @@ class Metrics:
             "pack-stage point-cache hits (affine point reused, "
             "decompression/aggregation/inversion skipped)",
         )
+        self.bls_pack_native_aggregations_total = r.counter(
+            "lodestar_bls_pack_native_aggregations_total",
+            "pack-stage aggregates summed natively from their members' "
+            "affine coordinates (no decompression, no Python field math)",
+        )
         self.bls_pack_cache_misses_total = r.counter(
             "lodestar_bls_pack_cache_misses_total",
             "pack-stage point-cache misses (full decompression + batched "

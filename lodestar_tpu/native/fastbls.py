@@ -93,6 +93,10 @@ def _load() -> Optional[ctypes.CDLL]:
             lib.fb_aggregate_pubkeys_c.argtypes = [
                 ctypes.c_size_t, ctypes.c_char_p, ctypes.c_char_p,
             ]
+            lib.fb_aggregate_pubkeys_affine.restype = ctypes.c_int
+            lib.fb_aggregate_pubkeys_affine.argtypes = [
+                ctypes.c_size_t, ctypes.c_char_p, ctypes.c_char_p,
+            ]
             if lib.fb_selftest() != 1:
                 return None
             _LIB = lib
@@ -219,6 +223,24 @@ def aggregate_pks(pks: Sequence[bytes]) -> Optional[bytes]:
         return None
     out = ctypes.create_string_buffer(48)
     if lib.fb_aggregate_pubkeys_c(len(pks), blob, out) != 1:
+        return None
+    return out.raw
+
+
+def aggregate_pks_affine(blob: bytes) -> Optional[bytes]:
+    """Sum of decompressed pubkeys given as concatenated 96-byte affine
+    x||y (big-endian), affine x||y out (fb_aggregate_pubkeys_affine): no
+    square root per member.  ``b""`` when the sum is the point at
+    infinity; None without the native lib, for a blob that is not whole
+    members, or for a coordinate >= p."""
+    lib = _load()
+    if lib is None or len(blob) % 96:
+        return None
+    out = ctypes.create_string_buffer(96)
+    rc = lib.fb_aggregate_pubkeys_affine(len(blob) // 96, blob, out)
+    if rc == 0:
+        return b""
+    if rc != 1:
         return None
     return out.raw
 

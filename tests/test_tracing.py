@@ -404,14 +404,48 @@ class TestHostSpans:
             assert a.ts_ns + a.dur_ns <= b.ts_ns
         by = {s.name: s for s in parts}
         # the two aggregates' members were summed; the single key was not
-        assert by["bls.pack.pubkeys"].args == {"members": 5}
+        assert by["bls.pack.pubkeys"].args == {"members": 5, "native_sets": 0}
         assert by["bls.pack.signatures"].args == {"decompressed": 3}
         # a second pack of the same sets hits the cache: nothing summed
         TRACER.clear()
         v.pack([make_set(0), make_aggregate(10, 3), make_aggregate(20, 2)])
         again = {s.name: s for s in TRACER.spans()}
-        assert again["bls.pack.pubkeys"].args == {"members": 0}
+        assert again["bls.pack.pubkeys"].args == {"members": 0, "native_sets": 0}
         assert again["bls.pack.signatures"].args == {"decompressed": 0}
+
+    def test_pack_counts_aggregates_summed_from_affine_coordinates(self):
+        from lodestar_tpu.crypto.bls.api import PublicKey
+        from lodestar_tpu.crypto.bls.verifier import AggregatedSignatureSet
+        from lodestar_tpu.native import fastbls
+
+        if not fastbls.have_native():
+            pytest.skip("no C toolchain for fastbls")
+
+        def decompressed(s):
+            # members holding a decompressed point, as a pubkey cache holds them
+            return AggregatedSignatureSet(
+                pubkeys=[PublicKey.from_bytes(pk.to_bytes(), validate=False)
+                         for pk in s.pubkeys],
+                signing_root=s.signing_root, signature=s.signature)
+
+        v = self._verifier()
+        sets = [make_set(0), decompressed(make_aggregate(10, 3)), make_set(1),
+                decompressed(make_aggregate(20, 2)), make_aggregate(30, 2)]
+        tracing.enable(64)
+        assert v.pack(sets) is not None
+        (pubkeys,) = [s for s in TRACER.spans() if s.name == "bls.pack.pubkeys"]
+        # two aggregates with decompressed members; the raw-only one takes
+        # the compressed branch
+        assert pubkeys.args == {"members": 7, "native_sets": 2}
+        text = v.metrics.reg.expose().decode()
+        assert "lodestar_bls_pack_native_aggregations_total 2.0" in text
+        # a repack the PointCache serves sums nothing
+        TRACER.clear()
+        assert v.pack([make_set(0), decompressed(make_aggregate(10, 3))]) is not None
+        (pubkeys,) = [s for s in TRACER.spans() if s.name == "bls.pack.pubkeys"]
+        assert pubkeys.args == {"members": 0, "native_sets": 0}
+        text = v.metrics.reg.expose().decode()
+        assert "lodestar_bls_pack_native_aggregations_total 2.0" in text
 
     def test_tail_split_records_readback_before_final_exp(self):
         import numpy as np
