@@ -37,6 +37,11 @@ COMPILE_EVENTS = ("/jax/core/compile/",)
 # valid sets of the returned jobs that the reference checks, besides every
 # bad-labelled one (PERF.md, how correct is decided)
 REFERENCE_SAMPLE = 768
+# chip-seconds of the window a traced run gives the profiler: stopping it
+# costs the host ~1 s a traced chip-second of this program (~20 us a device
+# operation; 214 s for 51 s on four v5e chips), so a traced run on more
+# chips traces the last part of the window only (PERF.md, layers)
+TRACE_CHIP_SECONDS = 60.0
 
 
 def log(msg: str) -> None:
@@ -73,6 +78,11 @@ def setup_env() -> None:
     """Caches inside the checkout, at fixed paths, before JAX is imported."""
     os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(CACHE, "jax")
     os.environ.setdefault("TPU_LOG_DIR", os.path.join(CACHE, "tpu_logs"))
+
+
+def traced_seconds(seconds: float, chips: int) -> float:
+    """How much of the window, at its end, a traced run traces."""
+    return min(seconds, TRACE_CHIP_SECONDS / chips)
 
 
 def check_device(chips: int):
@@ -191,7 +201,7 @@ def main(argv: List[str], t0: float, make_system: Optional[Callable] = None,
         else:
             from .system import ProgramSystem
 
-            system = ProgramSystem(pk, xy, os.path.join(CACHE, "aot"))
+            system = ProgramSystem(pk, xy, os.path.join(CACHE, "aot"), cell["chips"])
         steps["system_s"] = time.monotonic() - t
         t = time.monotonic()
         traffic.collect(signing)
@@ -209,14 +219,21 @@ def main(argv: List[str], t0: float, make_system: Optional[Callable] = None,
             TRACER.clear()
             TRACER.enable(1 << 17)
 
+        untraced = opts.seconds - traced_seconds(opts.seconds, cell["chips"])
+        starter = []
+
         def on_open() -> None:
             counter.active = True
-            if profiler:
+            if profiler and untraced > 0:
+                starter.append(asyncio.get_running_loop().call_later(untraced, profiler.start))
+            elif profiler:
                 profiler.start()
 
         def on_close() -> None:
             counter.active = False
-            if profiler:
+            for timer in starter:
+                timer.cancel()
+            if profiler and profiler.mark_ns is not None:
                 profiler.stop()
 
         t_warm = time.monotonic()
@@ -260,11 +277,12 @@ def main(argv: List[str], t0: float, make_system: Optional[Callable] = None,
     checks = checks_of(run, traffic, ref, health, counter.count)
     ops = None
     if captured is not None:
-        ops = [op for plane in sorted(captured["ops"])[:cell["chips"]]
-               for op in captured["ops"][plane]]
+        planes = [captured["ops"][p] for p in sorted(captured["ops"])[:cell["chips"]]]
+        ops = [op for plane in planes for op in plane]
         if not ops:
             raise RuntimeError(f"the trace holds no device operations: {captured['lines']}")
-    ctx = Context(run=run, setup_s=run.open_t - t0, spans=spans, device_ops=ops)
+    ctx = Context(run=run, setup_s=run.open_t - t0, spans=spans, device_ops=ops,
+                  trace_t=profiler.mark_ns / 1e9 if captured is not None and untraced > 0 else None)
     chosen = spec["layer"] if opts.trace else spec["e2e"]
     values = read_all([m["name"] for m in chosen], ctx)
     result = {
@@ -276,12 +294,15 @@ def main(argv: List[str], t0: float, make_system: Optional[Callable] = None,
         "device": device_of(devices, memory),
     }
     if captured is not None:
-        lo, hi = run.open_t, run.close_t
-        busy = trace.busy(ops, lo, hi)
-        result["device"]["busy_s"] = trace.total(busy)
+        lo, hi = ctx.device_window()
+        busy = ctx.device_busy()
+        # seconds averaged over the chips; idle gaps where none is busy
+        result["device"]["busy_s"] = sum(
+            trace.total(trace.busy(plane, lo, hi)) for plane in planes) / len(planes)
         result["device"]["window_s"] = hi - lo
         result["breakdown"] = {
-            "device_ops": trace.device_ops_top(ops, lo, hi),
+            "device_ops": [[name, s / len(planes)]
+                           for name, s in trace.device_ops_top(ops, lo, hi)],
             "idle_gaps": trace.idle_gaps_top(trace.gaps(busy, lo, hi),
                                             [(n, a, b) for n, a, b, _ in spans]),
         }

@@ -24,6 +24,8 @@ class Context:
     setup_s: float
     spans: Optional[List[Span]] = None       # program spans (traced runs)
     device_ops: Optional[list] = None        # device operations (traced runs)
+    trace_t: Optional[float] = None          # the trace's start, if after the opening
+    _busy: Optional[list] = dataclasses.field(default=None, repr=False)
 
     @property
     def jobs(self) -> List[Record]:
@@ -41,12 +43,31 @@ class Context:
         lo, hi = self.run.open_t, self.run.close_t
         return sum(b - a for n, a, b, _ in self.spans if n == name and lo <= a < hi)
 
-    def device_busy_s(self) -> Optional[float]:
-        """Device-busy seconds inside the window; None without device
-        operations in the trace."""
+    def device_window(self) -> Tuple[float, float]:
+        """The part of the window the device trace covers: all of it, or
+        from ``trace_t`` on."""
+        return max(self.run.open_t, self.trace_t or self.run.open_t), self.run.close_t
+
+    @property
+    def device_jobs(self) -> List[Record]:
+        """The jobs whose verdict returned inside the device window."""
+        lo = self.device_window()[0]
+        return [r for r in self.jobs if r.done > lo]
+
+    def device_busy(self) -> Optional[List[Tuple[float, float]]]:
+        """The union of the device operations' intervals inside the device
+        window, computed once; None without device operations in the trace."""
         if not self.device_ops:
             return None
-        return trace.total(trace.busy(self.device_ops, self.run.open_t, self.run.close_t))
+        if self._busy is None:
+            self._busy = trace.busy(self.device_ops, *self.device_window())
+        return self._busy
+
+    def device_busy_s(self) -> Optional[float]:
+        """Device-busy seconds inside the device window; None without
+        device operations in the trace."""
+        busy = self.device_busy()
+        return None if busy is None else trace.total(busy)
 
 
 def load(name: str):

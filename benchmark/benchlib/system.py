@@ -1,11 +1,13 @@
 """The system under test, and what stands in its place for the control.
 
 ``ProgramSystem`` is the deployment that ships: ``cli._make_pool`` with
-``--bls-verifier tpu`` and every other pool and verifier setting at its CLI
-default, except that programs load on first use (``--bls-warmup off``;
-the harness's warm-up jobs reach exactly the buckets the traffic uses)
-and the AOT store lives in the checkout.  It is the only module of the
-benchmark that imports the program.
+``--bls-verifier tpu`` over the cell's chips (``--bls-devices``) and every
+other pool and verifier setting at its CLI default, except that programs
+load on first use (``--bls-warmup off``; the harness's warm-up jobs reach
+exactly the buckets the traffic uses) and the AOT store lives in the
+checkout.  On more than one chip the CLI's default puts the sharded tier
+on: a bucket at the ladder's top runs as one mesh program over every
+chip.  It is the only module of the benchmark that imports the program.
 
 ``ReferenceSystem`` puts the reference in the program's place; with a
 48-byte hash-to-field draw it is the control of PERF.md.
@@ -22,8 +24,15 @@ from .traffic import Job
 from .workers import Workers, verify
 
 
+def pool_args(aot_dir: str, chips: int) -> List[str]:
+    """The CLI arguments of the pool under test, on ``chips`` chips."""
+    return ["dev", "--bls-verifier", "tpu", "--bls-warmup", "off",
+            "--bls-aot-store", aot_dir, "--bls-devices", str(chips)]
+
+
 class ProgramSystem:
-    def __init__(self, pk: np.ndarray, xy: np.ndarray, aot_dir: str, pool=None):
+    def __init__(self, pk: np.ndarray, xy: np.ndarray, aot_dir: str, chips: int = 1,
+                 pool=None):
         from lodestar_tpu import cli
         from lodestar_tpu.crypto.bls.api import PublicKey
         from lodestar_tpu.crypto.bls.curve import B1, Point
@@ -33,10 +42,7 @@ class ProgramSystem:
         )
 
         if pool is None:
-            pool = cli._make_pool(cli.build_parser().parse_args([
-                "dev", "--bls-verifier", "tpu", "--bls-warmup", "off",
-                "--bls-aot-store", aot_dir,
-            ]))
+            pool = cli._make_pool(cli.build_parser().parse_args(pool_args(aot_dir, chips)))
         self.pool = pool
         self.verifier = self.pool.verifier
         self._agg, self._single = AggregatedSignatureSet, SingleSignatureSet
@@ -64,22 +70,27 @@ class ProgramSystem:
             sets, priority=self.priority[priority])
 
     def health(self) -> Dict[str, int]:
-        """Verdicts not served by the fused device program: each must be 0."""
+        """Verdicts not served by the fused device program, or on more than
+        one chip not by the one mesh program over all of them: each must
+        be 0."""
         from lodestar_tpu.forensics.journal import JOURNAL
 
         v = self.verifier
+        mesh = getattr(v, "n_devices", 1) > 1
         return {
             "fused_fallbacks": getattr(v, "fused_fallbacks", 0),
             "native_fallbacks": getattr(v, "native_fallbacks", 0),
             "sharded_fallbacks": getattr(v, "sharded_fallbacks", 0),
             "degrade_events": sum(e.get("kind") == "bls.degrade" for e in JOURNAL.events()),
             "not_fused": int(getattr(v, "fused", True) is not True),
+            "not_mesh": v.dispatches - v.sharded_batches if mesh else 0,
         }
 
     def counters(self) -> Dict[str, object]:
         v = self.verifier
         out = {name: getattr(v, name) for name in (
-            "dispatches", "sets_verified", "pack_cache_hits", "pack_cache_misses")
+            "dispatches", "sharded_batches", "sets_verified", "pack_cache_hits",
+            "pack_cache_misses")
             if hasattr(v, name)}
         out["batch_retries"] = self.pool.batch_retries
         out["buckets_used"] = sorted({k[0] for ex in getattr(v, "_executors", [])

@@ -19,6 +19,8 @@ import shutil
 import time
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
+import numpy as np
+
 Interval = Tuple[float, float]
 
 CLOCK_MARK = "benchmark.clock"
@@ -103,13 +105,21 @@ def op_name(name: str) -> str:
 
 
 def union(intervals: Iterable[Interval]) -> List[Interval]:
-    out: List[List[float]] = []
-    for a, b in sorted(intervals):
-        if out and a <= out[-1][1]:
-            out[-1][1] = max(out[-1][1], b)
-        else:
-            out.append([a, b])
-    return [(a, b) for a, b in out]
+    pairs = np.array(list(intervals), dtype=float).reshape(-1, 2)
+    return _union(pairs[:, 0], pairs[:, 1])
+
+
+def _union(starts: np.ndarray, ends: np.ndarray) -> List[Interval]:
+    """Sorted by start, an interval joins the one before it where it
+    starts no later than every interval before it has ended."""
+    if not len(starts):
+        return []
+    order = np.lexsort((ends, starts))
+    starts, reach = starts[order], np.maximum.accumulate(ends[order])
+    first = np.ones(len(starts), dtype=bool)
+    first[1:] = starts[1:] > reach[:-1]
+    last = np.append(np.flatnonzero(first)[1:] - 1, len(starts) - 1)
+    return list(zip(starts[first].tolist(), reach[last].tolist()))
 
 
 def clip(intervals: Iterable[Interval], lo: float, hi: float) -> List[Interval]:
@@ -118,7 +128,9 @@ def clip(intervals: Iterable[Interval], lo: float, hi: float) -> List[Interval]:
 
 def busy(ops: Sequence[Tuple[str, float, float]], lo: float, hi: float) -> List[Interval]:
     """Union of the operations' intervals inside [lo, hi]."""
-    return clip(union((a, b) for _, a, b in ops), lo, hi)
+    n = len(ops)
+    return clip(_union(np.fromiter((op[1] for op in ops), float, n),
+                       np.fromiter((op[2] for op in ops), float, n)), lo, hi)
 
 
 def total(intervals: Iterable[Interval]) -> float:

@@ -1,9 +1,10 @@
-"""device_idle.block (device): the share of the window in which no
-operation ran on the device."""
+"""device_idle.block (device): the share of the traced part of the window
+in which no operation ran on the device."""
 
 
 def read(ctx):
     busy = ctx.device_busy_s()
     if not busy:
         return None
-    return 100.0 * (1.0 - busy / ctx.run.seconds)
+    lo, hi = ctx.device_window()
+    return 100.0 * (1.0 - busy / (hi - lo))

@@ -1,9 +1,10 @@
 """device_ms.block (device program): the union of the device's operation
-intervals in the window, per block verified in it."""
+intervals in the traced part of the window, per block whose verdict
+returned in it."""
 
 
 def read(ctx):
     busy = ctx.device_busy_s()
-    if not busy or not ctx.jobs:
+    if not busy or not ctx.device_jobs:
         return None
-    return 1e3 * busy / len(ctx.jobs)
+    return 1e3 * busy / len(ctx.device_jobs)

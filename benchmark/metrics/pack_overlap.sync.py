@@ -1,6 +1,7 @@
-"""pack_overlap.sync (pool): the share of ``bls.pack`` time in the window
-during which the device is busy, the overlap that the pool's pipelining
-is there to buy.  Program spans and the device trace, on one clock."""
+"""pack_overlap.sync (pool): the share of ``bls.pack`` time in the traced
+part of the window during which the device is busy, the overlap that the
+pool's pipelining is there to buy.  Program spans and the device trace,
+on one clock."""
 
 from benchlib import trace
 
@@ -8,8 +9,8 @@ from benchlib import trace
 def read(ctx):
     if not ctx.spans or not ctx.device_ops:
         return None
-    lo, hi = ctx.run.open_t, ctx.run.close_t
-    busy = trace.busy(ctx.device_ops, lo, hi)
+    lo, hi = ctx.device_window()
+    busy = ctx.device_busy()
     pack = covered = 0.0
     for name, a, b, _ in ctx.spans:
         if name != "bls.pack" or not lo <= a < hi:

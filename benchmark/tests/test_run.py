@@ -59,6 +59,44 @@ class Flip:
         return (not ok) if self.calls % 3 == 0 else ok
 
 
+class Mesh:
+    """Stand-in for the sharded tier on four chips: the batch padded to its
+    bucket, each chip's quarter of the lanes verified on its own, and the
+    chips' verdicts combined; every dispatch counted as a mesh batch."""
+
+    n_devices = 4
+    fused = True
+    exchange = True
+
+    def __init__(self, inner):
+        self.inner = inner
+        self.dispatches = self.sharded_batches = 0
+
+    def verify_signature_sets(self, sets):
+        self.dispatches += 1
+        self.sharded_batches += 1
+        q = next(b for b in (4, 16, 64, 128, 256) if b >= len(sets)) // self.n_devices
+        ok = [self.inner.verify_signature_sets(sets[i:i + q])
+              for i in range(0, len(sets), q)]
+        return all(ok) if self.exchange else ok[0]
+
+
+class NoExchange(Mesh):
+    """Fault: the exchange between chips left out; the verdict is the
+    first chip's own."""
+
+    exchange = False
+
+
+class LeavesMesh(Mesh):
+    """Fault: every batch after the first placed on a single chip."""
+
+    def verify_signature_sets(self, sets):
+        ok = super().verify_signature_sets(sets)
+        self.sharded_batches = 1
+        return ok
+
+
 def native_system(fault=None):
     def make(pk, xy, workers):
         from lodestar_tpu.chain.bls_pool import BlsBatchPool
@@ -125,3 +163,27 @@ def test_control_fails(capsys, few_workers):
     assert not result["correct"]
     # every valid job is rejected: one job in ten is bad, so most mismatch
     assert result["checks"]["verdict_mismatch"]["value"] >= result["attempted"] // 2
+
+
+@pytest.mark.parametrize("seed", [5, 2**40 + 1])
+def test_sound_mesh_run_is_correct(capsys, few_workers, seed):
+    setup, result = run(capsys, "block", native_system(Mesh), seed=seed)
+    assert result["correct"], result["checks"]
+    assert setup["system"]["sharded_batches"] == setup["system"]["dispatches"] > 0
+
+
+@pytest.mark.parametrize("seed", [5, 2**40 + 1])
+def test_exchange_between_chips_left_out_is_caught(capsys, few_workers, seed):
+    """Only the first chip's lanes decide: a bad set on the other chips
+    (the tail, the second half) goes unseen, on any seed."""
+    _, result = run(capsys, dict(MIXES["block"], warm_bad=[]), native_system(NoExchange),
+                    seed=seed)
+    assert not result["correct"]
+    assert result["checks"]["verdict_mismatch"]["value"] >= 1
+
+
+def test_batches_off_the_mesh_fail_the_run(capsys, few_workers):
+    _, result = run(capsys, "block", native_system(LeavesMesh))
+    assert not result["correct"]
+    assert result["checks"]["verdict_mismatch"]["value"] == 0
+    assert result["checks"]["fallbacks"]["value"] > 0

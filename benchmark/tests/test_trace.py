@@ -60,6 +60,28 @@ def test_readers():
     assert load("block_verify_ms").read(ctx) == pytest.approx(1e3 * 8.0 / 2)
 
 
+def test_device_readers_read_the_traced_part_of_the_window():
+    """A trace that starts at 6.0, after the opening: only the second job
+    returned in it, and operation d lies in it from 9.0 to the close."""
+    ctx = _ctx()
+    ctx.trace_t = 6.0
+    assert ctx.device_window() == (6.0, 10.0)
+    assert [r.job for r in ctx.device_jobs] == [2]
+    assert load("device_ms.block").read(ctx) == pytest.approx(1e3 * 1.0)
+    assert load("device_idle.block").read(ctx) == pytest.approx(100 * (1 - 1.0 / 4.0))
+    assert load("pack_overlap.sync").read(ctx) is None  # no pack starts in it
+    ctx.spans = [("bls.pack", 8.0, 9.5, 2)]
+    ctx._busy = None
+    assert load("pack_overlap.sync").read(ctx) == pytest.approx(100 * 0.5 / 1.5)
+
+
+@pytest.mark.parametrize("seconds,chips,traced", [(51, 1, 51), (51, 4, 15), (10, 4, 10)])
+def test_a_traced_run_on_more_chips_traces_the_end_of_the_window(seconds, chips, traced):
+    from benchlib import cli
+
+    assert cli.traced_seconds(seconds, chips) == traced
+
+
 def test_readers_find_nothing_without_a_trace():
     ctx = _ctx()
     ctx.spans, ctx.device_ops = None, None
@@ -126,3 +148,29 @@ def test_reduction_on_a_recorded_chip_profile():
     spans = [(n, a, b) for n, a, b, _ in fx["spans"]]
     assert trace.attribute(longest, spans) == "pack"
     assert trace.idle_gaps_top(trace.gaps(busy, fx["lo"], fx["hi"]), spans)[0][0] == "pack"
+
+
+# two chips' planes, flattened as the harness hands them to the readers:
+# each runs its Miller loops, then the combine's collectives
+MESH_OPS = [("%while.521", 1.0, 3.0), ("%all-reduce.519", 3.0, 3.1), ("%all-gather.5", 3.1, 3.2),
+            ("%fusion.488", 3.2, 3.3),
+            ("%while.521", 1.0, 3.05), ("%all-reduce.519", 3.05, 3.15), ("%all-gather.5", 3.15, 3.2),
+            ("%fusion.488", 3.2, 3.3),
+            ("%all-gather-start.2", 6.0, 6.01), ("%all-gather-done.2", 6.01, 6.3),
+            ("%all-gather.5", 10.5, 11.0)]
+
+
+def test_combine_is_the_union_of_the_collectives_over_the_chips():
+    ctx = _ctx()
+    ctx.device_ops = MESH_OPS
+    # [3.0, 3.2] on either chip, then [6.0, 6.3]; the one after the close is out
+    assert load("combine_ms.sharded_block").read(ctx) == pytest.approx(1e3 * 0.5 / 2)
+
+
+def test_combine_finds_nothing_without_a_collective():
+    ctx = _ctx()
+    assert load("combine_ms.sharded_block").read(ctx) is None  # one chip: no collective
+    ctx.device_ops = None
+    assert load("combine_ms.sharded_block").read(ctx) is None
+    ctx.device_ops = [("%all-gather.5", 10.5, 11.0)]  # only after the window
+    assert load("combine_ms.sharded_block").read(ctx) is None
