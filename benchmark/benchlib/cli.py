@@ -26,8 +26,8 @@ import numpy as np
 
 from . import keys as keyreg
 from . import pyref, signer, trace
-from .loop import drive
-from .readers import Context, read_all
+from .loop import drive, drive_open
+from .readers import Context, Span, read_all
 from .traffic import Traffic, load_json
 from .workers import Workers, default_count, verify
 
@@ -40,7 +40,9 @@ REFERENCE_SAMPLE = 768
 # chip-seconds of the window a traced run gives the profiler: stopping it
 # costs the host ~1 s a traced chip-second of this program (~20 us a device
 # operation; 214 s for 51 s on four v5e chips), so a traced run on more
-# chips traces the last part of the window only (PERF.md, layers)
+# chips traces the last part of the window only (PERF.md, layers).  A mix
+# whose traffic makes many more device operations a second (small batches)
+# names its own ``trace_s``
 TRACE_CHIP_SECONDS = 60.0
 
 
@@ -80,9 +82,10 @@ def setup_env() -> None:
     os.environ.setdefault("TPU_LOG_DIR", os.path.join(CACHE, "tpu_logs"))
 
 
-def traced_seconds(seconds: float, chips: int) -> float:
-    """How much of the window, at its end, a traced run traces."""
-    return min(seconds, TRACE_CHIP_SECONDS / chips)
+def traced_seconds(seconds: float, chips: int, limit: Optional[float] = None) -> float:
+    """How much of a window of ``seconds``, at its end, a traced run
+    traces: ``limit`` seconds where the mix gives one."""
+    return min(seconds, TRACE_CHIP_SECONDS / chips if limit is None else limit)
 
 
 def check_device(chips: int):
@@ -219,7 +222,8 @@ def main(argv: List[str], t0: float, make_system: Optional[Callable] = None,
             TRACER.clear()
             TRACER.enable(1 << 17)
 
-        untraced = opts.seconds - traced_seconds(opts.seconds, cell["chips"])
+        untraced = traffic.window_s - traced_seconds(traffic.window_s, cell["chips"],
+                                                     mix.get("trace_s"))
         starter = []
 
         def on_open() -> None:
@@ -237,7 +241,8 @@ def main(argv: List[str], t0: float, make_system: Optional[Callable] = None,
                 profiler.stop()
 
         t_warm = time.monotonic()
-        run = asyncio.run(drive(system, traffic, prepared, opts.seconds, on_open, on_close))
+        driver = drive_open if traffic.open_loop else drive
+        run = asyncio.run(driver(system, traffic, prepared, opts.seconds, on_open, on_close))
         steps["warm_s"] = run.open_t - t_warm
         captured = profiler.read() if profiler else None
         spans = None
@@ -245,7 +250,7 @@ def main(argv: List[str], t0: float, make_system: Optional[Callable] = None,
             from lodestar_tpu.tracing import TRACER
 
             TRACER.disable()
-            spans = [(s.name, s.ts_ns / 1e9, (s.ts_ns + s.dur_ns) / 1e9, s.cid)
+            spans = [Span(s.name, s.ts_ns / 1e9, (s.ts_ns + s.dur_ns) / 1e9, s.cid, s.args)
                      for s in TRACER.spans()]
         memory = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                      for d in devices[:cell["chips"]]) if devices else 0
@@ -260,7 +265,8 @@ def main(argv: List[str], t0: float, make_system: Optional[Callable] = None,
                       "program_load": program_load(COMPILE_LEDGER.session_summary()),
                       "window_compiles": counter.count},
             "traffic": {**traffic.counts(), "reused_jobs": traffic.reused,
-                        "window_jobs": len(run.window), "window_s": run.seconds},
+                        "window_jobs": len(run.window), "window_s": run.seconds,
+                        **run.notes()},
             "system": counters,
         }
         print(json.dumps(setup), flush=True)

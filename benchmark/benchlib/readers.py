@@ -8,14 +8,26 @@ from __future__ import annotations
 import dataclasses
 import importlib.util
 import os
-from typing import Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 from . import trace
 from .loop import Record, Run
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-Span = Tuple[str, float, float, Optional[int]]  # name, start, end, batch id
+
+class Span(tuple):
+    """A program span, ``(name, start, end, batch id)`` in monotonic
+    seconds, with the program's attributes of it (``lane``, ``sets``, ...)
+    in ``attrs``.  It unpacks and compares as that 4-tuple."""
+
+    attrs: Dict[str, Any]
+
+    def __new__(cls, name: str, start: float, end: float, cid: Optional[int] = None,
+                attrs: Optional[Dict[str, Any]] = None) -> "Span":
+        span = super().__new__(cls, (name, start, end, cid))
+        span.attrs = dict(attrs or {})
+        return span
 
 
 @dataclasses.dataclass
@@ -40,8 +52,12 @@ class Context:
         the window; None without spans."""
         if not self.spans:
             return None
+        return sum(b - a for _, a, b, _ in self.window_spans(name))
+
+    def window_spans(self, name: str) -> List[Span]:
+        """The spans called ``name`` that start inside the window."""
         lo, hi = self.run.open_t, self.run.close_t
-        return sum(b - a for n, a, b, _ in self.spans if n == name and lo <= a < hi)
+        return [s for s in self.spans or () if s[0] == name and lo <= s[1] < hi]
 
     def device_window(self) -> Tuple[float, float]:
         """The part of the window the device trace covers: all of it, or
@@ -68,6 +84,15 @@ class Context:
         device operations in the trace."""
         busy = self.device_busy()
         return None if busy is None else trace.total(busy)
+
+    def device_idle(self) -> Optional[float]:
+        """The share, in %, of the device window in which no operation ran
+        on the device; None where the trace shows it busy for no time."""
+        busy = self.device_busy_s()
+        if not busy:
+            return None
+        lo, hi = self.device_window()
+        return 100.0 * (1.0 - busy / (hi - lo))
 
 
 def load(name: str):

@@ -33,7 +33,8 @@ def test_sharded_block_mix_is_the_block_mix():
 
 
 @pytest.mark.parametrize("cell,chips", [("block.mainnet-500k", 1), ("sync.mainnet-1m", 1),
-                                        ("sharded_block.mainnet-500k", 4)])
+                                        ("sharded_block.mainnet-500k", 4),
+                                        ("gossip.mainnet-500k", 1)])
 def test_pool_runs_on_the_cells_chips(cell, chips):
     from lodestar_tpu import cli as program_cli
 

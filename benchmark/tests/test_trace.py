@@ -82,6 +82,13 @@ def test_a_traced_run_on_more_chips_traces_the_end_of_the_window(seconds, chips,
     assert cli.traced_seconds(seconds, chips) == traced
 
 
+@pytest.mark.parametrize("seconds,limit,traced", [(60, 12, 12), (10, 12, 10)])
+def test_a_mix_can_trace_less_of_the_window(seconds, limit, traced):
+    from benchlib import cli
+
+    assert cli.traced_seconds(seconds, 1, limit) == traced
+
+
 def test_readers_find_nothing_without_a_trace():
     ctx = _ctx()
     ctx.spans, ctx.device_ops = None, None
