@@ -12,8 +12,14 @@ Mechanics kept from the reference, retuned for a TPU dispatch:
   then flush (MAX_BUFFER_WAIT_MS=100 / MAX_BUFFERED_SIGS=32 analog,
   multithread/index.ts:41-57; both configurable because the optimal values
   are dispatch-latency dependent, not core-count dependent).
-- a failed merged batch is retried per job so one bad gossip message
+- a failed merged batch is re-checked per job so one bad gossip message
   cannot poison its batchmates (worker.ts:78-88 retry-individually).
+  Two departures, because a re-run here is a whole device program, not
+  one blst call: a failed batch of ONE job is final (the batch check
+  never fails a batch whose sets all verify, so a second run cannot
+  change the answer), and the jobs of a failed batch of several re-run
+  as batches of one through the same pipelined window as every other
+  batch, not one blocking call after another (see ``_flush``).
 - accumulation happens through JobItemQueue.drain_batch — the queue seam
   built for exactly this (utils/queue.py).
 
@@ -423,6 +429,104 @@ class BlsBatchPool:
             )
         return live
 
+    def _admit(self, drained: List[Tuple], cid: int, inflight: int, window: int):
+        """Turn a drained (and shed) batch into ``(jobs, merged sets,
+        tightest deadline)``, recording each job's queue wait and the
+        coalescing decision."""
+        now = time.monotonic()
+        jobs: List[Tuple] = []
+        merged: List[SignatureSet] = []
+        batch_deadline: Optional[float] = None
+        for item, fut, t_enq, lane, deadline in drained:
+            jobs.append((item, fut, lane, t_enq, deadline))
+            merged.extend(item)
+            if deadline is not None:
+                batch_deadline = (
+                    deadline if batch_deadline is None
+                    else min(batch_deadline, deadline)
+                )
+            if self.metrics:
+                self.metrics.bls_queue_wait_seconds.labels(
+                    lane=_lane_name(lane)
+                ).observe(now - t_enq)
+            if TRACER.enabled:
+                TRACER.add_span(
+                    "bls.queue_wait", "queue",
+                    int(t_enq * 1e9), int(now * 1e9),
+                    cid=cid, sets=len(item), lane=_lane_name(lane),
+                )
+        self._update_backpressure()
+        self._publish_lane_gauges()
+        if self.metrics:
+            self.metrics.bls_pool_dispatches_total.inc()
+            self.metrics.bls_pool_batch_size.observe(len(merged))
+        # black box: the coalescing decision (how many jobs merged into
+        # this batch, window occupancy at the time)
+        if JOURNAL.enabled:
+            JOURNAL.record(
+                "pool.flush", cid=cid, jobs=len(jobs), sets=len(merged),
+                inflight=inflight, window=window,
+            )
+        return jobs, merged, batch_deadline
+
+    async def _enqueue(self, sets: List[SignatureSet], deadline: Optional[float], cid: int):
+        """Pack and enqueue one batch on a worker thread; return its verdict
+        task and the executor the scheduler picked (None when unknown).
+
+        A pack/enqueue failure must NOT strand the batch's jobs: it comes
+        back as a verdict that raises, which the drain half keeps apart
+        from ``False`` — a failed dispatch is not an invalid signature."""
+        # correlation id rides the contextvar into to_thread and
+        # create_task (both copy the current context), so the verifier's
+        # pack/dispatch/final-exp spans pick it up without widening the
+        # IBlsVerifier API
+        token = tracing.set_batch(cid)
+        try:
+            if not self._use_async:
+                return asyncio.create_task(
+                    asyncio.to_thread(self.verifier.verify_signature_sets, sets)
+                ), None
+            # returns once the device program is ENQUEUED, not finished.
+            # The batch's tightest job deadline rides along so dispatch
+            # placement / the in-flight table see it.
+            if self._accepts_deadline:
+                pending = await asyncio.to_thread(
+                    self.verifier.verify_signature_sets_async, sets,
+                    deadline=deadline,
+                )
+            else:
+                pending = await asyncio.to_thread(
+                    self.verifier.verify_signature_sets_async, sets
+                )
+            # executor name the scheduler picked (None for a chunked batch
+            # spread over several devices)
+            return asyncio.create_task(asyncio.to_thread(pending.result)), getattr(
+                pending, "device", None
+            )
+        except Exception as e:  # noqa: BLE001
+            logger.warning("dispatch enqueue failed: %s; will re-run per job", e)
+            verdict = asyncio.get_running_loop().create_future()
+            verdict.set_exception(e)
+            return verdict, None
+        finally:
+            tracing.reset_batch(token)
+
+    def _needs_recheck(self, job: Tuple) -> bool:
+        """False for a job of a failed batch that must not be re-run: its
+        pusher was cancelled (future done), or the pool closed — then it
+        resolves with the typed shutdown drop, never a dispatch, so an
+        awaiting validator task does not hang on a pool that no longer
+        has a verifier behind it."""
+        item, fut, lane, _t_enq, _deadline = job
+        if fut.done():
+            return False
+        if self._closed:
+            lane_p = SignatureSetPriority(lane)
+            self._count_drop("shutdown", lane_p, len(item))
+            fut.set_exception(VerificationDroppedError("shutdown", lane_p))
+            return False
+        return True
+
     async def _flush(self) -> None:
         """Pipelined drain: keep up to ``pipeline_depth * n_devices``
         merged batches in flight.  The fill half sheds expired jobs, then
@@ -432,21 +536,32 @@ class BlsBatchPool:
         exponentiation of batch N runs concurrently with the device
         compute of batch N+1, and a multi-device verifier's scheduler
         sees enough batches to feed every chip.  Batches drain
-        lane-ordered: the queue hands back block proposals first."""
+        lane-ordered: the queue hands back block proposals first.
+
+        A failed batch: the batch check is a random linear combination of
+        its sets, so it cannot fail a batch whose sets all verify.  A
+        ``False`` for a batch of ONE job is therefore that job's verdict.
+        A failed batch of several jobs puts them on ``rechecks``, which
+        the fill half takes before the queue (they are the oldest jobs):
+        each re-runs as its own batch of one through the same window, so
+        re-check k+1 packs while re-check k is on the device, and its
+        verdict is final.  A verdict that RAISES is no verdict: its jobs
+        are re-run once, and a second raise reaches the caller."""
         self._flushing = True
-        use_async = self._use_async
-        accepts_deadline = self._accepts_deadline
+        # (jobs, sets, verdict, t_fill, t_enqueued, cid, device, parent):
+        # ``parent`` is the failed batch's cid for a re-check, else None
         inflight: collections.deque = collections.deque()
+        # (job, failed batch's cid) awaiting a re-run as a batch of one
+        rechecks: Deque[Tuple[Tuple, int]] = collections.deque()
         flush_t0 = time.monotonic()
         busy = 0.0  # sum of per-batch pack-start->verdict wall (overlap ratio)
         sets_done = 0  # sets resolved this flush (per-chip throughput gauge)
-        # pipeline_depth is per device: a multi-chip executor pool wants
-        # enough batches in flight to keep every chip busy.  With the
-        # sharded tier active the merge cap grows so storm backlogs form
-        # mesh-wide batches — see _flush_window.
-        window, max_size = self._flush_window()
         try:
-            while len(self._queue) or inflight:
+            while len(self._queue) or inflight or rechecks:
+                # pipeline_depth is per device: a multi-chip executor pool
+                # wants enough batches in flight to keep every chip busy.
+                # With the sharded tier active the merge cap grows so storm
+                # backlogs form mesh-wide batches — see _flush_window.
                 window, max_size = self._flush_window()
                 # fill the window.  max_size keeps each merged batch near
                 # the dispatch-sized flush_threshold even when a storm
@@ -454,103 +569,39 @@ class BlsBatchPool:
                 # if the block lane rides the NEXT batch, not the middle
                 # of one mega-batch (a single oversized job still drains
                 # alone and chunks verifier-side).
-                while len(self._queue) and len(inflight) < window:
-                    drained = self._queue.drain_batch(
-                        max_items=1024, with_meta=True,
-                        max_size=max_size,
-                    )
-                    if not drained:
-                        break
+                while (rechecks or len(self._queue)) and len(inflight) < window:
                     cid = self._next_batch_id
-                    self._next_batch_id += 1
-                    drained = self._shed_expired(drained, cid)
-                    if not drained:
-                        self._update_backpressure()
-                        continue  # the whole drain was expired backlog
-                    now = time.monotonic()
-                    jobs: List = []
-                    merged: List[SignatureSet] = []
-                    batch_deadline: Optional[float] = None
-                    for item, fut, t_enq, lane, deadline in drained:
-                        jobs.append((item, fut, lane, t_enq))
-                        merged.extend(item)
-                        if deadline is not None:
-                            batch_deadline = (
-                                deadline if batch_deadline is None
-                                else min(batch_deadline, deadline)
-                            )
+                    if rechecks:
+                        job, parent = rechecks.popleft()
+                        if not self._needs_recheck(job):
+                            continue
+                        self._next_batch_id += 1
                         if self.metrics:
-                            self.metrics.bls_queue_wait_seconds.labels(
-                                lane=_lane_name(lane)
-                            ).observe(now - t_enq)
-                        if TRACER.enabled:
-                            TRACER.add_span(
-                                "bls.queue_wait", "queue",
-                                int(t_enq * 1e9), int(now * 1e9),
-                                cid=cid, sets=len(item), lane=_lane_name(lane),
-                            )
-                    self._update_backpressure()
-                    self._publish_lane_gauges()
-                    if self.metrics:
-                        self.metrics.bls_pool_dispatches_total.inc()
-                        self.metrics.bls_pool_batch_size.observe(len(merged))
-                    # black box: the coalescing decision (how many jobs
-                    # merged into this batch, window occupancy at the time)
-                    if JOURNAL.enabled:
-                        JOURNAL.record(
-                            "pool.flush", cid=cid, jobs=len(jobs),
-                            sets=len(merged), inflight=len(inflight),
-                            window=window,
+                            self.metrics.bls_pool_recheck_jobs_total.inc()
+                        # no queue-wait span, no pool.batch: the job's wait
+                        # and its batch were recorded when it first drained
+                        jobs, merged, batch_deadline = [job], job[0], job[4]
+                    else:
+                        parent = None
+                        drained = self._queue.drain_batch(
+                            max_items=1024, with_meta=True,
+                            max_size=max_size,
                         )
-                    # correlation id rides the contextvar into to_thread and
-                    # create_task (both copy the current context), so the
-                    # verifier's pack/dispatch/final-exp spans pick it up
-                    # without widening the IBlsVerifier API
+                        if not drained:
+                            break
+                        self._next_batch_id += 1
+                        drained = self._shed_expired(drained, cid)
+                        if not drained:
+                            self._update_backpressure()
+                            continue  # the whole drain was expired backlog
+                        jobs, merged, batch_deadline = self._admit(
+                            drained, cid, len(inflight), window
+                        )
                     t_fill = time.monotonic()  # batch busy starts at pack
-                    device = None
-                    token = tracing.set_batch(cid)
-                    try:
-                        if use_async:
-                            # pack on a worker thread; returns once the
-                            # device program is ENQUEUED, not finished.  The
-                            # batch's tightest job deadline rides along so
-                            # dispatch placement / the in-flight table see it.
-                            if accepts_deadline:
-                                pending = await asyncio.to_thread(
-                                    self.verifier.verify_signature_sets_async,
-                                    merged, deadline=batch_deadline,
-                                )
-                            else:
-                                pending = await asyncio.to_thread(
-                                    self.verifier.verify_signature_sets_async,
-                                    merged,
-                                )
-                            # executor name the scheduler picked (None for a
-                            # chunked batch spread over several devices)
-                            device = getattr(pending, "device", None)
-                            verdict = asyncio.create_task(
-                                asyncio.to_thread(pending.result)
-                            )
-                        else:
-                            verdict = asyncio.create_task(
-                                asyncio.to_thread(
-                                    self.verifier.verify_signature_sets, merged
-                                )
-                            )
-                    except Exception as e:  # noqa: BLE001
-                        # a pack/enqueue failure must NOT strand the drained
-                        # jobs' futures: feed a failed verdict through the
-                        # normal drain half so the per-job retry resolves
-                        # every caller
-                        logger.warning(
-                            "dispatch enqueue failed: %s; will retry per job", e
-                        )
-                        verdict = asyncio.get_running_loop().create_future()
-                        verdict.set_result(False)
-                    finally:
-                        tracing.reset_batch(token)
+                    verdict, device = await self._enqueue(merged, batch_deadline, cid)
                     inflight.append(
-                        (jobs, merged, verdict, t_fill, time.monotonic(), cid, device)
+                        (jobs, merged, verdict, t_fill, time.monotonic(), cid,
+                         device, parent)
                     )
                     self.inflight_peak = max(self.inflight_peak, len(inflight))
                     if self.metrics:
@@ -558,16 +609,39 @@ class BlsBatchPool:
                 if not inflight:
                     return
                 # drain the oldest batch
-                jobs, merged, verdict, t_fill, t0, cid, device = inflight.popleft()
+                jobs, merged, verdict, t_fill, t0, cid, device, parent = inflight.popleft()
+                raised: Optional[Exception] = None
                 try:
                     ok = await verdict
                 except Exception as e:  # noqa: BLE001
-                    logger.warning("merged dispatch raised: %s; retrying per job", e)
-                    ok = False
+                    logger.warning("dispatch raised: %s; re-running per job", e)
+                    ok, raised = False, e
                 t_done = time.monotonic()
                 # busy counts from pack start so a fully serial pipeline
                 # reads ~1.0 (the documented baseline), overlap reads >1
                 busy += t_done - t_fill
+                if self.metrics:
+                    self.metrics.bls_pool_inflight_depth.set(len(inflight))
+                if parent is not None:
+                    # a re-check: a batch of one job, its verdict final
+                    item, fut, lane, t_enq, _deadline = jobs[0]
+                    if TRACER.enabled:
+                        TRACER.add_span(
+                            "pool.recheck", "pool", int(t_fill * 1e9),
+                            int(t_done * 1e9), cid=cid, parent=parent,
+                            sets=len(item), lane=_lane_name(lane), ok=bool(ok),
+                            device=device,
+                        )
+                    # a pusher cancelled during the re-check left its future
+                    # done: setting it raises and would kill the flusher
+                    if fut.done():
+                        continue
+                    if raised is not None:
+                        fut.set_exception(raised)
+                    else:
+                        fut.set_result(bool(ok))
+                        self._observe_e2e(lane, t_done - t_enq)
+                    continue
                 sets_done += len(merged)
                 if TRACER.enabled:
                     TRACER.add_span(
@@ -577,44 +651,35 @@ class BlsBatchPool:
                     )
                 if self.metrics:
                     self.metrics.bls_pool_dispatch_seconds.observe(t_done - t0)
-                    self.metrics.bls_pool_inflight_depth.set(len(inflight))
                 if ok:
                     self.batch_sets_success += len(merged)
-                    for item, fut, lane, t_enq in jobs:
+                    for item, fut, lane, t_enq, _deadline in jobs:
                         # e2e observes DELIVERED verdicts only: a pusher
                         # cancelled mid-flight (fut already done) never
-                        # received one, and the retry path below skips
+                        # received one, and the re-check path skips
                         # those too — the histogram must agree
                         if not fut.done():
                             fut.set_result(True)
                             self._observe_e2e(lane, t_done - t_enq)
                     continue
-                # merged batch failed: re-verify each job individually so
-                # innocent jobs still succeed (worker.ts:78-88)
                 self.batch_retries += 1
-                logger.debug("merged batch of %d jobs failed; retrying individually", len(jobs))
-                for item, fut, lane, t_enq in jobs:
-                    if fut.done():
-                        continue
-                    if self._closed:
-                        # shutdown mid-retry: resolve (typed), never strand —
-                        # an awaiting validator task must not hang forever
-                        # on a pool that no longer has a verifier behind it
-                        lane_p = SignatureSetPriority(lane)
-                        self._count_drop("shutdown", lane_p, len(item))
-                        fut.set_exception(
-                            VerificationDroppedError("shutdown", lane_p)
-                        )
-                        continue
-                    try:
-                        one = await asyncio.to_thread(self.verifier.verify_signature_sets, item)
-                    except Exception as e:  # noqa: BLE001
-                        if not fut.done():  # pusher cancelled during the await
-                            fut.set_exception(e)
-                        continue
-                    if not fut.done():  # ditto — set on a cancelled future
-                        fut.set_result(one)  # raises and would kill the flusher
-                        self._observe_e2e(lane, time.monotonic() - t_enq)
+                if self.metrics:
+                    self.metrics.bls_pool_failed_batches_total.labels(
+                        jobs="one" if len(jobs) == 1 else "many"
+                    ).inc()
+                if len(jobs) == 1 and raised is None:
+                    # a lone job's False is its verdict: re-running it
+                    # would pay a whole device program for the same answer
+                    item, fut, lane, t_enq, _deadline = jobs[0]
+                    if not fut.done():
+                        fut.set_result(False)
+                        self._observe_e2e(lane, t_done - t_enq)
+                    continue
+                # several jobs (or a raise): re-check each as its own batch
+                # so innocent jobs still succeed (worker.ts:78-88), through
+                # the pipeline rather than one blocking call after another
+                logger.debug("batch %d of %d jobs failed; re-checking each", cid, len(jobs))
+                rechecks.extend((job, cid) for job in jobs)
         finally:
             self._flushing = False
             self._update_backpressure()

@@ -84,6 +84,18 @@ class Metrics:
         self.bls_pool_dispatches_total = r.counter(
             "lodestar_bls_pool_dispatches_total", "device batch-verify dispatches"
         )
+        self.bls_pool_failed_batches_total = r.counter(
+            "lodestar_bls_pool_failed_batches_total",
+            "drained batches whose verdict was not True, by the jobs they "
+            "held: one (a False is that job's verdict) or many (each job "
+            "re-checked as a batch of one)",
+            labels=("jobs",),
+        )
+        self.bls_pool_recheck_jobs_total = r.counter(
+            "lodestar_bls_pool_recheck_jobs_total",
+            "jobs of a failed batch re-run as batches of one through the "
+            "pool's pipeline",
+        )
         self.bls_pool_sets_total = r.counter(
             "lodestar_bls_pool_sets_total", "signature sets verified", labels=("result",)
         )

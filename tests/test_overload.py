@@ -489,15 +489,15 @@ class TestPoolLanes:
     def test_close_during_flush_strands_nothing(self):
         """Satellite regression: close() while a flush has batches in
         flight — every already-drained job future still resolves, and the
-        per-job retry loop respects _closed (typed shutdown drop, no
-        stranded awaits, no further verifier calls)."""
+        pipelined re-check respects _closed (typed shutdown drop, no
+        stranded awaits, no re-check dispatched)."""
 
         async def main():
             release = __import__("threading").Event()
 
             class BlockingFalseVerifier(RecordingVerifier):
-                """First merged verdict blocks until released, then returns
-                False so the pool enters the per-job retry loop."""
+                """Every verdict blocks until released, then returns False,
+                so the merged batch fails and its jobs await a re-check."""
 
                 def verify_signature_sets_async(self, sets, deadline=None):
                     self.batches.append(list(sets))
@@ -513,9 +513,6 @@ class TestPoolLanes:
                     return _Pending()
 
             v = BlockingFalseVerifier()
-            retried = []
-            real_single = v.verify_signature_sets
-            v.verify_signature_sets = lambda sets: retried.append(sets) or True
             pool = BlsBatchPool(v, max_buffer_wait=0.005, pipeline_depth=1)
             jobs = [
                 asyncio.create_task(
@@ -531,14 +528,14 @@ class TestPoolLanes:
                 asyncio.gather(*jobs, return_exceptions=True), timeout=5.0
             )
             # nothing stranded: every future resolved, each with the typed
-            # shutdown drop (the batch failed and retry found the pool closed)
+            # shutdown drop (the batch failed and the re-check found the
+            # pool closed)
             assert len(results) == 3
             for r in results:
                 assert isinstance(r, VerificationDroppedError)
                 assert r.reason == "shutdown"
-            assert retried == []  # _closed checked before any retry dispatch
+            assert v.dispatches == 1  # _closed checked before any re-check
             assert pool.dropped_sets == {("shutdown", "unaggregated"): 3}
-            del real_single
 
         run(main())
 
@@ -570,8 +567,8 @@ class TestPoolLanes:
         run(main())
 
     def test_pusher_cancelled_mid_retry_does_not_kill_flusher(self):
-        """A caller cancelled while its job is being retried individually
-        cancels the job future; the retry loop must not set_result on it
+        """A caller cancelled while its job is being re-checked cancels
+        the job future; the re-check's verdict must not be set on it
         (InvalidStateError would kill the flusher and strand every other
         in-flight job)."""
 
@@ -581,20 +578,24 @@ class TestPoolLanes:
             release = threading.Event()
 
             class SlowRetryVerifier(RecordingVerifier):
+                """Each job's own truth: A and B are valid alone, so only
+                the merged batch reads False; a re-check's verdict blocks
+                until released."""
+
                 def verify_signature_sets_async(self, sets, deadline=None):
                     self.batches.append(list(sets))
+                    merged = len(sets) > 1
 
                     class _Pending:
                         device = "stub:0"
 
                         def result(_self):
-                            return False  # force retry-individually
+                            if merged:
+                                return False  # force the per-job re-check
+                            release.wait(5.0)
+                            return True
 
                     return _Pending()
-
-                def verify_signature_sets(self, sets):
-                    release.wait(5.0)  # per-job retry blocks until released
-                    return True
 
             v = SlowRetryVerifier()
             pool = BlsBatchPool(v, max_buffer_wait=0.005, pipeline_depth=1)
@@ -604,8 +605,9 @@ class TestPoolLanes:
             t_b = asyncio.create_task(
                 pool.verify_signature_sets([("b", 0)], priority=UNAGG)
             )
-            await asyncio.sleep(0.05)  # merged batch failed; retry of A blocked
-            t_a.cancel()  # cancels A's job future mid-retry-await
+            await asyncio.sleep(0.05)  # merged batch failed; re-check of A blocked
+            assert v.batches[-1] == [("a", 0)]
+            t_a.cancel()  # cancels A's job future mid-re-check
             await asyncio.sleep(0.01)
             release.set()
             with pytest.raises(asyncio.CancelledError):
