@@ -164,8 +164,8 @@ def check_fused_on_tpu(v) -> None:
     assert v.fused is True, f"verifier not on the fused path (fused={v.fused})"
     programs = [(key, fn) for ex in v._executors for key, fn in ex.compiled.items()]
     assert programs, "no program materialized"
-    for (n, host_final_exp, fused), fn in programs:
-        assert fused and host_final_exp, f"bucket {n}: not the fused split program"
+    for (n, fused), fn in programs:
+        assert fused, f"bucket {n}: not the fused split program"
         for s in jax.tree.leaves(fn.input_shardings):
             plats = {d.platform for d in s.device_set}
             assert plats == {"tpu"}, f"bucket {n}: program on {plats}"

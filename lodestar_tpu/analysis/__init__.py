@@ -39,7 +39,6 @@ Five layers, one report format (``report.Violation``):
   — the contract layer for ROADMAP item 3's remote-DMA pairing v2.
 
 ``tools/lint.py`` drives all five and exits nonzero on violations;
-``bench.py`` runs the same suite as a pre-flight stage;
 ``tools/tier1_budget.py --enforce`` combines the compile-cost layer with
 the wall-clock margin gate.  The rule catalogue (with the incident
 behind each rule and the inline-suppression syntax) is
@@ -61,7 +60,7 @@ def run_all(
     with_pallas: bool = True,
 ) -> List[Violation]:
     """Every analysis layer, one violation list — the entry point
-    tools/lint.py, bench.py's pre-flight stage, and the tier-1 tests share
+    tools/lint.py and the tier-1 tests share
     (lazy imports keep `import lodestar_tpu.analysis` jax-free)."""
     import os
 

@@ -22,8 +22,8 @@ tree and library by AST + a one-level import graph and maps every
   (``verify_signature_sets*`` / ``dispatch`` / ``warmup*`` / handing the
   verifier to a ``BlsBatchPool``) that actually materialize programs;
 - the per-(entry, bucket) program key each real construction implies,
-  derived exactly like ``tpu_verifier._entry_name`` (``fused``/
-  ``host_final_exp`` kwargs x ``buckets``).
+  derived exactly like ``tpu_verifier._entry_name`` (``fused`` kwarg x
+  ``buckets``).
 
 The static map is then cross-checked against the runtime ledgers
 (``.jax_cache/tier1_timings.json`` per-test compile-guard events) and
@@ -45,7 +45,7 @@ the conftest ``COMPILE_WHITELIST``, emitting four typed violations:
 
 Everything here is stdlib-only (ast/json/fnmatch): importing this module
 never imports jax, so the auditor itself runs inside the tier-1 compile
-guard and in bench.py's pre-flight lint stage.
+guard and in tools/lint.py.
 """
 
 from __future__ import annotations
@@ -129,7 +129,7 @@ class ConstructInfo:
     line: int
     ctor: str
     buckets: Tuple[int, ...]
-    entry: str  # xla_split / xla_full / fused_split / fused_full
+    entry: str  # xla_split / fused_split
     stubbed: bool = False
 
     @property
@@ -230,10 +230,7 @@ def _collect_imports(tree: ast.Module) -> Dict[str, str]:
 def _entry_for_kwargs(kwargs: Dict[str, object]) -> str:
     """Static twin of tpu_verifier._entry_name: fused=None resolves to
     the XLA path on the CPU backend tier-1 runs on."""
-    fused = bool(kwargs.get("fused") or False)
-    host_final_exp = kwargs.get("host_final_exp", True)
-    side = "split" if host_final_exp else "full"
-    return f"{'fused' if fused else 'xla'}_{side}"
+    return "fused_split" if kwargs.get("fused") else "xla_split"
 
 
 class _BodyScanner(ast.NodeVisitor):

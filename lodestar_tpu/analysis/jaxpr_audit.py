@@ -64,7 +64,7 @@ body's collective structure:
 
 ``trace_entry`` is lru-cached per (entry, bucket): the alignment contract
 test, the static-analysis test, and tools/lint.py share one trace — the
-trace of the full fused graph is the expensive part (~15-30 s), so it is
+trace of the fused graph is the expensive part (~15-30 s), so it is
 paid once per process.
 
 On top of that, the audit is INCREMENTAL across processes: everything the
@@ -112,7 +112,7 @@ _COLLECTIVE_PRIMITIVES = (
 )
 
 #: pow-x window scans one final exponentiation contributes (the x-chain:
-#: y0, y1, y2 and y3's double pow — fused_pairing.final_exponentiation)
+#: y0, y1, y2 and y3's double pow — pairing.final_exponentiation)
 FINAL_EXP_POW_SCANS = 5
 
 
@@ -145,14 +145,14 @@ def _abstract_batch(n: int):
 def entry_points() -> Dict[str, dict]:
     """name -> {fn, mosaic}: plain functions of the abstract batch args.
 
-    The two fused programs cover the whole Pallas call graph
+    The fused split program covers the whole Pallas call graph
     (fused_points / fused_pairing / fused_htc / fused_ladder /
-    fused_field / fused_core are all reached from them) and are the
-    Mosaic-bound entries; the two XLA-graph kernels are the portable
-    twins TpuBlsVerifier degrades to (``mosaic=False`` — XLA retiles
-    narrow concats fine, so the concat rule does not apply to them).
-    Fused entries trace with interpret=True — interpret only affects
-    lowering, and tracing must not require a TPU plugin."""
+    fused_field / fused_core are all reached from it) and is the
+    Mosaic-bound entry; the XLA-graph kernel is the portable twin
+    TpuBlsVerifier degrades to (``mosaic=False`` — XLA retiles narrow
+    concats fine, so the concat rule does not apply to it).  The fused
+    entry traces with interpret=True — interpret only affects lowering,
+    and tracing must not require a TPU plugin."""
     from ..ops import batch_verify as bv
     from ..ops import fused_verify as fv
 
@@ -160,17 +160,10 @@ def entry_points() -> Dict[str, dict]:
         f, ok = fv.miller_product_fused(*a, interpret=True)
         return f.a, ok  # digits + verdict (the static bound is not an output)
 
-    def fused_full(*a):
-        return fv.verify_signature_sets_fused(*a, interpret=True)
-
     return {
         "fused_verify.miller_product_fused": {"fn": fused_split, "mosaic": True},
-        "fused_verify.verify_signature_sets_fused": {"fn": fused_full, "mosaic": True},
         "batch_verify.miller_product_kernel": {
             "fn": bv.miller_product_kernel, "mosaic": False,
-        },
-        "batch_verify.verify_signature_sets_kernel": {
-            "fn": bv.verify_signature_sets_kernel, "mosaic": False,
         },
     }
 
@@ -190,11 +183,11 @@ def sharded_audit_available() -> bool:
 
 @functools.lru_cache(maxsize=1)
 def sharded_entry_points() -> Dict[str, dict]:
-    """name -> {fn, mosaic, sharded}: the round-11 mesh entry points over
-    a SHARDED_AUDIT_MESH-device mesh.  The fused flavor traces with
-    interpret=True (lowering-only difference, no TPU plugin needed) and
-    carries the Mosaic concat rules; the XLA full flavor carries the
-    final-exp placement the full path runs on device."""
+    """name -> {fn, mosaic, sharded}: the round-11 mesh entry point over
+    a SHARDED_AUDIT_MESH-device mesh.  It traces with interpret=True
+    (lowering-only difference, no TPU plugin needed) and carries the
+    Mosaic concat rules and the final-exp placement rule (the mesh
+    program leaves the final exponentiation to the host)."""
     from ..ops import sharded_verify as sv
 
     mesh = sv.make_mesh(n_devices=SHARDED_AUDIT_MESH)
@@ -202,11 +195,6 @@ def sharded_entry_points() -> Dict[str, dict]:
         "sharded_verify.miller_product_sharded": {
             "fn": sv.miller_product_sharded(mesh, fused=True, interpret=True),
             "mosaic": True,
-            "sharded": True,
-        },
-        "sharded_verify.verify_signature_sets_sharded": {
-            "fn": sv.verify_signature_sets_sharded(mesh, fused=False),
-            "mosaic": False,
             "sharded": True,
         },
     }

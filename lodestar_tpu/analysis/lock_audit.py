@@ -361,14 +361,17 @@ def _make_sets(n: int, start: int = 0):
 def _stub_verifier(point_cache_size: int = 64):
     """Real TpuBlsVerifier (real pack, real scheduler, real counters) whose
     per-executor programs are host stubs — zero XLA trace/compile work."""
+    import numpy as np
+
     from ..crypto.bls.tpu_verifier import TpuBlsVerifier
+    from ..ops.tower import FQ12_ONE
 
     v = TpuBlsVerifier(
-        buckets=(4,), fused=False, host_final_exp=False,
-        point_cache_size=point_cache_size,
+        buckets=(4,), fused=False, point_cache_size=point_cache_size,
     )
+    one = np.asarray(FQ12_ONE, dtype=np.float32)
     for ex in v._executors:
-        ex.compiled[(4, False, False)] = lambda *a: True
+        ex.compiled[(4, False)] = lambda *a: (one, True)
     return v
 
 

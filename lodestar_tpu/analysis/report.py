@@ -38,7 +38,7 @@ def format_report(violations: Iterable[Violation]) -> str:
 
 
 def to_dicts(violations: Iterable[Violation]) -> List[dict]:
-    """JSON-ready form (bench.py extras, --json output)."""
+    """JSON-ready form (--json output)."""
     return [dataclasses.asdict(v) for v in violations]
 
 

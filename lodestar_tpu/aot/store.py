@@ -22,12 +22,14 @@ Key schema (one entry per fully-resolved program identity)::
 - **topology** — ``{platform}x{device_count}`` of the process that
   compiled (a serialized executable embeds its device assignment; a
   process with a different local topology must miss, not crash);
-- **entry** — the compile-ledger entry label (``fused_split`` /
-  ``fused_full`` / ``xla_split`` / ``xla_full``);
+- **entry** — the compile-ledger entry label: ``fused_split`` (the
+  program every TPU cell serves), ``xla_split`` (the portable twin a
+  failed fused compile degrades to) or ``sharded_split`` (the mesh
+  program);
 - **bucket** — the padded batch size (one program per bucket);
-- **device** — the executor's pinned ordinal (``cpu:2``) or
-  ``default``; executables are per-ordinal, exactly like the
-  per-device programs they replace;
+- **device** — the executor's pinned ordinal (``cpu:2``), ``default``,
+  or ``mesh{k}`` for the one mesh program; per-device executables are
+  per-ordinal, exactly like the per-device programs they replace;
 - **jax version + ops content-hash** — the PR 4 jaxpr-artifact
   fingerprint scheme one level lower: any change to ``lodestar_tpu/ops``
   or the jax install makes every old entry *skew*, evicted on first
@@ -70,7 +72,7 @@ from ..utils.logger import get_logger
 
 logger = get_logger("aot-store")
 
-#: env var naming the store directory (conftest / bench / cli all use it)
+#: env var naming the store directory (conftest and cli use it)
 STORE_ENV = "LODESTAR_TPU_AOT_STORE"
 
 MANIFEST_NAME = "manifest.json"
@@ -107,7 +109,7 @@ def _payload_loadable_cross_process(n_bytes: int) -> bool:
     Persisting it would poison the store: every later restart would pay
     a quarantine + recompile + re-save churn.  Only compile processes
     that pinned ``--xla_cpu_parallel_codegen_split_count=1`` (the
-    prewarm farm and the bench aot variant do) produce big CPU payloads
+    prewarm farm does) produce big CPU payloads
     worth keeping; small programs never split, and TPU executables are
     device binaries, unaffected either way."""
     if n_bytes <= CPU_SAVE_MAX_BYTES:
@@ -318,7 +320,7 @@ class AotExecutableStore:
         #: keys quarantined/evicted by THIS process (loads skip them even
         #: when the best-effort manifest rewrite could not take the lock)
         self._dead_keys: set = set()
-        # counters (tier-1 ledger + bench extras + bundles read these)
+        # counters (tier-1 ledger + bundles read these)
         self.hits = 0
         self.misses = 0
         self.corrupt = 0

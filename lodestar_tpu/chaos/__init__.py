@@ -12,9 +12,8 @@ Public surface:
 - ``DeviceLostError`` / ``InjectedCompileError`` / ``InjectedIOError`` /
   ``FaultInjected``    the typed injected failures
 
-``tools/chaos_campaign.py`` drives the full campaign; ``bench.py``'s
-``chaos`` stage publishes ``time_to_quarantine_s`` / ``time_to_recover_s``
-/ ``verdicts_lost``.
+``tools/chaos_campaign.py`` drives the full campaign and reports
+``time_to_quarantine_s`` / ``time_to_recover_s`` / ``verdicts_lost``.
 """
 
 from .plan import (  # noqa: F401

@@ -72,8 +72,8 @@ import signal
 import threading
 from typing import Any, Dict, List, Optional
 
-#: env var carrying a JSON FaultPlan into spawn children (bench stages,
-#: the campaign's kill child) — see install_from_env()
+#: env var carrying a JSON FaultPlan into spawn children (the campaign's
+#: kill child, tools/prewarm.py) — see install_from_env()
 PLAN_ENV = "LODESTAR_TPU_CHAOS_PLAN"
 
 KNOWN_SEAMS = (
@@ -294,8 +294,8 @@ CHAOS = ChaosController()
 
 def install_from_env(env: Optional[Dict[str, str]] = None) -> bool:
     """Arm CHAOS from the ``LODESTAR_TPU_CHAOS_PLAN`` JSON env var (the
-    spawn-child activation path: bench stage children and the campaign's
-    kill child call this first).  Returns True when a plan was armed."""
+    spawn-child activation path: the campaign's kill child and
+    tools/prewarm.py call this first).  Returns True when a plan was armed."""
     blob = (env or os.environ).get(PLAN_ENV)
     if not blob:
         return False

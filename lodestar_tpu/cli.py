@@ -109,21 +109,10 @@ def build_parser() -> argparse.ArgumentParser:
             "first block import doesn't eat a cold compile",
         )
         p.add_argument(
-            "--bls-fused", choices=("auto", "on", "off"), default="auto",
-            help="fused Pallas kernel path (auto: on only on real TPU "
-            "backends; off: portable XLA-graph kernels)",
-        )
-        p.add_argument(
-            "--bls-sharded", choices=("auto", "on", "off"), default="auto",
-            help="cross-chip sharded pairing tier: merged batches at the "
-            "bucket ladder's top end ride ONE shard_map program spanning "
-            "the whole --bls-devices pool, final exponentiation once per "
-            "batch (auto: on for multi-device TPU pools; "
-            "docs/multichip.md)",
-        )
-        p.add_argument(
             "--bls-sharded-min-batch", type=int, default=0,
-            help="smallest merged batch the sharded tier takes "
+            help="smallest merged batch the cross-chip sharded tier (on "
+            "for a multi-device TPU pool: ONE shard_map program spans the "
+            "whole --bls-devices pool; docs/multichip.md) takes "
             "(0 = the largest --bls-buckets entry)",
         )
         p.add_argument(
@@ -547,8 +536,6 @@ def _make_verifier(args):
         buckets = tuple(
             int(b) for b in str(getattr(args, "bls_buckets", "4,16,64,128,256")).split(",") if b
         )
-        fused_flag = getattr(args, "bls_fused", "auto")
-        fused = None if fused_flag == "auto" else fused_flag == "on"
         n_dev = getattr(args, "bls_devices", 1)
         if n_dev < 0:
             raise SystemExit(f"--bls-devices: expected 0 (all) or a positive count, got {n_dev}")
@@ -560,11 +547,8 @@ def _make_verifier(args):
             devices = local if n_dev == 0 else local[:n_dev]
             logger.info("bls executor pool: %d of %d local devices",
                         len(devices), len(local))
-        sharded_flag = getattr(args, "bls_sharded", "auto")
-        sharded = None if sharded_flag == "auto" else sharded_flag == "on"
         v = TpuBlsVerifier(
-            buckets=buckets, fused=fused, devices=devices,
-            sharded=sharded,
+            buckets=buckets, devices=devices,
             sharded_min_batch=getattr(args, "bls_sharded_min_batch", 0) or None,
             point_cache_size=getattr(args, "bls_point_cache_size", 8192),
             quarantine_threshold=getattr(args, "bls_quarantine_threshold", 2),

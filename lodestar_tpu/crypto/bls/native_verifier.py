@@ -8,7 +8,6 @@ SURVEY.md section 2.9): portable C with 64-bit Montgomery limbs
   costs hundreds of ms of serial scan latency; one C verify costs ~10 ms —
   the same latency split the reference makes with blsVerifyOnMainThread,
   network/gossip/handlers/index.ts:114-118),
-- the honest vs_baseline denominator in bench.py,
 - the oracle-checked fallback when no TPU is present.
 
 Falls back to PyBlsVerifier transparently when the C toolchain is missing.

@@ -31,7 +31,15 @@ device finishes; a scheduling layer (chain/bls_pool.BlsBatchPool) keeps
 final exponentiation while batch N computes.  AOT warmup and the
 persistent-compilation-cache wiring live HERE (``warmup`` /
 ``configure_persistent_cache``) so a node's first block import doesn't
-eat a cold Mosaic/XLA compile — bench.py and cli.py both call in.
+eat a cold Mosaic/XLA compile — cli.py calls in.
+
+One program per tier, chosen from what the verifier observes: the fused
+Pallas split program on a TPU backend (the portable XLA split program
+elsewhere, and as the rung a failed fused compile degrades to), and on a
+multi-device TPU pool the one ``shard_map`` mesh program for merged
+batches that fill the top bucket.  Every program returns the Miller
+product and the subgroup verdict; the final exponentiation always runs
+on the host, once per batch.
 """
 
 from __future__ import annotations
@@ -67,11 +75,7 @@ logger = get_logger("tpu-verifier")
 
 def _fused_default() -> bool:
     """The fused Pallas dispatch is the production path on real TPUs; the
-    XLA-graph kernels remain the portable path (CPU tests, sharded dryrun).
-    LODESTAR_TPU_FUSED=0/1 overrides."""
-    env = os.environ.get("LODESTAR_TPU_FUSED")
-    if env is not None:
-        return env not in ("0", "false", "no")
+    XLA-graph kernels remain the portable path (CPU tests)."""
     import jax
 
     return jax.default_backend() == "tpu"
@@ -79,13 +83,9 @@ def _fused_default() -> bool:
 
 def _sharded_default(n_devices: int) -> bool:
     """The cross-chip sharded pairing tier (ops/sharded_verify) is the
-    production top tier on real multi-device TPU pools; elsewhere it is
-    opt-in (a CPU mesh of virtual devices shares the host's cores, so
-    sharding there is a test shape, not a win).
-    LODESTAR_TPU_SHARDED=0/1 overrides."""
-    env = os.environ.get("LODESTAR_TPU_SHARDED")
-    if env is not None:
-        return env not in ("0", "false", "no")
+    production top tier on real multi-device TPU pools; elsewhere only a
+    test pins it (a CPU mesh of virtual devices shares the host's cores,
+    so sharding there is a test shape, not a win)."""
     if n_devices < 2:
         return False
     import jax
@@ -138,13 +138,14 @@ DEFAULT_BUCKETS = (4, 16, 64, 128, 256)
 
 
 def _entry_name(key) -> str:
-    """Compile-ledger entry label for a (n, host_final_exp, fused)
-    program key: which of the 4 public kernels this program is."""
-    _n, host_final_exp, fused = key
-    if fused:
-        return "fused_split" if host_final_exp else "fused_full"
-    return "xla_split" if host_final_exp else "xla_full"
+    """Compile-ledger entry label for a (n, fused) program key."""
+    return "fused_split" if key[1] else "xla_split"
 
+
+#: Compile-ledger / AOT-store entry label of the mesh program.  Paired
+#: with the ``mesh{k}`` device label it makes the program ledger as ONE
+#: entry — never k per-ordinal rows.
+MESH_ENTRY = "sharded_split"
 
 #: Process-level program memo: (program key, device identity) -> compiled
 #: callable.  The compile ledger surfaced the cost this kills: every
@@ -173,19 +174,12 @@ def _fused_split(*args):
     return f.a, ok
 
 
-def _fused_full(*args):
-    from ...ops import fused_verify as fv
-
-    return fv.verify_signature_sets_fused(*args, interpret=False)
-
-
 class PendingVerdict:
     """A dispatched batch whose verdict has not been read back.
 
     Construction never blocks: the device work is already enqueued (jax
     dispatch is async) and ``result()`` performs the only synchronization
-    — the device readback plus, on the split path, the host C final
-    exponentiation.  ``result()`` is idempotent (the verdict — or the
+    — the device readback plus the host C final exponentiation.  ``result()`` is idempotent (the verdict — or the
     terminal failure — is cached).
 
     ``release`` is the scheduler's in-flight slot return: called exactly
@@ -197,17 +191,16 @@ class PendingVerdict:
     packed payload onto a surviving executor (``bls.requeue``) before
     degrading to the host-native tier."""
 
-    __slots__ = ("_verifier", "_f", "_ok", "_out", "_value", "_parts", "_release",
+    __slots__ = ("_verifier", "_f", "_ok", "_value", "_parts", "_release",
                  "_packed", "_sets", "_executor", "_attempt", "_fault", "_exc",
                  "device", "deadline")
 
-    def __init__(self, verifier=None, f=None, ok=None, out=None, value=None,
+    def __init__(self, verifier=None, f=None, ok=None, value=None,
                  parts=None, release=None, device=None, deadline=None,
                  packed=None, sets=None, executor=None, attempt=0, fault=None):
         self._verifier = verifier
         self._f = f
         self._ok = ok
-        self._out = out
         self._value = value
         self._parts = parts
         self._release = release
@@ -249,18 +242,7 @@ class PendingVerdict:
         if self._parts is not None:
             results = [p.result() for p in self._parts]
             return all(results)
-        if self._f is not None:
-            return self._verifier._host_final_exp_verdict(self._f, self._ok)
-        # fused on-device verdict: the bool() read is the sync, a pure
-        # device wait with no host work behind it
-        t0_ns = TRACER.now()
-        value = bool(self._out)
-        if TRACER.enabled:
-            TRACER.add_span(
-                "bls.readback", "bls", t0_ns,
-                cid=current_batch_id(), on_device=True,
-            )
-        return value
+        return self._verifier._host_final_exp_verdict(self._f, self._ok)
 
     def result(self) -> bool:
         if self._value is not None:
@@ -380,13 +362,16 @@ class TpuBlsVerifier:
     ``platform=None`` uses the default JAX backend (TPU when present);
     tests pin ``platform='cpu'``.
 
-    Round-4 split dispatch (``host_final_exp=True``, the default): the
-    device runs only the batch-parallel stages and returns the Miller
-    product; the host finishes with the native C final exponentiation
-    (csrc/fastbls.c — ~2 ms vs ~145 ms of serial device scan latency;
-    see ops/batch_verify.miller_product_kernel).  The pure-Python oracle
-    is the automatic fallback when the C toolchain is absent, and
-    ``host_final_exp=False`` restores the single fused device program.
+    Split dispatch: the device runs only the batch-parallel stages and
+    returns the Miller product; the host finishes with the native C
+    final exponentiation (csrc/fastbls.c — ~2 ms vs ~145 ms of serial
+    device scan latency; see ops/batch_verify.miller_product_kernel).
+    The pure-Python oracle is the automatic fallback when the C
+    toolchain is absent.
+
+    ``fused`` and ``sharded`` (None = decide from the backend and the
+    device count) pick the tier; tests pin them to run a tier on the
+    CPU.
 
     Multi-chip scale-out (``devices=[...]``, round-8): a ``DeviceExecutor``
     per chip, each holding its own AOT-compiled programs, and a throughput
@@ -417,11 +402,9 @@ class TpuBlsVerifier:
         buckets: Sequence[int] = DEFAULT_BUCKETS,
         platform: Optional[str] = None,
         devices: Optional[Sequence] = None,
-        host_final_exp: bool = True,
         fused: Optional[bool] = None,
         sharded: Optional[bool] = None,
         sharded_min_batch: Optional[int] = None,
-        sharded_combine: str = "all_gather",
         metrics=None,
         point_cache_size: int = 8192,
         quarantine_threshold: int = 2,
@@ -434,7 +417,6 @@ class TpuBlsVerifier:
         self.buckets = tuple(sorted(buckets))
         self.platform = platform
         self.devices = list(devices) if devices else None
-        self.host_final_exp = host_final_exp
         # round-5: the fused Pallas kernel path (ops/fused_verify) — the
         # production dispatch on TPU; resolved lazily so constructing a
         # verifier never touches a JAX backend.
@@ -443,12 +425,9 @@ class TpuBlsVerifier:
         # program spans the whole device pool for merged batches >=
         # ``sharded_min_batch`` (default: the bucket ladder's top end)
         # whose bucket divides evenly across the mesh.  None = auto (on
-        # for multi-device TPU pools; LODESTAR_TPU_SHARDED overrides),
-        # resolved lazily like ``fused``.  ``sharded_combine`` picks the
-        # GT cross-chip reduction topology (all_gather | ring).
+        # for multi-device TPU pools), resolved lazily like ``fused``.
         self.sharded = sharded
         self.sharded_min_batch = sharded_min_batch
-        self.sharded_combine = sharded_combine
         self.metrics = metrics
         # self-healing pool knobs (docs/chaos.md): consecutive failures
         # before quarantine, the first backoff, and the doubling cap
@@ -626,23 +605,16 @@ class TpuBlsVerifier:
             self.metrics.bls_device_inflight.labels(device=ex.name).set(inflight)
         return ex
 
-    def _mesh_entry_name(self) -> str:
-        """Compile-ledger / AOT-store entry label for the mesh program.
-        Paired with the ``mesh{k}`` device label it makes the program
-        ledger as ONE entry — never k per-ordinal rows."""
-        return "sharded_split" if self.host_final_exp else "sharded_full"
-
     def _mesh_memo_key(self, key):
         dev_ids = tuple(
             (d.platform, d.id) for d in (self.devices or ())
         )
-        return (("sharded",) + key, dev_ids, self.sharded_combine)
+        return (("sharded",) + key, dev_ids)
 
     def _aot_load_mesh(self, bucket: int):
         """AOT-store lookup for the mesh program (mesh{k}-keyed)."""
         return self._aot_load_program(
-            self._mesh_entry_name(), bucket, self._mesh_ex.name,
-            self.devices,
+            MESH_ENTRY, bucket, self._mesh_ex.name, self.devices,
         )
 
     def _mesh_fn(self, n: int):
@@ -654,7 +626,7 @@ class TpuBlsVerifier:
         import jax
 
         fused = self._resolve_fused()
-        key = (n, self.host_final_exp, fused)
+        key = (n, fused)
         ex = self._mesh_ex
         if key not in ex.compiled:
             mk = self._mesh_memo_key(key)
@@ -666,35 +638,25 @@ class TpuBlsVerifier:
                 if self.load_only:
                     raise AotStoreMiss(
                         f"load-only verifier: no stored executable for "
-                        f"{self._mesh_entry_name()} bucket {n} on {ex.name}"
+                        f"{MESH_ENTRY} bucket {n} on {ex.name}"
                     )
                 from ...ops import sharded_verify as sharded
 
-                mesh = sharded.make_mesh(self.devices)
-                factory = (
-                    sharded.miller_product_sharded if self.host_final_exp
-                    else sharded.verify_signature_sets_sharded
+                kernel = sharded.miller_product_sharded(
+                    sharded.make_mesh(self.devices), fused=fused
                 )
-                kernel = factory(mesh, fused=fused,
-                                 combine=self.sharded_combine)
                 fn = jax.jit(kernel).lower(*self._abstract_args(n)).compile()
                 store = self._get_aot_store()
                 if store is not None:
-                    store.save(self._mesh_entry_name(), n, ex.name, fn)
+                    store.save(MESH_ENTRY, n, ex.name, fn)
             with _PROGRAM_MEMO_LOCK:
                 fn = _PROGRAM_MEMO.setdefault(mk, fn)
             ex.compiled[key] = fn
         return ex.compiled[key]
 
     def _kernel(self, key):
-        """Python kernel callable for a (n, host_final_exp, fused) key."""
-        n, host_final_exp, fused = key
-        if fused:
-            return _fused_split if host_final_exp else _fused_full
-        return (
-            bv.miller_product_kernel if host_final_exp
-            else bv.verify_signature_sets_kernel
-        )
+        """Python kernel callable for a (n, fused) key."""
+        return _fused_split if key[1] else bv.miller_product_kernel
 
     def _device(self, executor: DeviceExecutor):
         """The device an executor's programs run on: its pinned device,
@@ -739,7 +701,7 @@ class TpuBlsVerifier:
     def _get_aot_store(self):
         """The active store, or None when the tier is disabled.  The
         process-wide singleton lazily picks up LODESTAR_TPU_AOT_STORE so
-        conftest/bench can enable the tier by env alone."""
+        conftest can enable the tier by env alone."""
         store = self.aot_store if self.aot_store is not None else AOT_STORE
         if not store.enabled and store is AOT_STORE and os.environ.get(STORE_ENV):
             store.configure()
@@ -766,7 +728,7 @@ class TpuBlsVerifier:
         return fn
 
     def _aot_load(self, key, bucket: int, ex: DeviceExecutor):
-        """Per-device store lookup for a (n, host_final_exp, fused) key."""
+        """Per-device store lookup for a (n, fused) key."""
         return self._aot_load_program(_entry_name(key), bucket, ex.name,
                                       [self._device(ex)])
 
@@ -785,7 +747,7 @@ class TpuBlsVerifier:
         (trace + lower + warm backend load) -> cold compile.  A
         ``load_only`` verifier stops after the store tier and raises
         ``AotStoreMiss`` — dispatch's degradation ladder owns it."""
-        key = (n, self.host_final_exp, self._resolve_fused() if fused is None else fused)
+        key = (n, self._resolve_fused() if fused is None else fused)
         ex = executor if executor is not None else self._executors[0]
         if key not in ex.compiled:
             mk = self._memo_key(key, ex)
@@ -1103,7 +1065,7 @@ class TpuBlsVerifier:
         (the pre-AOT behavior, one level down)."""
         missing = []
         for b in bucket_list:
-            key = (b, self.host_final_exp, self._resolve_fused())
+            key = (b, self._resolve_fused())
             for ex in self._executors:
                 if key in ex.compiled and not hasattr(ex.compiled[key], "lower"):
                     continue  # already an AOT executable
@@ -1137,7 +1099,7 @@ class TpuBlsVerifier:
                     if CHAOS.armed:
                         CHAOS.maybe_raise(
                             "bls.compile", where="warmup", device=ex.name,
-                            bucket=b, fused=key[2],
+                            bucket=b, fused=key[1],
                         )
                     # ledger attribution: the monitoring events this
                     # compile fires land on (entry, bucket, device) and
@@ -1191,8 +1153,7 @@ class TpuBlsVerifier:
                         fused=self._resolve_fused(), sharded=True,
                     )
                 with COMPILE_LEDGER.attribute(
-                    self._mesh_entry_name(), bucket=b,
-                    device=self._mesh_ex.name,
+                    MESH_ENTRY, bucket=b, device=self._mesh_ex.name,
                 ):
                     # load_only: _mesh_fn stops after the store tier and
                     # raises AotStoreMiss — the degrade arm below owns it
@@ -1451,31 +1412,24 @@ class TpuBlsVerifier:
         a requeue (``_attempt > 0``) never re-enters the mesh (the
         replay's job is a surviving executor, not the tier that just
         failed)."""
-        if _attempt == 0 and _exclude is None and self._sharded_eligible(
-            packed[0].shape[0]
-        ):
+        n = packed[0].shape[0]
+        if _attempt == 0 and _exclude is None and self._sharded_eligible(n):
             try:
                 return self._dispatch_sharded(packed, deadline=deadline,
                                               sets=sets)
             except Exception as e:  # noqa: BLE001 — hop down to the pool tier
                 tier = "fused" if self._resolve_fused() else "xla"
-                self._degrade(where="dispatch", tier=tier,
-                              bucket=packed[0].shape[0],
+                self._degrade(where="dispatch", tier=tier, bucket=n,
                               device=self._mesh_ex.name, error=e)
                 self.sharded = False
                 with self._stats_lock:
                     self.sharded_fallbacks += 1
                 # drop the broken mesh program so a later verifier (or a
                 # re-enabled tier) retries it fresh
-                key = (packed[0].shape[0], self.host_final_exp, self.fused)
+                key = (n, self.fused)
                 self._mesh_ex.compiled.pop(key, None)
                 with _PROGRAM_MEMO_LOCK:
                     _PROGRAM_MEMO.pop(self._mesh_memo_key(key), None)
-        live = int(np.sum(np.asarray(packed[6])))
-        with self._stats_lock:
-            self.dispatches += 1
-            self.sets_verified += live
-        n = packed[0].shape[0]
         t0_ns = TRACER.now()
         # snapshot the path THIS call uses: a concurrent warmup_async thread
         # may degrade self.fused mid-flight, and the except arm must judge
@@ -1496,8 +1450,7 @@ class TpuBlsVerifier:
                 # in-process hit — the three-way split the cold-start
                 # baseline (ROADMAP item 4) is measured against
                 with COMPILE_LEDGER.attribute(
-                    _entry_name((n, self.host_final_exp, used_fused)),
-                    bucket=n, device=ex.name,
+                    _entry_name((n, used_fused)), bucket=n, device=ex.name,
                 ):
                     out = self._fn(n, fused=used_fused, executor=ex)(*packed)
             except Exception as e:  # noqa: BLE001
@@ -1512,9 +1465,7 @@ class TpuBlsVerifier:
                 # a later verifier retries it fresh (status-quo per-
                 # instance behavior) instead of inheriting the failure
                 with _PROGRAM_MEMO_LOCK:
-                    _PROGRAM_MEMO.pop(
-                        self._memo_key((n, self.host_final_exp, True), ex), None
-                    )
+                    _PROGRAM_MEMO.pop(self._memo_key((n, True), ex), None)
                 # chaos seam: the XLA hop can be failed independently
                 # (match {"fused": False}) to drive the batch to the
                 # native tier — the full-ladder campaign scenario
@@ -1524,8 +1475,7 @@ class TpuBlsVerifier:
                         bucket=n, fused=False,
                     )
                 with COMPILE_LEDGER.attribute(
-                    _entry_name((n, self.host_final_exp, False)),
-                    bucket=n, device=ex.name,
+                    _entry_name((n, False)), bucket=n, device=ex.name,
                 ):
                     out = self._fn(n, fused=False, executor=ex)(*packed)
         except Exception as e:
@@ -1536,13 +1486,77 @@ class TpuBlsVerifier:
             if not isinstance(e, AotStoreMiss):
                 self._record_executor_failure(ex, e)
             raise
+        return self._enqueued(out, packed, ex, t0_ns, t_disp, used_fused,
+                              deadline, sets, _attempt)
+
+    def _dispatch_sharded(self, packed, deadline: Optional[float] = None,
+                          sets=None) -> PendingVerdict:
+        """One mesh-spanning dispatch: the whole packed batch sharded
+        over every pool device by the shard_map program — per-pair
+        Miller loops run locally per chip, the GT partial products
+        combine across the mesh, and the host final exponentiation runs
+        once per merged batch (docs/multichip.md).
+
+        Identity discipline: the ledger attribution, the AOT store key,
+        the journal/trace device, and the in-flight table entry all use
+        the single ``mesh{k}`` label — one program, one ledger row, one
+        span.  A sync-time failure (device loss mid-batch) rides the
+        normal PendingVerdict recovery: the mesh health record takes the
+        failure (quarantine -> backoff -> probe re-admission) and the
+        SAME packed payload requeues onto a single surviving executor —
+        zero verdicts lost."""
+        n = packed[0].shape[0]
+        t0_ns = TRACER.now()
+        used_fused = self._resolve_fused()
+        ex = self._acquire_mesh()
+        t_disp = time.perf_counter()
+        try:
+            # chaos seam: an injected mesh compile failure surfaces
+            # exactly where a real Mosaic/XLA/collective one would
+            if CHAOS.armed:
+                CHAOS.maybe_raise(
+                    "bls.compile", where="dispatch", device=ex.name,
+                    bucket=n, fused=used_fused, sharded=True,
+                )
+            with COMPILE_LEDGER.attribute(MESH_ENTRY, bucket=n, device=ex.name):
+                out = self._mesh_fn(n)(*packed)
+        except Exception:
+            self._release_executor(ex)
+            # enqueue-time failure is a TIER problem (compile, store,
+            # lowering), not chip sickness: dispatch()'s fallthrough owns
+            # the degrade; the mesh health record is reserved for
+            # sync-time device faults
+            raise
+        return self._enqueued(out, packed, ex, t0_ns, t_disp, used_fused,
+                              deadline, sets, 0, sharded=True)
+
+    def _enqueued(self, out, packed, ex: DeviceExecutor, t0_ns: int,
+                  t_disp: float, used_fused: bool, deadline: Optional[float],
+                  sets, attempt: int, sharded: bool = False) -> PendingVerdict:
+        """Bookkeeping of one batch whose program call returned, the same
+        for both tiers: the counters and the ``dispatch`` stage, the
+        ``bls.dispatch`` span and journal record, the in-flight table
+        entry and the exactly-once slot release, and the chaos draw for
+        the device seams.  A mesh batch (``sharded``) also counts as a
+        sharded batch, and its span and record carry ``sharded`` and
+        ``mesh_devices`` so tools/check_trace.py can hold a mesh dump to
+        the mesh contract."""
         dt_disp = time.perf_counter() - t_disp
+        n = packed[0].shape[0]
+        live = int(np.sum(np.asarray(packed[6])))
         with self._stats_lock:
+            self.dispatches += 1
+            self.sets_verified += live
+            if sharded:
+                self.sharded_batches += 1
             self.stage_seconds["dispatch"] += dt_disp
         if self.metrics:
             self.metrics.bls_verifier_stage_duration_seconds.labels(
                 stage="dispatch"
             ).observe(dt_disp)
+            if sharded:
+                self.metrics.bls_sharded_batches_total.inc()
+        mesh = {"sharded": True, "mesh_devices": self.n_devices} if sharded else {}
         cid = current_batch_id()
         if TRACER.enabled:
             # covers the async enqueue only (plus compile when cold); the
@@ -1551,7 +1565,8 @@ class TpuBlsVerifier:
             # multi-device dump actually spread across the pool
             TRACER.add_span("bls.dispatch", "bls", t0_ns,
                             cid=cid, bucket=n, fused=used_fused,
-                            device=ex.name, devices_total=self.n_devices)
+                            device=ex.name, devices_total=self.n_devices,
+                            **mesh)
         # flight recorder: placement decision into the black box, the
         # batch into the in-flight table the watchdog scans — resolved by
         # the same exactly-once path that returns the executor slot, so a
@@ -1566,7 +1581,8 @@ class TpuBlsVerifier:
             JOURNAL.record("bls.dispatch", cid=cid, device=ex.name, bucket=n,
                            sets=live, fused=used_fused,
                            inflight=ex.inflight, devices_total=self.n_devices,
-                           deadline_headroom_s=headroom, attempt=_attempt or None)
+                           deadline_headroom_s=headroom, attempt=attempt or None,
+                           **mesh)
         token = INFLIGHT.register(cid=cid, device=ex.name, bucket=n, sets=live,
                                   deadline_s=headroom)
 
@@ -1584,111 +1600,11 @@ class TpuBlsVerifier:
                 CHAOS.fire("device.loss", device=ex.name, bucket=n, cid=cid)
                 or CHAOS.fire("device.wedge", device=ex.name, bucket=n, cid=cid)
             )
-        if self.host_final_exp:
-            f, ok = out
-            return PendingVerdict(verifier=self, f=f, ok=ok, release=release,
-                                  device=ex.name, deadline=deadline,
-                                  packed=packed, sets=sets, executor=ex,
-                                  attempt=_attempt, fault=fault)
-        return PendingVerdict(verifier=self, out=out, release=release,
+        f, ok = out
+        return PendingVerdict(verifier=self, f=f, ok=ok, release=release,
                               device=ex.name, deadline=deadline,
                               packed=packed, sets=sets, executor=ex,
-                              attempt=_attempt, fault=fault)
-
-    def _dispatch_sharded(self, packed, deadline: Optional[float] = None,
-                          sets=None) -> PendingVerdict:
-        """One mesh-spanning dispatch: the whole packed batch sharded
-        over every pool device by the shard_map program — per-pair
-        Miller loops run locally per chip, the GT partial products
-        combine across the mesh, and the final exponentiation runs once
-        per merged batch (docs/multichip.md).
-
-        Identity discipline: the ledger attribution, the AOT store key,
-        the journal/trace device, and the in-flight table entry all use
-        the single ``mesh{k}`` label — one program, one ledger row, one
-        span.  The dispatch span additionally carries ``sharded`` and
-        ``mesh_devices`` so tools/check_trace.py can hold a mesh dump to
-        the mesh contract.  A sync-time failure (device loss mid-batch)
-        rides the normal PendingVerdict recovery: the mesh health record
-        takes the failure (quarantine -> backoff -> probe re-admission)
-        and the SAME packed payload requeues onto a single surviving
-        executor — zero verdicts lost."""
-        n = packed[0].shape[0]
-        live = int(np.sum(np.asarray(packed[6])))
-        t0_ns = TRACER.now()
-        used_fused = self._resolve_fused()
-        ex = self._acquire_mesh()
-        t_disp = time.perf_counter()
-        try:
-            # chaos seam: an injected mesh compile failure surfaces
-            # exactly where a real Mosaic/XLA/collective one would
-            if CHAOS.armed:
-                CHAOS.maybe_raise(
-                    "bls.compile", where="dispatch", device=ex.name,
-                    bucket=n, fused=used_fused, sharded=True,
-                )
-            with COMPILE_LEDGER.attribute(
-                self._mesh_entry_name(), bucket=n, device=ex.name
-            ):
-                out = self._mesh_fn(n)(*packed)
-        except Exception:
-            self._release_executor(ex)
-            # enqueue-time failure is a TIER problem (compile, store,
-            # lowering), not chip sickness: dispatch()'s fallthrough owns
-            # the degrade; the mesh health record is reserved for
-            # sync-time device faults
-            raise
-        with self._stats_lock:
-            self.dispatches += 1
-            self.sets_verified += live
-            self.sharded_batches += 1
-        dt_disp = time.perf_counter() - t_disp
-        with self._stats_lock:
-            self.stage_seconds["dispatch"] += dt_disp
-        if self.metrics:
-            self.metrics.bls_verifier_stage_duration_seconds.labels(
-                stage="dispatch"
-            ).observe(dt_disp)
-            self.metrics.bls_sharded_batches_total.inc()
-        cid = current_batch_id()
-        if TRACER.enabled:
-            TRACER.add_span("bls.dispatch", "bls", t0_ns,
-                            cid=cid, bucket=n, fused=used_fused,
-                            device=ex.name, devices_total=self.n_devices,
-                            sharded=True, mesh_devices=self.n_devices)
-        headroom = None
-        if deadline is not None:
-            headroom = round(deadline - time.monotonic(), 3)
-        if JOURNAL.enabled:
-            JOURNAL.record("bls.dispatch", cid=cid, device=ex.name, bucket=n,
-                           sets=live, fused=used_fused, sharded=True,
-                           mesh_devices=self.n_devices,
-                           inflight=ex.inflight,
-                           devices_total=self.n_devices,
-                           deadline_headroom_s=headroom)
-        token = INFLIGHT.register(cid=cid, device=ex.name, bucket=n, sets=live,
-                                  deadline_s=headroom)
-
-        def release():
-            INFLIGHT.resolve(token)
-            self._release_executor(ex)
-
-        fault = None
-        if CHAOS.armed:
-            fault = (
-                CHAOS.fire("device.loss", device=ex.name, bucket=n, cid=cid)
-                or CHAOS.fire("device.wedge", device=ex.name, bucket=n, cid=cid)
-            )
-        if self.host_final_exp:
-            f, ok = out
-            return PendingVerdict(verifier=self, f=f, ok=ok, release=release,
-                                  device=ex.name, deadline=deadline,
-                                  packed=packed, sets=sets, executor=ex,
-                                  attempt=0, fault=fault)
-        return PendingVerdict(verifier=self, out=out, release=release,
-                              device=ex.name, deadline=deadline,
-                              packed=packed, sets=sets, executor=ex,
-                              attempt=0, fault=fault)
+                              attempt=attempt, fault=fault)
 
     def close(self) -> None:
         for ex in self._executors:

@@ -14,9 +14,8 @@ production:
   ``RECORDER`` singleton wiring it to signals (SIGTERM/SIGUSR2),
   unhandled exceptions, faulthandler, the watchdog, and the REST
   ``GET /eth/v1/lodestar/forensics`` endpoint.
-- ``salvage``   — bench.py stage-child heartbeats, so a timed-out child
-  still leaves a last-known bundle for the parent to attach to
-  ``extras.stage_errors``.
+- ``salvage``   — stage-child heartbeats, so a timed-out child still
+  leaves a last-known bundle for the parent to read.
 
 Inspect any bundle with ``python tools/inspect_bundle.py BUNDLE_DIR``.
 """

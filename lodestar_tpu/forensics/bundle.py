@@ -3,7 +3,7 @@ node doing when it died".
 
 A bundle is written on unhandled exception, SIGTERM/SIGUSR2, a watchdog
 stall, on demand via ``GET /eth/v1/lodestar/forensics``, and (as a
-heartbeat) by bench stage children so a killed child still leaves its
+heartbeat) by stage children so a killed child still leaves its
 last-known state behind.  Layout (``tools/inspect_bundle.py`` validates
 and summarizes it):
 

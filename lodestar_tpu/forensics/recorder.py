@@ -18,8 +18,8 @@ directory, metrics, pool/verifier references) and the dump triggers:
                               so segfault-class deaths leave stacks next
                               to the bundles
 
-``install()`` is the one-call CLI entry (cli.py); bench stage children
-use the lighter ``salvage`` heartbeat instead.
+``install()`` is the one-call CLI entry (cli.py); stage children use
+the lighter ``salvage`` heartbeat instead.
 """
 
 from __future__ import annotations

@@ -1,13 +1,13 @@
-"""Bench-stage salvage: heartbeat bundles from spawn children.
+"""Stage-child salvage: heartbeat bundles from spawn children.
 
-bench.py runs every benchmark stage in a spawn subprocess with a hard
-wall-clock bound; on timeout the parent SIGKILLs the child and, before
-this module existed, all evidence died with it ("timeout after Ns" was
-the entire post-mortem — the BENCH_r05 failure mode).  The fix is a
-heartbeat: each stage child periodically snapshots a diagnostic bundle
-to a scratch directory keyed by stage name, keeping only the newest few,
-and the parent attaches the last-known bundle path to
-``extras.stage_errors`` when the stage dies.  ``tools/inspect_bundle.py``
+A parent that runs work in a spawn subprocess under a hard wall-clock
+bound SIGKILLs the child on timeout, and without this module all
+evidence dies with it ("timeout after Ns" was the entire post-mortem —
+the BENCH_r05 failure mode).  The fix is a heartbeat: the child
+periodically snapshots a diagnostic bundle to a scratch directory keyed
+by stage name, keeping only the newest few, and the parent reads the
+last-known bundle (``latest_stage_bundle``) when the child dies (the
+chaos campaign's ``bench_kill`` class drives this path).  ``tools/inspect_bundle.py``
 then answers what the child was doing — last compile event, in-flight
 batches, last errors — instead of nothing.
 """
@@ -20,7 +20,7 @@ import threading
 from typing import Optional
 
 from . import bundle as bundle_mod
-from .journal import JOURNAL, install_jax_monitoring
+from .journal import JOURNAL
 
 BASE_DIR_ENV = "BENCH_FORENSICS_DIR"
 INTERVAL_ENV = "BENCH_HEARTBEAT_S"
@@ -84,19 +84,6 @@ class Heartbeat:
         t, self._thread = self._thread, None
         if t is not None:
             t.join(timeout=2)
-
-
-def start_heartbeat(stage: str, interval_s: Optional[float] = None) -> Heartbeat:
-    """Child-side entry (bench._stage_child): journal jax compile events
-    and start the snapshot loop.  Never raises — a broken scratch disk
-    must not fail the stage it is trying to protect."""
-    install_jax_monitoring(JOURNAL)
-    JOURNAL.record("bench.stage_start", stage=stage, pid=os.getpid())
-    hb = Heartbeat(stage, interval_s)
-    try:
-        return hb.start()
-    except Exception:
-        return hb
 
 
 def latest_stage_bundle(stage: str, pid: Optional[int] = None) -> Optional[str]:

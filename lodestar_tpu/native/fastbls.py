@@ -1,8 +1,8 @@
 """ctypes binding for csrc/fastbls.c (native BLS12-381) with
 build-on-demand and self-test gating.
 
-Three roles (see fastbls.c header):
-- honest CPU baseline for bench.py (portable-C blst counterpart),
+Roles (see fastbls.c header):
+- honest CPU baseline (portable-C blst counterpart),
 - host-side final exponentiation for the split TPU dispatch,
 - fast CPU fallback verifier (FastBlsVerifier in crypto/bls/native_verifier).
 

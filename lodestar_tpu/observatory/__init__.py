@@ -73,9 +73,9 @@ _IMPORT_MONOTONIC = time.monotonic()
 def process_age_s() -> float:
     """Seconds since THIS process started — the cold-start clock.
 
-    ``bench.py cold_start`` measures process start -> first verified
-    batch, and "process start" must include interpreter boot and the
-    import of jax, not just the stage function body.  On Linux the exact
+    A cold-start measurement runs from process start to the first
+    verified batch, and "process start" must include interpreter boot
+    and the import of jax, not just the measured function body.  On Linux the exact
     figure comes from /proc (process start tick vs uptime); elsewhere we
     fall back to time-since-this-module-imported, which undercounts by
     the pre-import boot only.

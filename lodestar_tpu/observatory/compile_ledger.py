@@ -27,8 +27,7 @@ warm".  The ledger is that answer, kept across processes:
 - **Persistence**: aggregated per-key stats in
   ``<jax-cache-dir>/compile_ledger.json`` next to the executables they
   describe, read-modify-written atomically (the jaxpr-audit artifact
-  pattern, one level lower).  ``tools/perf_report.py`` ingests it and
-  the ``bench.py cold_start`` stage attaches it in extras.
+  pattern, one level lower).  ``tools/perf_report.py`` ingests it.
 - **Metrics**: ``lodestar_bls_compile_seconds{entry,kind}`` histogram
   over the :data:`~lodestar_tpu.observatory.latency.COMPILE_BUCKETS_S`
   ladder.

@@ -19,8 +19,7 @@ HBM at all.  The sampler is the low-overhead background answer:
   bundles carry the HBM/occupancy history leading up to a death;
 - self-accounted overhead: every tick measures its own wall time and
   ``overhead_ratio()`` reports total sampler work / elapsed — the
-  "<1 % of a dev_chain run" bound is *measured*, not asserted
-  (bench.py attaches it to the dev_chain stage extras).
+  "<1 % of a dev_chain run" bound is *measured*, not asserted.
 
 The sampler never initializes a JAX backend: pass ``devices=`` (the
 verifier's executor devices, or fakes in tests) or it resolves

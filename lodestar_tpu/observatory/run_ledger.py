@@ -349,8 +349,8 @@ def _sidecar_tier1(repo: str) -> Optional[dict]:
 def deltas_vs_previous(repo: str, current: Dict[str, Optional[float]],
                        bench_pattern: str = "BENCH_r*.json",
                        backend: Optional[str] = None) -> Dict[str, Any]:
-    """bench.py's extras.perf_deltas: each current metric vs the most
-    recent committed run that produced it, with the tripwire verdict.
+    """Each current metric vs the most recent committed run that
+    produced it, with the tripwire verdict.
 
     ``backend`` (the live ``jax.default_backend()``) restricts the
     comparison series to committed runs measured on the same backend —

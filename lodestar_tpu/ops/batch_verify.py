@@ -1,6 +1,8 @@
-"""The batched signature-set verification kernel — the north-star dispatch.
+"""The batched signature-set verification kernel, XLA-graph flavor — the
+portable twin of ops/fused_verify (CPU tests, and the rung a failed fused
+compile degrades to).
 
-One jitted call verifies a whole padded batch of signature sets with the
+One jitted call checks a whole padded batch of signature sets with the
 random-linear-combination equation (the TPU redesign of blst's
 verifyMultipleSignatures behind the reference's worker pool,
 chain/bls/maybeBatch.ts:17-27 + multithread/worker.ts):
@@ -10,23 +12,22 @@ chain/bls/maybeBatch.ts:17-27 + multithread/worker.ts):
 with fresh odd 64-bit coefficients c_i.  Soundness ~2^-64 per attempt, the
 same bound the reference accepts.
 
-Device stages (all one fused XLA program):
+Device stages (all one XLA program):
   1. G2 subgroup checks on the signatures (psi(P) == [z]P ladder with
      complete adds — the adversary picks these points).
   2. hash_to_g2 device stage on the per-message field draws.
   3. [c_i]pk_i (G1) and [c_i]s_i (G2) scalar ladders (unsafe adds: operands
      are freshly randomized).
   4. Tree-sum of scaled signatures; batched affine conversions.
-  5. Miller loops over the N+1 pairs, Fq12 product tree, one shared final
-     exponentiation, is_one verdict.
+  5. Miller loops over the N+1 pairs and the Fq12 product tree.  The one
+     shared final exponentiation and the is-one verdict run on the host.
 
 Host-side packing (byte parsing, sha256 expansion, coefficient sampling)
 lives in crypto/bls/tpu_verifier.py.
 
 Inputs are fixed-shape and padded; ``mask`` marks live lanes.  The batch
-axis is shardable: __graft_entry__.dryrun_multichip runs this kernel over a
-jax.sharding.Mesh with the set axis partitioned across devices, which is
-the ICI scale-out story (SURVEY §2.10 item 1).
+axis is shardable: ops/sharded_verify maps ``miller_product_parts_kernel``
+over a device mesh.
 """
 
 from __future__ import annotations
@@ -41,59 +42,6 @@ from . import pairing as kp
 from . import points as pts
 from . import tower as tw
 from .points import FQ2_NS, FQ_NS
-
-
-def verify_signature_sets_kernel(
-    pk_x: jnp.ndarray,  # (N, 26)  aggregated pubkey affine x (G1)
-    pk_y: jnp.ndarray,  # (N, 26)
-    sig_x: jnp.ndarray,  # (N, 2, 26) signature affine x (G2, on curve)
-    sig_y: jnp.ndarray,  # (N, 2, 26)
-    msg_u: jnp.ndarray,  # (N, 2, 2, 26) hash_to_field draws
-    coeff_bits: jnp.ndarray,  # (N, 64) uint32 bits of c_i (LSB first, odd)
-    mask: jnp.ndarray,  # (N,) bool: live set?
-) -> jnp.ndarray:
-    """Returns a scalar bool: all live sets verify."""
-    n = pk_x.shape[0]
-
-    # 1. signature subgroup checks (only live lanes must pass)
-    sig_jac = pts.point_from_affine(sig_x, sig_y, FQ2_NS)
-    sig_in_g2 = pts.g2_subgroup_check(sig_jac)
-    subgroup_ok = jnp.all(jnp.where(mask, sig_in_g2, True))
-
-    # 2. message points
-    h_jac = htc.hash_to_g2_device(msg_u)  # (N,) jacobian G2
-
-    # 3. scalar ladders
-    pk_jac = pts.point_from_affine(pk_x, pk_y, FQ_NS)
-    pk_scaled = pts.point_mul_bits(pk_jac, coeff_bits, FQ_NS)  # (N,) jacobian G1
-    sig_scaled = pts.point_mul_bits(sig_jac, coeff_bits, FQ2_NS)
-
-    # 4. sum scaled signatures; padding lanes must not contribute
-    inf = pts.point_infinity(FQ2_NS, batch_shape=(n,))
-    sig_masked = pts.point_select(mask, sig_scaled, inf, FQ2_NS)
-    s_sum = pts.point_sum_tree(sig_masked, FQ2_NS)  # jacobian G2
-
-    # batched affine conversions: G2 side stacks H (N) and S (1)
-    g2_stack = tuple(
-        jnp.concatenate([h_jac[i], s_sum[i][None]], axis=0) for i in range(3)
-    )
-    g2_aff_x, g2_aff_y = pts.point_to_affine(g2_stack, FQ2_NS)
-    pk_aff_x, pk_aff_y = pts.point_to_affine(pk_scaled, FQ_NS)
-
-    # 5. pair list: (c_i pk_i, H_i) for live lanes, then (-g1, S)
-    neg_g1_x = jnp.asarray(pts.G1_GEN_NEG_AFFINE[0])
-    neg_g1_y = jnp.asarray(pts.G1_GEN_NEG_AFFINE[1])
-    xp = jnp.concatenate([pk_aff_x, neg_g1_x[None]], axis=0)
-    yp = jnp.concatenate([pk_aff_y, neg_g1_y[None]], axis=0)
-    xq = g2_aff_x
-    yq = g2_aff_y
-    # S may legitimately be infinity only in degenerate/masked-out batches;
-    # its affine coords are then garbage — mask the pair (e(-, O) = 1).
-    s_not_inf = ~tw.fq2_is_zero(s_sum[2])  # z == 0 mod p covers exact zeros too
-    pair_mask = jnp.concatenate([mask, s_not_inf[None]], axis=0)
-
-    product_one = kp.pairing_product_is_one(xp, yp, xq, yq, pair_mask)
-    return product_one & subgroup_ok & jnp.any(mask)
 
 
 def miller_product_kernel(
@@ -176,7 +124,7 @@ def miller_product_parts_kernel(
 
 def example_inputs(n: int = 8) -> tuple:
     """Deterministic, well-formed example inputs (numpy only — safe to build
-    without touching any JAX backend).  Used by __graft_entry__ and bench."""
+    without touching any JAX backend).  Used by __graft_entry__ and tests."""
     from ..crypto.bls import curve as C
     from ..crypto.bls.api import interop_secret_key
     from ..crypto.bls.hash_to_curve import hash_to_g2

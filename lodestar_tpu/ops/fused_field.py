@@ -8,17 +8,12 @@ Exponentiation scans (Fermat inversion, Legendre chi, Fq2 sqrt) run
 4-bit-windowed with the fused r^16*t kernel: 96 serial kernel calls for a
 381-bit exponent instead of ~48k serial HLO ops.
 
-Frobenius constants are precombined on the host from the oracle's computed
-values (e.g. V*W) so one kernel call applies the whole coefficient set.
-
 Differentially tested against ops/tower.py and the bigint oracle in
 tests/test_fused_field.py (interpret mode on CPU; compiled on TPU by the
 .probe scripts and the production dispatch tests).
 """
 
 from __future__ import annotations
-
-import numpy as np
 
 import jax.numpy as jnp
 from jax import lax
@@ -30,15 +25,11 @@ from .fused_core import (
     LV,
     f2_mul,
     f2_pow16mul,
-    f2_sqr,
     f_canon,
     f_mul,
     f_pow16mul,
     ladd,
     lc,
-    lcast,
-    lconcat,
-    ldbl,
     lneg,
     lselect,
     lstack,
@@ -56,20 +47,6 @@ FQ_ONE = fl.ONE
 FQ2_ONE = tw.FQ2_ONE
 FQ12_ONE = tw.FQ12_ONE
 P_MINUS_1 = fl.int_to_limbs(F.P - 1)
-
-# flat Fq12 Frobenius coefficient set: out_i = conj(c_i) * FROB12[i]
-# (FROB12[0] = 1; see tower.fq12_frobenius for the per-level structure)
-FROB12 = np.stack(
-    [
-        tw.fq2_const(F.Fq2.one()),
-        tw.fq2_const(F.FROB_C1_V),
-        tw.fq2_const(F.FROB_C1_V2),
-        tw.fq2_const(F.FROB_C1_W),
-        tw.fq2_const(F.FROB_C1_V * F.FROB_C1_W),
-        tw.fq2_const(F.FROB_C1_V2 * F.FROB_C1_W),
-    ]
-)  # (6, 2, 50)
-
 
 # ---------------------------------------------------------------------------
 # Fq2 glue (LVs shaped (..., 2, 50))
@@ -248,13 +225,6 @@ def _fq6_mul_by_v(A):
     return [f2_mul_by_xi(A[2]), A[0], A[1]]
 
 
-def f6_mul_comps(A, B, interpret=None):
-    """Fq6 product on 3-component Fq2 LV lists — one 6-lane kernel call."""
-    ls, rs = _fq6_lanes(A, B)
-    q = f2_mul(lstack(ls, axis=-3), lstack(rs, axis=-3), interpret)
-    return _fq6_recombine([LV(q.a[..., i, :, :], q.b) for i in range(6)])
-
-
 def _f12_comps(x: LV):
     return [LV(x.a[..., i, :, :], x.b) for i in range(6)]
 
@@ -303,100 +273,11 @@ def f12_sqr(a: LV, interpret=None) -> LV:
     return lstack(C0 + C1, axis=-3)
 
 
-def f12_cyc_sqr(a: LV, interpret=None) -> LV:
-    """Granger-Scott cyclotomic squaring — 9 Fq2 squarings in ONE kernel
-    call (tower.fq12_cyc_sqr, loose glue; the folded-input second output of
-    the squaring kernel keeps the 3t - 2x recombination bounds small)."""
-    X = _f12_comps(a)
-    pairs = [(X[0], X[4]), (X[3], X[2]), (X[1], X[5])]
-    sq_in = []
-    for u, v in pairs:
-        sq_in += [u, v, ladd(u, v)]
-    sq, folded = f2_sqr(lstack(sq_in, axis=-3), interpret)
-    SQ = [LV(sq.a[..., i, :, :], sq.b) for i in range(9)]
-    FD = [LV(folded.a[..., i, :, :], 256) for i in range(9)]
-    # folded copies of the inputs, in pair order (x0,x4),(x3,x2),(x1,x5)
-    fx = {0: FD[0], 4: FD[1], 3: FD[3], 2: FD[4], 1: FD[6], 5: FD[7]}
-    t_even, t_odd = [], []
-    for k in range(3):
-        a2, b2, ab2 = SQ[3 * k], SQ[3 * k + 1], SQ[3 * k + 2]
-        t_even.append(ladd(a2, f2_mul_by_xi(b2)))
-        t_odd.append(lsub(ab2, ladd(a2, b2)))
-    t0, t2, t4 = t_even
-    t1, t3, t5 = t_odd
-    trip = lambda t: ladd(ladd(t, t), t)
-    z0 = lsub(trip(t0), ldbl(fx[0]))
-    z1 = lsub(trip(t2), ldbl(fx[1]))
-    z2 = lsub(trip(t4), ldbl(fx[2]))
-    z3 = ladd(trip(f2_mul_by_xi(t5)), ldbl(fx[3]))
-    z4 = ladd(trip(t1), ldbl(fx[4]))
-    z5 = ladd(trip(t3), ldbl(fx[5]))
-    return lstack([z0, z1, z2, z3, z4, z5], axis=-3)
-
-
 def f12_conj(a: LV) -> LV:
     """x -> x^(p^6) (inverse on the cyclotomic subgroup)."""
     A = _f12_comps(a)
     return lstack(A[0:3] + [lneg(c) for c in A[3:6]], axis=-3)
 
 
-def f12_frobenius(a: LV, interpret=None) -> LV:
-    """x -> x^p: conjugate every component, multiply by the precombined
-    flat coefficient set — ONE 5-lane kernel call (FROB12[0] = 1)."""
-    A = _f12_comps(a)
-    conj = [f2_conj(c) for c in A]
-    coeff = lv(jnp.asarray(FROB12[1:]))  # (5, 2, 50)
-    prod = f2_mul(lstack(conj[1:], axis=-3), coeff, interpret)
-    out = [conj[0]] + [LV(prod.a[..., i, :, :], prod.b) for i in range(5)]
-    return lstack(out, axis=-3)
-
-
-def f12_inv(a: LV, interpret=None) -> LV:
-    """Fq12 inversion via the Fq6 norm (tower.fq12_inv, fused)."""
-    A = _f12_comps(a)
-    a0, a1 = A[0:3], A[3:6]
-    t0 = f6_mul_comps(a0, a0, interpret)
-    t1 = f6_mul_comps(a1, a1, interpret)
-    vt1 = _fq6_mul_by_v(t1)
-    denom = [lsub(t0[j], vt1[j]) for j in range(3)]
-    dinv = f6_inv_comps(denom, interpret)
-    out0 = f6_mul_comps(a0, dinv, interpret)
-    out1 = f6_mul_comps(a1, dinv, interpret)
-    return lstack(out0 + [lneg(c) for c in out1], axis=-3)
-
-
-def f6_inv_comps(A, interpret=None):
-    """Fq6 inversion (tower.fq6_inv structure, fused lanes)."""
-    a0, a1, a2 = A
-    sq = f2_mul(lstack([a0, a2, a1], axis=-3), lstack([a0, a2, a1], axis=-3), interpret)
-    cross = f2_mul(lstack([a1, a0, a0], axis=-3), lstack([a2, a1, a2], axis=-3), interpret)
-    sqs = [LV(sq.a[..., i, :, :], sq.b) for i in range(3)]
-    crs = [LV(cross.a[..., i, :, :], cross.b) for i in range(3)]
-    t0 = lsub(sqs[0], f2_mul_by_xi(crs[0]))
-    t1 = lsub(f2_mul_by_xi(sqs[1]), crs[1])
-    t2 = lsub(sqs[2], crs[2])
-    parts = f2_mul(
-        lstack([a0, a2, a1], axis=-3), lstack([t0, t1, t2], axis=-3), interpret
-    )
-    ps = [LV(parts.a[..., i, :, :], parts.b) for i in range(3)]
-    denom = ladd(ps[0], f2_mul_by_xi(ladd(ps[1], ps[2])))
-    dinv = f2_inv(denom, interpret)
-    scaled = f2_mul(
-        lstack([t0, t1, t2], axis=-3),
-        LV(jnp.broadcast_to(dinv.a[..., None, :, :], lstack([t0, t1, t2], axis=-3).a.shape), dinv.b),
-        interpret,
-    )
-    return [LV(scaled.a[..., i, :, :], scaled.b) for i in range(3)]
-
-
 def f12_select(cond: jnp.ndarray, a: LV, b: LV) -> LV:
     return LV(jnp.where(cond[..., None, None, None], a.a, b.a), max(a.b, b.b))
-
-
-def f12_is_one(a: LV, interpret=None) -> jnp.ndarray:
-    """a == 1 in Fq12: subtract the constant one from component 0 and
-    canonically reduce all 12 coordinates in one stacked call."""
-    A = _f12_comps(a)
-    one = lv(jnp.broadcast_to(jnp.asarray(FQ2_ONE), A[0].a.shape).astype(jnp.float32))
-    diff = lstack([lsub(A[0], one)] + A[1:6], axis=-3)
-    return jnp.all(f_canon(diff, interpret) == 0, axis=(-3, -2, -1))

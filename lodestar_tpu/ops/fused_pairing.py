@@ -1,9 +1,9 @@
 """Optimal ate pairing over the fused Pallas kernel core.
 
-The fused twin of ops/pairing.py — same inversion-free jacobian Miller
-loop, same branch-free scan, same BLS12 x-chain final exponentiation
-(computing f^(3*lambda); identical is-one verdicts) — engineered for
-KERNEL-CALL COUNT, the serial cost unit of the fused dispatch:
+The fused twin of ops/pairing.py's Miller product — same inversion-free
+jacobian Miller loop, same branch-free scan — engineered for KERNEL-CALL
+COUNT, the serial cost unit of the fused dispatch.  The final
+exponentiation runs on the host (csrc/fastbls.c), once per batch:
 
 - The doubling step shares its multiply rounds with the point doubling
   (x^2, y^2, yz are common subexpressions) and embeds the Fq line
@@ -12,8 +12,6 @@ KERNEL-CALL COUNT, the serial cost unit of the fused dispatch:
 - Line values are assembled sparse-in-glue but multiplied by the generic
   18-lane f12_mul — lane count is free, calls are not, so a dedicated
   sparse multiply would save nothing.
-- pow-by-x runs the 2-bit-windowed scan (pairing._X_WINDOWS) at 3 calls
-  per iteration (2 fused cyclotomic squarings + one table multiply).
 
 Verified against ops/pairing.py and the bigint oracle in
 tests/test_fused_pairing.py.
@@ -21,19 +19,15 @@ tests/test_fused_pairing.py.
 
 from __future__ import annotations
 
-import numpy as np
-
 import jax.numpy as jnp
 from jax import lax
 
-from ..crypto.bls.fields import BLS_X
 from . import limbs as fl
 from . import tower as tw
 from .fused_core import (
     LV,
     f2_mul,
     ladd,
-    lc,
     lcast,
     lconcat,
     ldbl,
@@ -45,16 +39,12 @@ from .fused_core import (
 )
 from .fused_field import (
     f12_conj,
-    f12_cyc_sqr,
-    f12_frobenius,
-    f12_inv,
-    f12_is_one,
     f12_mul,
     f12_select,
     f12_sqr,
 )
-from .fused_points import COORD_B, Point, fq2_ns
-from .pairing import _X_BITS, _X_WINDOWS
+from .fused_points import COORD_B, Point
+from .pairing import _X_BITS
 
 # scan-carry digit-bound contract for Fq12 values (f12_mul peaks ~11k)
 F12_B = 16384
@@ -184,56 +174,6 @@ def miller_loop(xp: LV, yp: LV, xq: LV, yq: LV, interpret=None) -> LV:
     return f12_conj(lv(f_a, F12_B))
 
 
-def _pow_x_abs(f: LV, interpret=None) -> LV:
-    """f^|BLS_X| via the 2-bit-windowed cyclotomic scan (pairing._pow_x_abs):
-    3 kernel calls per iteration.  The scan carry rides the F12_B contract;
-    the returned bound is the body's true fixpoint bound, captured at trace
-    time (so downstream conjugations don't ratchet past the contract)."""
-    one = lv(jnp.broadcast_to(jnp.asarray(tw.FQ12_ONE), f.a.shape).astype(jnp.float32))
-    f2c = f12_cyc_sqr(f, interpret)
-    f3 = f12_mul(f2c, f, interpret)
-    table = lstack([one, f, f2c, f3], axis=0)
-    out_bound = {}
-
-    def body(r_a, w):
-        r = f12_cyc_sqr(f12_cyc_sqr(lv(r_a, F12_B), interpret), interpret)
-        r = f12_mul(r, LV(jnp.take(table.a, w, axis=0), table.b), interpret)
-        assert r.b <= F12_B
-        out_bound["b"] = r.b
-        return r.a, None
-
-    out, _ = lax.scan(body, one.a, jnp.asarray(_X_WINDOWS))
-    return lv(out, out_bound["b"])
-
-
-def _pow_x(f: LV, interpret=None) -> LV:
-    out = _pow_x_abs(f, interpret)
-    return f12_conj(out) if BLS_X < 0 else out
-
-
-def final_exponentiation(f: LV, interpret=None) -> LV:
-    """f^(3 * (p^12-1)/r) by the x-chain (pairing.final_exponentiation —
-    the identity checks live there)."""
-    f1 = f12_mul(f12_conj(f), f12_inv(f, interpret), interpret)
-    m = f12_mul(
-        f12_frobenius(f12_frobenius(f1, interpret), interpret), f1, interpret
-    )
-    y0 = f12_mul(_pow_x(m, interpret), f12_conj(m), interpret)
-    y1 = f12_mul(_pow_x(y0, interpret), f12_conj(y0), interpret)
-    y2 = f12_mul(_pow_x(y1, interpret), f12_frobenius(y1, interpret), interpret)
-    y3 = f12_mul(
-        f12_mul(
-            _pow_x(_pow_x(y2, interpret), interpret),
-            f12_frobenius(f12_frobenius(y2, interpret), interpret),
-            interpret,
-        ),
-        f12_conj(y2),
-        interpret,
-    )
-    m2 = f12_cyc_sqr(m, interpret)
-    return f12_mul(y3, f12_mul(m2, m, interpret), interpret)
-
-
 def multi_miller_product(xp, yp, xq, yq, mask, interpret=None) -> LV:
     """prod_i f_i over the leading batch axis, masked entries contributing 1
     (pairing.multi_miller_product): one shared final exponentiation
@@ -268,10 +208,3 @@ def f12_product_tree(f: LV, interpret=None) -> LV:
         half = f.a.shape[0] // 2
         f = f12_mul(LV(f.a[:half], f.b), LV(f.a[half:], f.b), interpret)
     return LV(f.a[0], f.b)
-
-
-def pairing_product_is_one(xp, yp, xq, yq, mask, interpret=None) -> jnp.ndarray:
-    return f12_is_one(
-        final_exponentiation(multi_miller_product(xp, yp, xq, yq, mask, interpret), interpret),
-        interpret,
-    )

@@ -1,7 +1,7 @@
 """The fused batched signature-set verification dispatch — round-5 headline.
 
-Semantically identical to ops/batch_verify.verify_signature_sets_kernel
-(same RLC equation, same masking, same subgroup/infinity semantics, same
+Semantically identical to ops/batch_verify.miller_product_kernel (same
+RLC equation, same masking, same subgroup/infinity semantics, same
 ~2^-64 soundness — the TPU redesign of blst's verifyMultipleSignatures
 behind the reference worker pool, chain/bls/multithread/index.ts:39), but
 built on the fused Pallas kernel core, engineered for serial kernel-call
@@ -16,12 +16,12 @@ count:
 - ONE merged Fermat inversion canonicalizes every affine conversion: the
   G2 z-norms (N+1 points) and the G1 z coordinates (N points) share a
   single windowed pow scan.
-- The Miller loop runs ~12 kernel calls per iteration; the final
-  exponentiation ~3 per pow-x window (fused_pairing).
+- The Miller loop runs ~12 kernel calls per iteration (fused_pairing);
+  the final exponentiation runs on the host, once per batch.
 
 Each stage runs under a ``jax.named_scope`` (``hash_to_g2``,
 ``g2_ladder``, ``subgroup_check``, ``cofactor``, ``sig_sum``,
-``g1_ladder``, ``affine``, ``miller_loop``, ``final_exp``): the scope
+``g1_ladder``, ``affine``, ``miller_loop``): the scope
 lands in every op's ``op_name`` metadata (and names the Pallas custom
 calls), and changes nothing else in the program
 (tests/test_fused_scopes.py).
@@ -39,23 +39,19 @@ from jax import named_scope
 
 from ..crypto.bls.fields import BLS_X
 from . import limbs as fl
-from . import tower as tw
 from .fused_core import (
     LV,
     aligned_splice,
-    f2_mul,
-    f_canon,
     f_mul,
     ladd,
     lconcat,
     lneg,
-    lselect,
     lstack,
     lv,
 )
 from .fused_field import f2_is_zero, fi_inv
 from .fused_htc import hash_to_g2_pre_cofactor
-from .fused_pairing import final_exponentiation, multi_miller_product, f12_is_one
+from .fused_pairing import multi_miller_product
 from .fused_points import (
     G1_GEN_NEG_AFFINE,
     Point,
@@ -96,25 +92,6 @@ _L2_BITS = _bits_lsb(abs(BLS_X - 1))
 
 def _neg_point(p: Point) -> Point:
     return (p[0], lneg(p[1]), p[2])
-
-
-def verify_signature_sets_fused(
-    pk_x: jnp.ndarray,
-    pk_y: jnp.ndarray,
-    sig_x: jnp.ndarray,
-    sig_y: jnp.ndarray,
-    msg_u: jnp.ndarray,
-    coeff_bits: jnp.ndarray,
-    mask: jnp.ndarray,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Scalar bool: all live sets verify (batch_verify semantics)."""
-    f, ok = miller_product_fused(
-        pk_x, pk_y, sig_x, sig_y, msg_u, coeff_bits, mask, interpret
-    )
-    with named_scope("final_exp"):
-        product_one = f12_is_one(final_exponentiation(f, interpret), interpret)
-    return product_one & ok
 
 
 def miller_product_fused(

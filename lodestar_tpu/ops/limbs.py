@@ -192,8 +192,7 @@ def _sub_pad(w: int) -> np.ndarray:
 # The unset-env default is BACKEND-AWARE: the one-hot contraction is only a
 # win where a matrix unit exists to absorb it — on CPU/GPU backends the same
 # dots lower to dense (B, 2500) @ (2500, 99) matmuls against mostly-zero
-# constants and measurably LOSE to the sparse-aware ladder (bench.py's
-# limb_mul stage records the ratio per backend).  The env var always
+# constants and measurably LOSE to the sparse-aware ladder.  The env var always
 # overrides, and every mode is read PER CALL at trace time and passed into
 # the jitted implementations as a static argument, so the jit cache key
 # carries the mode and a flip can never reuse a stale program.

@@ -27,10 +27,9 @@ This module turns the mesh into ONE logical verifier:
   output) or ``ring`` (``lax.ppermute`` ring — n-1 hops each overlapping
   one f12 multiply; on TPU ppermute lowers to the ICI async remote copy
   the Pallas ``make_async_remote_copy`` snippets hand-roll);
-- the final exponentiation runs ONCE per merged batch — on the host for
-  the split path (the production dispatch), or once on the replicated
-  post-combine product for the full path — never once per shard.  The
-  jaxpr auditor's sharded rule set pins this structurally.
+- the final exponentiation runs ONCE per merged batch, on the host,
+  never once per shard.  The jaxpr auditor's sharded rule set pins this
+  structurally.
 
 Shard-verdict subtlety: a shard whose slice is all padding has
 ``any_live == False`` (its masked product contributes 1); the mesh
@@ -38,12 +37,11 @@ verdict is ``all(subgroup_ok) & any(any_live)``, NOT an AND over the
 fused per-shard verdicts — which is why the local bodies are the
 ``*_parts`` variants.
 
-Entry family (factories — a mesh is trace-time state, so each returns a
+The entry is a factory — a mesh is trace-time state, so it returns a
 plain function of the 7 packed arrays, ready for ``jax.jit`` or AOT
-``lower().compile()``):
+``lower().compile()``:
 
-    miller_product_sharded(mesh, fused=...)        # split: (f, ok)
-    verify_signature_sets_sharded(mesh, fused=...) # full: scalar bool
+    miller_product_sharded(mesh, fused=...)        # (f, ok)
 """
 
 from __future__ import annotations
@@ -228,48 +226,3 @@ def miller_product_sharded(mesh: Mesh, fused: bool = False,
         return fc.a, ok
 
     return _wrap(mesh, split_body)
-
-
-def verify_signature_sets_sharded(mesh: Mesh, fused: bool = False,
-                                  interpret: bool = False,
-                                  combine: str = "all_gather"):
-    """FULL sharded entry factory: fn(*packed_global) -> scalar bool.
-    The final exponentiation runs on the post-combine replicated product
-    — once per merged batch (physically replicated per chip, never once
-    per SHARD of the batch)."""
-    _check_combine(combine)
-    n_shards = _n_shards(mesh)
-    body = _local_body(fused, interpret, combine, n_shards)
-
-    if fused:
-        from .fused_pairing import f12_is_one, final_exponentiation
-
-        def full_body(*args):
-            fc, ok = body(*args)
-            return final_is_one(fc) & ok
-
-        def final_is_one(fc):
-            return f12_is_one(final_exponentiation(fc, interpret), interpret)
-    else:
-        from . import pairing as kp
-
-        def full_body(*args):
-            fc, ok = body(*args)
-            return tw.fq12_is_one(kp.final_exponentiation(fc.a)) & ok
-
-    def scalar_body(*args):
-        return (full_body(*args),)
-
-    spec = PartitionSpec(MESH_AXIS)
-    wrapped = jax.shard_map(
-        scalar_body,
-        mesh=mesh,
-        in_specs=(spec,) * 7,
-        out_specs=(PartitionSpec(),),
-        check_vma=False,
-    )
-
-    def fn(*args):
-        return wrapped(*args)[0]
-
-    return fn

@@ -6,7 +6,7 @@ The module-level singleton ``TRACER`` is what the instrumented code
 clock) records into; it is disabled by default, and every hot-path site
 gates on the constant-time ``TRACER.enabled`` check.  ``enable()`` /
 ``disable()`` flip it process-wide (CLI: ``--trace-dump`` /
-``--trace-buffer-size``; bench.py flips it around the e2e stages).
+``--trace-buffer-size``; the benchmark flips it for a traced run).
 
 Correlation: the BLS pool assigns each merged batch a monotonically
 increasing id and parks it in a ``contextvars.ContextVar`` before handing

@@ -96,7 +96,7 @@ def _configure_root(level: Optional[str] = None) -> logging.Logger:
     global _configured
     root = logging.getLogger(_ROOT_NAME)
     # guard on the LIVE logger: logging's registry survives a module
-    # re-import (bench spawn children, reload), so `_configured` alone
+    # re-import (spawn children, reload), so `_configured` alone
     # would stack a second stderr handler and double-emit every line
     if not _tagged_handler(root, "stream"):
         handler = logging.StreamHandler(sys.stderr)

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _stubs import FQ12_ONE_DIGITS
+
 from lodestar_tpu.aot.store import (
     AotExecutableStore,
     acquire_lockfile,
@@ -66,11 +68,11 @@ class TestRoundTrip:
         live = tiny_compiled(3.0)
         x = np.arange(4, dtype=np.float32)
         expected = np.asarray(live(x))
-        assert store.save("xla_full", 4, "default", live) is not None
+        assert store.save("xla_split", 4, "default", live) is not None
         # a FRESH store instance (new manifest read) must serve an
         # executable producing the identical output
         fresh = AotExecutableStore(path=str(tmp_path))
-        loaded = fresh.load("xla_full", 4, "default")
+        loaded = fresh.load("xla_split", 4, "default")
         assert loaded is not None
         np.testing.assert_array_equal(np.asarray(loaded(x)), expected)
         assert fresh.hits == 1 and fresh.corrupt == 0
@@ -82,7 +84,7 @@ class TestRoundTrip:
         live = tiny_compiled(5.0)
         x = np.arange(4, dtype=np.float32)
         expected = np.asarray(live(x)).tolist()
-        assert store.save("xla_full", 4, "default", live) is not None
+        assert store.save("xla_split", 4, "default", live) is not None
         xla_flags = os.environ.get("XLA_FLAGS", "")
         code = (
             "import os, sys, json\n"
@@ -92,7 +94,7 @@ class TestRoundTrip:
             "import numpy as np\n"
             "from lodestar_tpu.aot.store import AotExecutableStore\n"
             f"store = AotExecutableStore(path={str(tmp_path)!r})\n"
-            "fn = store.load('xla_full', 4, 'default')\n"
+            "fn = store.load('xla_split', 4, 'default')\n"
             "assert fn is not None, 'store missed in the new process'\n"
             "print(json.dumps(np.asarray(fn(np.arange(4, dtype=np.float32))).tolist()))\n"
         )
@@ -106,14 +108,14 @@ class TestRoundTrip:
     def test_absent_key_is_a_plain_miss(self, tmp_path):
         store = AotExecutableStore(path=str(tmp_path))
         seq0 = JOURNAL.seq
-        assert store.load("xla_full", 4, "default") is None
+        assert store.load("xla_split", 4, "default") is None
         assert store.misses == 1 and store.corrupt == 0 and store.skew == 0
         assert "aot.corrupt" not in kinds_since(seq0)
 
     def test_disabled_store_is_inert(self):
         store = AotExecutableStore(path=None)
-        assert store.load("xla_full", 4, "default") is None
-        assert store.save("xla_full", 4, "default", object()) is None
+        assert store.load("xla_split", 4, "default") is None
+        assert store.save("xla_split", 4, "default", object()) is None
         assert store.stats()["entries"] == 0
 
 
@@ -128,11 +130,11 @@ class TestCrashConsistency:
         never happened — the loader must not even see it (the manifest,
         written last, is the only index it trusts)."""
         store = AotExecutableStore(path=str(tmp_path))
-        assert store.save("xla_full", 4, "default", tiny_compiled()) is not None
+        assert store.save("xla_split", 4, "default", tiny_compiled()) is not None
         orphan = tmp_path / "entries" / "deadbeef.aotx.12345.tmp"
         orphan.write_bytes(b"half-written garbage")
         fresh = AotExecutableStore(path=str(tmp_path))
-        assert fresh.load("xla_full", 4, "default") is not None
+        assert fresh.load("xla_split", 4, "default") is not None
         assert fresh.corrupt == 0
         sweep = fresh.verify()
         assert sweep["orphans"] == [orphan.name]
@@ -141,31 +143,31 @@ class TestCrashConsistency:
 
     def test_checksum_rejection_quarantines(self, tmp_path):
         store = AotExecutableStore(path=str(tmp_path))
-        key = store.save("xla_full", 4, "default", tiny_compiled())
+        key = store.save("xla_split", 4, "default", tiny_compiled())
         rel = store.keys()[key]["file"]
         corrupt_file(str(tmp_path / rel), seed=7)
         seq0 = JOURNAL.seq
         fresh = AotExecutableStore(path=str(tmp_path))
-        assert fresh.load("xla_full", 4, "default") is None
+        assert fresh.load("xla_split", 4, "default") is None
         assert fresh.corrupt == 1
         assert "aot.corrupt" in kinds_since(seq0)
         # quarantined aside (evidence), dropped from the manifest, and
         # the next load is a cheap plain miss
         assert (tmp_path / (rel + ".quarantined")).exists()
         assert key not in fresh.keys()
-        assert fresh.load("xla_full", 4, "default") is None
+        assert fresh.load("xla_split", 4, "default") is None
         assert fresh.corrupt == 1  # counted once, not per retry
 
     def test_version_skew_evicts(self, tmp_path):
         store = AotExecutableStore(path=str(tmp_path))
-        key = store.save("xla_full", 4, "default", tiny_compiled())
+        key = store.save("xla_split", 4, "default", tiny_compiled())
         mpath = tmp_path / "manifest.json"
         doc = json.loads(mpath.read_text())
         doc["entries"][key]["jax"] = "0.0.0-skewed"
         mpath.write_text(json.dumps(doc))
         seq0 = JOURNAL.seq
         fresh = AotExecutableStore(path=str(tmp_path))
-        assert fresh.load("xla_full", 4, "default") is None
+        assert fresh.load("xla_split", 4, "default") is None
         assert fresh.skew == 1
         ev = [e for e in journal_since(seq0) if e["kind"] == "aot.skew"]
         assert ev and ev[0]["reason"] == "jax_version"
@@ -174,25 +176,25 @@ class TestCrashConsistency:
 
     def test_ops_hash_skew_evicts(self, tmp_path):
         store = AotExecutableStore(path=str(tmp_path))
-        key = store.save("xla_full", 4, "default", tiny_compiled())
+        key = store.save("xla_split", 4, "default", tiny_compiled())
         mpath = tmp_path / "manifest.json"
         doc = json.loads(mpath.read_text())
         doc["entries"][key]["ops_hash"] = "feedfacefeedface"
         mpath.write_text(json.dumps(doc))
         fresh = AotExecutableStore(path=str(tmp_path))
-        assert fresh.load("xla_full", 4, "default") is None
+        assert fresh.load("xla_split", 4, "default") is None
         assert fresh.skew == 1
 
     def test_truncated_manifest_survivable(self, tmp_path):
         store = AotExecutableStore(path=str(tmp_path))
-        store.save("xla_full", 4, "default", tiny_compiled())
+        store.save("xla_split", 4, "default", tiny_compiled())
         mpath = tmp_path / "manifest.json"
         blob = mpath.read_bytes()
         mpath.write_bytes(blob[: len(blob) // 2])
         seq0 = JOURNAL.seq
         fresh = AotExecutableStore(path=str(tmp_path))
         assert fresh.keys() == {}
-        assert fresh.load("xla_full", 4, "default") is None
+        assert fresh.load("xla_split", 4, "default") is None
         ev = [e for e in journal_since(seq0) if e["kind"] == "aot.corrupt"]
         assert ev and ev[0]["what"] == "manifest"
 
@@ -200,7 +202,7 @@ class TestCrashConsistency:
         """A payload whose bytes match the manifest but whose pickle is
         poison (written corrupt at save time) still degrades cleanly."""
         store = AotExecutableStore(path=str(tmp_path))
-        key = store.save("xla_full", 4, "default", tiny_compiled())
+        key = store.save("xla_split", 4, "default", tiny_compiled())
         rec = store.keys()[key]
         fpath = tmp_path / rec["file"]
         bad = pickle.dumps(("not", "an", "executable"))
@@ -212,7 +214,7 @@ class TestCrashConsistency:
         doc["entries"][key]["sha256"] = hashlib.sha256(bad).hexdigest()
         mpath.write_text(json.dumps(doc))
         fresh = AotExecutableStore(path=str(tmp_path))
-        assert fresh.load("xla_full", 4, "default") is None
+        assert fresh.load("xla_split", 4, "default") is None
         assert fresh.corrupt == 1
 
 
@@ -230,13 +232,13 @@ class TestLockfile:
         lock.write_text(json.dumps({"pid": os.getpid(), "wall": 0}))
         seq0 = JOURNAL.seq
         t0 = time.monotonic()
-        assert store.save("xla_full", 4, "default", tiny_compiled()) is None
+        assert store.save("xla_split", 4, "default", tiny_compiled()) is None
         assert time.monotonic() - t0 < 3.0
         assert store.lock_bypasses == 1
         assert "aot.lock_busy" in kinds_since(seq0)
         # release: the next save goes through
         lock.unlink()
-        assert store.save("xla_full", 4, "default", tiny_compiled()) is not None
+        assert store.save("xla_split", 4, "default", tiny_compiled()) is not None
 
     def test_stale_lock_from_dead_pid_is_broken(self, tmp_path):
         """A writer that died mid-save must not wedge the store: its
@@ -273,17 +275,17 @@ class TestLockfile:
         store = AotExecutableStore(
             path=str(blocker / "store"), lock_wait_s=0.1
         )
-        assert store.save("xla_full", 4, "default", tiny_compiled()) is None
-        assert store.load("xla_full", 4, "default") is None  # plain miss
+        assert store.save("xla_split", 4, "default", tiny_compiled()) is None
+        assert store.load("xla_split", 4, "default") is None  # plain miss
 
     def test_loads_take_no_lock(self, tmp_path):
         store = AotExecutableStore(path=str(tmp_path))
-        store.save("xla_full", 4, "default", tiny_compiled())
+        store.save("xla_split", 4, "default", tiny_compiled())
         (tmp_path / "store.lock").write_text(
             json.dumps({"pid": os.getpid(), "wall": 0})
         )
         t0 = time.monotonic()
-        assert store.load("xla_full", 4, "default") is not None
+        assert store.load("xla_split", 4, "default") is not None
         assert time.monotonic() - t0 < 1.0
 
 
@@ -304,9 +306,10 @@ def tiny_verifier_factory():
         kw.setdefault("buckets", (BUCKET,))
         kw.setdefault("platform", "cpu")
         kw.setdefault("fused", False)
-        kw.setdefault("host_final_exp", False)
         v = TpuBlsVerifier(aot_store=store, **kw)
-        v._kernel = lambda key: (lambda *a: jnp.asarray(True))
+        v._kernel = lambda key: (
+            lambda *a: (jnp.asarray(FQ12_ONE_DIGITS), jnp.asarray(True))
+        )
         built.append(v)
         return v
 
@@ -353,7 +356,7 @@ class TestVerifierLadder:
         v2 = tiny_verifier_factory(store2)
         v2.warmup()
         assert store2.hits == 1
-        key = (BUCKET, False, False)
+        key = (BUCKET, False)
         assert key in v2._executors[0].compiled
         assert v2.verify_signature_sets(make_sets(2)) == live_verdict is True
 
@@ -450,7 +453,7 @@ class TestVerifierLadder:
         v2 = tiny_verifier_factory(store2)
         v2.warmup()
         summary = COMPILE_LEDGER.session_summary()
-        assert "aot_load" in summary.get("xla_full", {})
+        assert "aot_load" in summary.get("xla_split", {})
 
 
 class TestCpuCodegenGate:

@@ -184,9 +184,9 @@ class TestMutations:
             from lodestar_tpu.crypto.bls.tpu_verifier import TpuBlsVerifier
 
             def test_drives_stub_programs():
-                v = TpuBlsVerifier(buckets=(4,), fused=False, host_final_exp=False)
+                v = TpuBlsVerifier(buckets=(4,), fused=False)
                 for ex in v._executors:
-                    ex.compiled[(4, False, False)] = lambda *a: True
+                    ex.compiled[(4, False)] = lambda *a: True
                 assert v.verify_signature_sets([])
         """)
         assert _audit(tmp_path, [p]) == []
@@ -315,9 +315,9 @@ class TestMutations:
             from lodestar_tpu.crypto.bls.tpu_verifier import TpuBlsVerifier
 
             def make_stub():
-                v = TpuBlsVerifier(buckets=(4,), fused=False, host_final_exp=False)
+                v = TpuBlsVerifier(buckets=(4,), fused=False)
                 for ex in v._executors:
-                    ex.compiled[(4, False, False)] = lambda *a: True
+                    ex.compiled[(4, False)] = lambda *a: True
                 return v
 
             def test_uses_stub():

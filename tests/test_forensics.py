@@ -1,13 +1,12 @@
 """Flight recorder & failure forensics (round 9): the always-on event
 journal, the in-flight stall watchdog, diagnostic bundles, and the
-bench stage-child salvage path.
+stage-child salvage heartbeats.
 
 Budget discipline (tests/conftest.py compile guard): every test here is
 host-side — the fault-injection tests wedge a real ``TpuBlsVerifier``
 whose per-executor device programs are stubs (the
 tests/test_multidevice_scheduler.py pattern), so nothing is traced or
-compiled by XLA.  The bench salvage test spawns a child that sleeps; it
-imports jax but never touches a device program.
+compiled by XLA.
 """
 
 import importlib.util
@@ -18,6 +17,8 @@ import signal
 import time
 
 import pytest
+
+from _stubs import split_program
 
 from lodestar_tpu import tracing
 from lodestar_tpu.crypto.bls.api import interop_secret_key
@@ -98,10 +99,10 @@ def make_sets(n, start=0):
 def stub_verifier(buckets=(4,)):
     """Real TpuBlsVerifier (real pack, real in-flight registration) whose
     device programs are host stubs — no XLA trace or compile."""
-    v = TpuBlsVerifier(buckets=buckets, fused=False, host_final_exp=False)
+    v = TpuBlsVerifier(buckets=buckets, fused=False)
     for ex in v._executors:
         for b in buckets:
-            ex.compiled[(b, False, False)] = lambda *a: True
+            ex.compiled[(b, False)] = split_program()
     return v
 
 
@@ -658,44 +659,11 @@ class TestRestForensics:
 
 
 # ---------------------------------------------------------------------------
-# bench salvage: a timed-out stage child leaves a diagnosable artifact
+# stage-child salvage: heartbeat bundles, scoped by stage and pid
 # ---------------------------------------------------------------------------
 
 
 class TestBenchSalvage:
-    def test_stage_timeout_attaches_salvage_bundle(self, tmp_path, monkeypatch):
-        """Acceptance: killing a bench stage child via the existing
-        ``BENCH_STAGE_TIMEOUT_S`` path yields a bundle path in the stage
-        error that ``tools/inspect_bundle.py`` validates and summarizes —
-        the next rc=124 is a diagnosable artifact, not a wall-clock
-        number.  The child only sleeps (``bench_wedge``); no device
-        program is built on either side."""
-        import bench
-        from lodestar_tpu.forensics import salvage
-
-        monkeypatch.setenv(salvage.BASE_DIR_ENV, str(tmp_path))
-        monkeypatch.setenv(salvage.INTERVAL_ENV, "0.2")
-        # generous enough for the spawn child to finish importing jax +
-        # bench and write its first heartbeat; tiny next to a real stage
-        monkeypatch.setenv("BENCH_STAGE_TIMEOUT_S", "30")
-
-        out, err = bench._stage("bench_wedge", (3600.0,), retries=0)
-        assert out is None
-        assert isinstance(err, dict)
-        assert err["error"].startswith("timeout after")
-        bundle = err["bundle"]
-        assert bundle, "timeout carried no salvage bundle"
-        assert bundle.startswith(str(tmp_path))
-
-        assert inspect_bundle.validate(bundle) == []
-        s = inspect_bundle.summarize(bundle)
-        assert s["reason"] == "heartbeat"
-        # the child journaled its own stage start before wedging
-        events = [json.loads(l) for l in open(os.path.join(bundle, "journal.jsonl"))]
-        starts = [e for e in events if e.get("kind") == "bench.stage_start"]
-        assert starts and starts[0]["stage"] == "bench_wedge"
-        assert starts[0]["pid"] != os.getpid()
-
     def test_latest_stage_bundle_scoping(self, tmp_path, monkeypatch):
         from lodestar_tpu.forensics import salvage
 

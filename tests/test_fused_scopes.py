@@ -1,7 +1,7 @@
-"""Named device stages of the fused program (ops/fused_verify): every
-stage scope reaches the lowered program's op metadata, and the scopes
-change nothing else.  The program is lowered for the TPU platform on the
-CPU at bucket 4; nothing is compiled."""
+"""Named device stages of the fused split program (ops/fused_verify):
+every stage scope reaches the lowered program's op metadata, and the
+scopes change nothing else.  The program is lowered for the TPU platform
+on the CPU at bucket 4; nothing is compiled."""
 
 import base64
 import contextlib
@@ -15,18 +15,19 @@ from lodestar_tpu.analysis import jaxpr_audit
 from lodestar_tpu.ops import fused_verify as fv
 
 STAGES = ("hash_to_g2", "g2_ladder", "subgroup_check", "cofactor", "sig_sum",
-          "g1_ladder", "affine", "miller_loop", "final_exp")
+          "g1_ladder", "affine", "miller_loop")
 _METADATA = re.compile(r", metadata=\{[^}]*\}")
 # a Mosaic kernel's serialized module, as the custom call carries it
 _BODY = re.compile(r'"body": "([A-Za-z0-9+/=]+)"')
 
 
 def _lower():
-    def full(*a):  # a fresh function per call: no jit cache hit between calls
-        return fv.verify_signature_sets_fused(*a, interpret=False)
+    def split(*a):  # a fresh function per call: no jit cache hit between calls
+        f, ok = fv.miller_product_fused(*a, interpret=False)
+        return f.a, ok
 
     args = jaxpr_audit._abstract_batch(4)
-    return jax.jit(full).trace(*args).lower(lowering_platforms=("tpu",))
+    return jax.jit(split).trace(*args).lower(lowering_platforms=("tpu",))
 
 
 def _without_metadata(hlo: str) -> str:

@@ -14,8 +14,8 @@ These tests pin the contract ON CPU, without a Mosaic compile:
 2. The traced fused call graph (buckets 4 and 128) contains NO
    concatenate that mixes operand extents along the concat dimension
    while every tiled non-concat dim sits below the (8, 128) tile.
-3. Shape equivalence: the fused entry points produce exactly the
-   XLA-graph kernels' output shapes/dtypes at buckets {4, 128}
+3. Shape equivalence: the fused split program produces exactly the
+   XLA-graph kernel's output shapes/dtypes at buckets {4, 128}
    (jax.eval_shape — abstract, no FLOPs).
 4. (slow) value equivalence of the fused vs XLA Miller product in
    interpret mode at bucket 4.
@@ -32,10 +32,7 @@ from lodestar_tpu.analysis import jaxpr_audit
 from lodestar_tpu.ops import batch_verify as bv
 from lodestar_tpu.ops import limbs as fl
 from lodestar_tpu.ops.fused_core import LV, aligned_splice, lconcat
-from lodestar_tpu.ops.fused_verify import (
-    miller_product_fused,
-    verify_signature_sets_fused,
-)
+from lodestar_tpu.ops.fused_verify import miller_product_fused
 
 rng = np.random.default_rng(29)
 
@@ -84,12 +81,11 @@ class TestAlignedSplice:
 # where tools/lint.py and tests/test_static_analysis.py drive it too.
 # These tests consume the auditor's per-(entry, bucket) ARTIFACTS —
 # in-process lru-cached AND persisted under .jax_cache/ keyed by a content
-# hash of lodestar_tpu/ops/ — so a trace of the full fused graph (the
+# hash of lodestar_tpu/ops/ — so a trace of the fused graph (the
 # expensive part, ~15-30s) is paid once per ops/ edit, not once per run.
 
 _ENTRY_NAMES = {
     "split": "fused_verify.miller_product_fused",
-    "full": "fused_verify.verify_signature_sets_fused",
 }
 
 
@@ -98,11 +94,10 @@ def _mixed_concats(bucket, entry_name):
     return art["mixed_concats"]
 
 
-# coverage note: split@4, full@4, split@128, full@128 are exactly the
-# auditor's AUDIT_BUCKETS matrix, so every combination here rides the
-# shared cache
+# coverage note: split@4 and split@128 are exactly the auditor's
+# AUDIT_BUCKETS matrix, so both ride the shared cache
 @pytest.mark.parametrize(
-    "bucket,entry", [(4, "split"), (4, "full"), (128, "split")]
+    "bucket,entry", [(4, "split"), (128, "split")]
 )
 def test_fused_graph_has_no_narrow_mixed_concat(bucket, entry):
     bad = _mixed_concats(bucket, entry)
@@ -126,14 +121,6 @@ def test_fused_shapes_match_xla_kernel(bucket):
     assert got[0][0] == want[0][0] == (6, 2, fl.NLIMBS)
     assert got[1][0] == want[1][0] == ()
     assert got[1][1] == want[1][1]
-
-
-def test_fused_full_verdict_shape_matches_xla_kernel():
-    # the XLA twin's output is a static scalar bool
-    # (batch_verify.verify_signature_sets_kernel docstring) — asserting
-    # against the literal avoids a second whole-graph XLA trace
-    got_full = jaxpr_audit.entry_out_avals(_ENTRY_NAMES["full"], 4)
-    assert got_full == [((), "bool")]
 
 
 # ---------------------------------------------------------------------------

@@ -14,6 +14,7 @@ standalone with ``pytest tests/test_multidevice_scheduler.py -m slow``.
 """
 
 import asyncio
+import threading
 import time
 
 import numpy as np
@@ -26,6 +27,8 @@ from lodestar_tpu.crypto.bls.tpu_verifier import TpuBlsVerifier
 from lodestar_tpu.crypto.bls.verifier import SingleSignatureSet
 from lodestar_tpu.metrics import create_metrics
 from lodestar_tpu.tracing import TRACER
+
+from _stubs import split_output
 
 
 @pytest.fixture(autouse=True)
@@ -52,51 +55,80 @@ def make_sets(n, start=0, key_mod=256):
     return out
 
 
-class _SlowVerdict:
-    """Device-latency stand-in: the bool() read (PendingVerdict's sync
-    point on the fused-verdict path) blocks until ``ready_at``, exactly
-    like a real device readback."""
+class _DeviceGate:
+    """The stub device: every batch it receives is held until the test
+    releases it.  Each dispatch makes the subgroup verdict of a split
+    program's output a ``_HeldVerdict`` whose ``bool()`` read (the
+    device sync point) waits on the batch's own event."""
 
-    def __init__(self, ready_at, value=True):
-        self._ready_at = ready_at
-        self._value = value
+    def __init__(self, hold=True):
+        self.hold = hold
+        self._lock = threading.Lock()
+        self.events = []  # one per dispatched batch, in dispatch order
+
+    def program(self, *a):
+        ev = threading.Event()
+        if not self.hold:
+            ev.set()
+        with self._lock:
+            self.events.append(ev)
+        return split_output(_HeldVerdict(ev))
+
+    def held(self):
+        with self._lock:
+            return sum(not ev.is_set() for ev in self.events)
+
+    def release_all(self):
+        with self._lock:
+            for ev in self.events:
+                ev.set()
+
+
+class _HeldVerdict:
+    def __init__(self, event):
+        self._event = event
 
     def __bool__(self):
-        rem = self._ready_at - time.monotonic()
-        if rem > 0:
-            time.sleep(rem)
-        return self._value
+        assert self._event.wait(30), "the test never released this batch"
+        return True
 
 
-def stub_verifier(n_devices, buckets=(4,), device_s=0.0, pack_s=0.0, **kw):
+def stub_verifier(n_devices, buckets=(4,), gate=None, **kw):
     """A real TpuBlsVerifier (real pack, real scheduler, real spans) whose
     per-executor compiled programs are host stubs — no XLA trace/compile,
-    conftest's compile guard stays quiet."""
+    conftest's compile guard stays quiet.  ``gate`` holds each batch on
+    the stub device until the test releases it (default: a gate that
+    holds nothing)."""
     import jax
 
     devices = jax.devices("cpu")[:n_devices] if n_devices > 1 else None
-
-    if pack_s:
-        class _V(TpuBlsVerifier):
-            def pack(self, sets):
-                time.sleep(pack_s)
-                return super().pack(sets)
-        v = _V(buckets=buckets, devices=devices, fused=False,
-               host_final_exp=False, **kw)
-    else:
-        v = TpuBlsVerifier(buckets=buckets, devices=devices, fused=False,
-                           host_final_exp=False, **kw)
+    gate = gate or _DeviceGate(hold=False)
+    v = TpuBlsVerifier(buckets=buckets, devices=devices, fused=False, **kw)
     for ex in v._executors:
         for b in buckets:
-            ex.compiled[(b, False, False)] = (
-                lambda *a: _SlowVerdict(time.monotonic() + device_s)
-            )
+            ex.compiled[(b, False)] = gate.program
     return v
+
+
+async def _run_held(pool, gate, jobs, expect_held):
+    """Submit ``jobs`` 1-set jobs at once, wait until ``expect_held``
+    batches sit on the stub devices together (or every job's verdict is
+    back), release them, and return the verdicts."""
+    tasks = [asyncio.create_task(pool.verify_signature_sets(make_sets(1, start=4 * i)))
+             for i in range(jobs)]
+    deadline = time.monotonic() + 30
+    while gate.held() < expect_held and time.monotonic() < deadline:
+        await asyncio.sleep(0.001)
+    held = gate.held()
+    while not all(t.done() for t in tasks):
+        gate.release_all()
+        await asyncio.sleep(0.001)
+    return held, [t.result() for t in tasks]
 
 
 class TestScheduler:
     def test_least_loaded_placement(self):
-        v = stub_verifier(4, device_s=0.0)
+        v = stub_verifier(4)
         pend = [v.dispatch(v.pack(make_sets(2, start=4 * i))) for i in range(4)]
         # four idle devices, four batches: every executor gets exactly one
         assert {p.device for p in pend} == {"cpu:0", "cpu:1", "cpu:2", "cpu:3"}
@@ -120,11 +152,14 @@ class TestScheduler:
     def test_round_robin_fan_out_oversized_batch(self):
         """An oversized batch chunks at buckets[-1] and the chunks spread
         across the pool (the range-sync shape)."""
-        v = stub_verifier(4, buckets=(4,), device_s=0.05)
+        gate = _DeviceGate()
+        v = stub_verifier(4, buckets=(4,), gate=gate)
         pending = v.verify_signature_sets_async(make_sets(10))
         parts = pending._parts
         assert parts is not None and len(parts) == 3  # 4 + 4 + 2
         assert len({p.device for p in parts}) == 3  # distinct devices
+        assert gate.held() == 3  # all three chunks on their devices at once
+        gate.release_all()
         assert pending.result() is True
 
     def test_single_device_default_unchanged(self):
@@ -154,16 +189,14 @@ class TestPoolMultiDevice:
 
         async def main():
             tracing.enable(2048)
-            v = stub_verifier(4, device_s=0.06, pack_s=0.02)
+            gate = _DeviceGate()
+            v = stub_verifier(4, gate=gate)
+            # flush_threshold 1: each 1-set job is its own batch
             pool = BlsBatchPool(v, max_buffer_wait=0.004, pipeline_depth=2,
-                                metrics=create_metrics())
-            jobs = [asyncio.create_task(pool.verify_signature_sets(make_sets(1)))]
-            for i in range(1, 4):
-                await asyncio.sleep(0.018)
-                jobs.append(asyncio.create_task(
-                    pool.verify_signature_sets(make_sets(1, start=4 * i))
-                ))
-            assert await asyncio.gather(*jobs) == [True] * 4
+                                flush_threshold=1, metrics=create_metrics())
+            held, verdicts = await _run_held(pool, gate, 4, expect_held=4)
+            assert verdicts == [True] * 4
+            assert held == 4  # four batches on the devices together
             pool.close()
             return pool
 
@@ -192,20 +225,20 @@ class TestPoolMultiDevice:
     def test_pipeline_depth_is_per_device(self):
         """depth 1 on a 4-device pool still keeps >= 2 batches in flight
         (window = depth x n_devices); the same depth on one device is
-        serial (peak 1)."""
+        serial (peak 1).  Each batch is held on its stub device until the
+        test releases it, so the peak does not depend on timing."""
 
         def run_pool(n_devices):
             async def main():
-                v = stub_verifier(n_devices, device_s=0.06, pack_s=0.015)
-                pool = BlsBatchPool(v, max_buffer_wait=0.004, pipeline_depth=1)
-                jobs = [asyncio.create_task(
-                    pool.verify_signature_sets(make_sets(1)))]
-                for i in range(1, 4):
-                    await asyncio.sleep(0.013)
-                    jobs.append(asyncio.create_task(
-                        pool.verify_signature_sets(make_sets(1, start=4 * i))
-                    ))
-                assert await asyncio.gather(*jobs) == [True] * 4
+                gate = _DeviceGate()
+                v = stub_verifier(n_devices, gate=gate)
+                # flush_threshold 1: each 1-set job is its own batch
+                pool = BlsBatchPool(v, max_buffer_wait=0.004, pipeline_depth=1,
+                                    flush_threshold=1)
+                held, verdicts = await _run_held(pool, gate, 4,
+                                                 expect_held=n_devices)
+                assert verdicts == [True] * 4
+                assert held == n_devices
                 pool.close()
                 return pool.inflight_peak
 

@@ -13,7 +13,7 @@ are stub-program or artifact-riding only —
   artifacts (disk-cached, content-addressed on ops/ — rebuilt by
   ``python tools/lint.py``, abstract traces only, no backend compiles);
 - the REAL multi-device executions (GT combine vs the bigint oracle,
-  full sharded-entry equivalence) compile small mesh programs (~3-6 s
+  sharded-entry equivalence) compile small mesh programs (~3-6 s
   each) and are ``@pytest.mark.slow`` — run them standalone with
   ``pytest tests/test_sharded_verify.py -m slow``.
 """
@@ -43,8 +43,9 @@ from lodestar_tpu.tracing import TRACER
 
 from tools.check_trace import validate_pipeline
 
+from _stubs import split_program
+
 SPLIT_ENTRY = "sharded_verify.miller_product_sharded"
-FULL_ENTRY = "sharded_verify.verify_signature_sets_sharded"
 
 
 @pytest.fixture(autouse=True)
@@ -71,11 +72,8 @@ def make_sets(n, start=0):
     return out
 
 
-FQ12_ONE_F32 = np.asarray(tw.FQ12_ONE, dtype=np.float32)
-
-
-def sharded_stub_verifier(n_devices=4, bucket=8, host_final_exp=False,
-                          mesh_program=None, pool_program=None, **kw):
+def sharded_stub_verifier(n_devices=4, bucket=8, mesh_program=None,
+                          pool_program=None, **kw):
     """Real TpuBlsVerifier (real pack, real routing, real spans) with
     host stubs in BOTH the mesh pseudo-executor and the per-device
     executors, so every tier of the ladder is dispatchable without XLA."""
@@ -83,15 +81,11 @@ def sharded_stub_verifier(n_devices=4, bucket=8, host_final_exp=False,
 
     v = TpuBlsVerifier(
         buckets=(bucket,), devices=jax.devices("cpu")[:n_devices],
-        fused=False, host_final_exp=host_final_exp,
-        sharded=True, sharded_min_batch=bucket, **kw,
+        fused=False, sharded=True, sharded_min_batch=bucket, **kw,
     )
-    key = (bucket, host_final_exp, False)
+    key = (bucket, False)
     if mesh_program is None:
-        if host_final_exp:
-            mesh_program = lambda *a: (FQ12_ONE_F32, np.True_)  # noqa: E731
-        else:
-            mesh_program = lambda *a: np.True_  # noqa: E731
+        mesh_program = split_program()
     v._mesh_ex.compiled[key] = mesh_program
     if pool_program is None:
         pool_program = mesh_program
@@ -107,36 +101,27 @@ def sharded_stub_verifier(n_devices=4, bucket=8, host_final_exp=False,
 
 class TestShardedEntryStructure:
     def test_sharded_entries_audit_clean(self):
-        """Both mesh entries pass the full sharded rule set (collective
-        present, final-exp after the combine, no Mosaic-unretileable
+        """The mesh entry passes the full sharded rule set (collective
+        present, no final-exp on the device, no Mosaic-unretileable
         concats in the mapped body, stable cache keys)."""
         if not jaxpr_audit.sharded_audit_available():
             pytest.skip("needs >= 2 devices for the trace-time mesh")
-        vs = []
-        for name in (SPLIT_ENTRY, FULL_ENTRY):
-            vs.extend(
-                jaxpr_audit.audit_entry(
-                    name, jaxpr_audit.SHARDED_AUDIT_BUCKETS
-                )
-            )
+        vs = jaxpr_audit.audit_entry(
+            SPLIT_ENTRY, jaxpr_audit.SHARDED_AUDIT_BUCKETS
+        )
         assert vs == [], [f"{v.rule}: {v.message}" for v in vs]
 
     def test_final_exp_runs_once_per_merged_batch(self):
-        """The acceptance pin: the split entry contains ZERO final-exp
-        scans (the host runs it, once per batch); the full entry
-        contains exactly one final exponentiation's worth of pow-x
-        scans, every one AFTER the cross-shard combine — never once per
-        shard."""
+        """The acceptance pin: the mesh entry combines across shards and
+        contains ZERO final-exp scans — the host runs the final
+        exponentiation once per merged batch, never once per shard."""
         if not jaxpr_audit.sharded_audit_available():
             pytest.skip("needs >= 2 devices for the trace-time mesh")
         (bucket,) = jaxpr_audit.SHARDED_AUDIT_BUCKETS
         split = jaxpr_audit.entry_artifacts(SPLIT_ENTRY, bucket)["sharded"]
-        full = jaxpr_audit.entry_artifacts(FULL_ENTRY, bucket)["sharded"]
         assert split["collectives"], "split entry lost its combine"
         assert split["final_exp_scans"] == 0
-        assert full["collectives"], "full entry lost its combine"
-        assert full["final_exp_scans"] == jaxpr_audit.FINAL_EXP_POW_SCANS
-        assert full["final_exp_scans_before_combine"] == 0
+        assert split["final_exp_scans_before_combine"] == 0
 
     def test_split_output_contract_matches_single_chip(self):
         """The sharded split entry returns exactly what the single-chip
@@ -222,7 +207,7 @@ class TestShardedDispatch:
         """The behavioral half of the final-exp-once pin: a mesh-wide
         split batch costs exactly ONE host final exponentiation (the
         per-device fan-out of the same sets would cost n_devices)."""
-        v = sharded_stub_verifier(n_devices=4, bucket=8, host_final_exp=True)
+        v = sharded_stub_verifier(n_devices=4, bucket=8)
         assert v.dispatch(v.pack(make_sets(8))).result() is True
         assert v.host_final_exps == 1
 
@@ -231,7 +216,7 @@ class TestShardedDispatch:
         # below sharded_min_batch: per-device placement
         v.buckets = (4, 8)
         for ex in v._executors:
-            ex.compiled[(4, False, False)] = lambda *a: np.True_
+            ex.compiled[(4, False)] = split_program()
         p = v.dispatch(v.pack(make_sets(3)))
         assert p.device.startswith("cpu:")
         assert v.sharded_batches == 0
@@ -249,7 +234,7 @@ class TestShardedDispatch:
         v = sharded_stub_verifier(n_devices=4, bucket=8)
         hits_before = (
             COMPILE_LEDGER._session_total.get(
-                COMPILE_LEDGER.key("sharded_full", 8, "mesh4"), {}
+                COMPILE_LEDGER.key("sharded_split", 8, "mesh4"), {}
             ).get("kinds", {}).get("hit", {}).get("count", 0)
         )
         assert v.dispatch(v.pack(make_sets(8))).result() is True
@@ -260,7 +245,7 @@ class TestShardedDispatch:
         assert not any("cpu:" in k for k in keys), keys
         hits_after = (
             COMPILE_LEDGER._session_total.get(
-                COMPILE_LEDGER.key("sharded_full", 8, "mesh4"), {}
+                COMPILE_LEDGER.key("sharded_split", 8, "mesh4"), {}
             ).get("kinds", {}).get("hit", {}).get("count", 0)
         )
         assert hits_after == hits_before + 1
@@ -286,12 +271,12 @@ class TestShardedDispatch:
 
         v = TpuBlsVerifier(
             buckets=(8,), devices=jax.devices("cpu")[:4], fused=False,
-            host_final_exp=False, sharded=True, sharded_min_batch=8,
+            sharded=True, sharded_min_batch=8,
             aot_store=FakeStore(), load_only=True,
         )
         with pytest.raises(AotStoreMiss):
             v._mesh_fn(8)
-        assert calls == [("sharded_full", 8, "mesh4")]
+        assert calls == [("sharded_split", 8, "mesh4")]
 
     def test_enqueue_failure_degrades_to_pool_once(self):
         """A mesh program that cannot even enqueue hops the batch down
@@ -303,7 +288,7 @@ class TestShardedDispatch:
 
         v = sharded_stub_verifier(n_devices=4, bucket=8,
                                   mesh_program=broken,
-                                  pool_program=lambda *a: np.True_)
+                                  pool_program=split_program())
         seq0 = JOURNAL.seq
         p = v.dispatch(v.pack(make_sets(8)))
         assert p.device.startswith("cpu:")
@@ -333,7 +318,7 @@ class TestShardedDispatch:
 
         v = TpuBlsVerifier(
             buckets=(8,), devices=jax.devices("cpu")[:4], fused=False,
-            host_final_exp=False, sharded=True, sharded_min_batch=8,
+            sharded=True, sharded_min_batch=8,
             aot_store=MissStore(), load_only=True,
         )
         seq0 = JOURNAL.seq
@@ -344,6 +329,95 @@ class TestShardedDispatch:
             if e["seq"] >= seq0 and e["kind"] == "bls.degrade"
         ]
         assert len(degrades) == 1 and degrades[0]["device"] == "mesh4"
+
+
+class TestEnqueueBookkeeping:
+    """The one enqueue path both tiers share (``TpuBlsVerifier._enqueued``):
+    a per-device batch (3 sets, bucket 4, below ``sharded_min_batch``)
+    and a mesh batch (8 sets, bucket 8) through one 4-device stub
+    verifier."""
+
+    #: tier -> live sets of its batch
+    TIERS = {"per-device": 3, "mesh": 8}
+
+    @staticmethod
+    def _slots(v):
+        return {**v.device_inflight(), v._mesh_ex.name: v._mesh_ex.inflight}
+
+    @pytest.mark.parametrize("outcome", ["success", "enqueue_failure"])
+    @pytest.mark.parametrize("tier", sorted(TIERS))
+    def test_span_inflight_entry_and_slot(self, tier, outcome):
+        from lodestar_tpu import tracing
+        from lodestar_tpu.forensics.watchdog import INFLIGHT
+
+        def broken(*a):
+            raise RuntimeError("enqueue exploded")
+
+        fail = outcome == "enqueue_failure"
+        v = sharded_stub_verifier(
+            n_devices=4, bucket=8,
+            mesh_program=broken if fail and tier == "mesh" else None,
+            pool_program=split_program(),
+        )
+        v.buckets = (4, 8)
+        for ex in v._executors:
+            ex.compiled[(4, False)] = (
+                broken if fail and tier == "per-device" else split_program()
+            )
+        live = self.TIERS[tier]
+        TRACER.enable(512)
+        seq0 = JOURNAL.seq
+        before = {e["token"] for e in INFLIGHT.snapshot()}
+
+        def new_entries():
+            return [e for e in INFLIGHT.snapshot() if e["token"] not in before]
+
+        token = tracing.set_batch(4242)
+        try:
+            if fail and tier == "per-device":
+                with pytest.raises(RuntimeError, match="enqueue exploded"):
+                    v.dispatch(v.pack(make_sets(live)))
+                # nothing counted, nothing registered, the slot returned,
+                # and the executor's health took the failure
+                assert v.dispatches == 0 and not new_entries()
+                assert set(self._slots(v).values()) == {0}
+                assert v.executor_health()["cpu:0"]["failures"] == 1
+                assert not [s for s in TRACER.spans() if s.name == "bls.dispatch"]
+                return
+            p = v.dispatch(v.pack(make_sets(live)))
+        finally:
+            tracing.reset_batch(token)
+        on_mesh = tier == "mesh" and not fail
+        if fail:
+            # the mesh slot came back and the batch hopped down to the
+            # per-device tier in the same call
+            assert v.sharded_fallbacks == 1
+        assert p.device == ("mesh4" if on_mesh else "cpu:0")
+        bucket = 4 if tier == "per-device" else 8
+        # in flight: the slot held and the in-flight entry live
+        assert self._slots(v) == {**{f"cpu:{i}": 0 for i in range(4)},
+                                  "mesh4": 0, p.device: 1}
+        (entry,) = new_entries()
+        assert (entry["cid"], entry["device"], entry["bucket"],
+                entry["sets"]) == (4242, p.device, bucket, live)
+        assert p.result() is True
+        # resolved: the entry gone and every slot returned
+        assert not new_entries()
+        assert set(self._slots(v).values()) == {0}
+        attrs = {"bucket": bucket, "fused": False, "device": p.device,
+                 "devices_total": 4}
+        if on_mesh:
+            attrs.update(sharded=True, mesh_devices=4)
+        (span,) = [s for s in TRACER.spans() if s.name == "bls.dispatch"]
+        assert span.cid == 4242 and span.args == attrs
+        (rec,) = [e for e in JOURNAL.events()
+                  if e["seq"] >= seq0 and e["kind"] == "bls.dispatch"]
+        assert {k: rec[k] for k in attrs} == attrs
+        assert (rec["cid"], rec["sets"], rec["inflight"]) == (4242, live, 1)
+        assert set(rec) - set(attrs) == {
+            "ts_ns", "wall", "kind", "level", "seq", "cid", "sets", "inflight"}
+        assert (v.dispatches, v.sets_verified) == (1, live)
+        assert v.sharded_batches == int(on_mesh)
 
 
 class TestShardedChaos:
@@ -430,8 +504,7 @@ class TestPoolMeshWindow:
         """8 concurrent 1-set jobs merge into ONE mesh-spanning batch
         (not 4 per-device placements), and the resulting dump passes
         check_trace's pipeline + mesh rules."""
-        v = sharded_stub_verifier(n_devices=4, bucket=8,
-                                  host_final_exp=True)
+        v = sharded_stub_verifier(n_devices=4, bucket=8)
 
         async def run():
             TRACER.enable(1024)
@@ -487,11 +560,11 @@ class TestMeshWarmup:
 
         v = TpuBlsVerifier(
             buckets=(8,), devices=jax.devices("cpu")[:4], fused=False,
-            host_final_exp=False, sharded=True, sharded_min_batch=8,
+            sharded=True, sharded_min_batch=8,
         )
-        key = (8, False, False)
+        key = (8, False)
         mk = v._mesh_memo_key(key)
-        stub = lambda *a: np.True_  # noqa: E731
+        stub = split_program()
         with _PROGRAM_MEMO_LOCK:
             _PROGRAM_MEMO[mk] = stub
         try:
@@ -594,11 +667,11 @@ class TestShardedEntryEquivalence:
         return final_exponentiation(fq12)
 
     def test_sharded_verdict_matches_single_chip(self):
-        """The full sharded entry over a 2-device mesh agrees with the
-        single-chip kernel — valid sets verify, one corrupted signature
-        flips the verdict, and the split entries' Miller products reduce
-        to the SAME GT element (the identity, for a valid batch) under
-        the final exponentiation."""
+        """The split mesh program over a 2-device mesh, finished by the
+        host final exponentiation, agrees with the single-chip kernel —
+        valid sets verify, one corrupted signature flips the verdict, and
+        the two Miller products reduce to the SAME GT element (the
+        identity, for a valid batch) under the final exponentiation."""
         import jax
 
         from lodestar_tpu.ops import batch_verify as bv
@@ -608,17 +681,22 @@ class TestShardedEntryEquivalence:
         args[6] = np.array([True, True, True, False])  # padding lane
         args = tuple(args)
         mesh = sv.make_mesh(n_devices=2)
-        full = jax.jit(sv.verify_signature_sets_sharded(mesh, fused=False))
-        assert bool(full(*args)) is True
-        single = jax.jit(bv.verify_signature_sets_kernel)
-        assert bool(single(*args)) is True
+        split = jax.jit(sv.miller_product_sharded(mesh, fused=False))
+        single = jax.jit(bv.miller_product_kernel)
+
+        def verdict(fn, batch):
+            f, ok = fn(*batch)
+            return bool(ok) and self._reduced(f).is_one()
+
+        assert verdict(split, args) is True
+        assert verdict(single, args) is True
         bad = list(args)
         bad[2] = np.array(bad[2])
         bad[2][0, 0, 0] += 1
-        assert bool(full(*tuple(bad))) is False
-        split = jax.jit(sv.miller_product_sharded(mesh, fused=False))
+        assert verdict(split, tuple(bad)) is False
+        assert verdict(single, tuple(bad)) is False
         f_sh, ok_sh = split(*args)
-        f_1, ok_1 = jax.jit(bv.miller_product_kernel)(*args)
+        f_1, ok_1 = single(*args)
         assert bool(ok_sh) and bool(ok_1)
         r_sh, r_1 = self._reduced(f_sh), self._reduced(f_1)
         assert r_sh.is_one() and r_1.is_one()  # same host verdict: True

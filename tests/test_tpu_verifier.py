@@ -23,6 +23,8 @@ import random
 
 import pytest
 
+from _stubs import split_program
+
 from lodestar_tpu.crypto.bls.native_verifier import FastBlsVerifier
 from lodestar_tpu.crypto.bls.api import (
     SecretKey,
@@ -55,10 +57,10 @@ def stub_verifier():
     program materializes (the compile-cost auditor proves this statically;
     the compile guard enforces it at runtime — this fixture is deliberately
     NOT in COMPILE_WHITELIST)."""
-    v = TpuBlsVerifier(buckets=(4, 8), fused=False, host_final_exp=False)
+    v = TpuBlsVerifier(buckets=(4, 8), fused=False)
     for ex in v._executors:
         for b in (4, 8):
-            ex.compiled[(b, False, False)] = lambda *a: True
+            ex.compiled[(b, False)] = split_program()
     yield v
     v.close()
 
@@ -253,7 +255,7 @@ class TestWarmupAot:
         dt = v.warmup()
         assert dt >= 0 and v.stage_seconds["warmup"] >= dt
         # the AOT executable (not a jit wrapper) serves the dispatch
-        key = (4, v.host_final_exp, v.fused)
+        key = (4, v.fused)
         assert key in v._compiled and not hasattr(v._compiled[key], "lower")
         assert v.verify_signature_sets(make_sets(2))
         v.close()

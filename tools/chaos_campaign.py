@@ -43,7 +43,6 @@ Scenarios (all driven from ONE seed; repro = rerun with the same seed):
 Usage:
     python tools/chaos_campaign.py --seed 0
     python tools/chaos_campaign.py --seed 7 --json
-    python bench.py        # runs this as the `chaos` stage
 
 Exit 0 when every scenario holds; 1 otherwise (failures listed).
 """
@@ -64,7 +63,7 @@ sys.path.insert(0, _REPO)
 
 # the stub executors need >= 4 virtual CPU devices; must be set before
 # the first jax import (a no-op when the host already forces them, e.g.
-# under tests/conftest.py or the bench multichip stage)
+# under tests/conftest.py)
 if "jax" not in sys.modules:
     _flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in _flags:
@@ -108,7 +107,8 @@ def load_tool(name: str):
 
 
 class _SlowVerdict:
-    """bool() blocks until ready_at — the device-readback stand-in."""
+    """bool() blocks until ready_at — the device-readback stand-in (the
+    subgroup verdict of a split program's output)."""
 
     def __init__(self, ready_at: float, value: bool = True):
         self._ready_at = ready_at
@@ -165,26 +165,31 @@ def stub_verifier(n_devices: int = 4, device_s: float = 0.01,
     mesh pseudo-executor as well, so the round-11 mesh tier routes for
     ``bucket``-sized merged batches with zero XLA work."""
     import jax
+    import numpy as np
 
     from lodestar_tpu.crypto.bls.tpu_verifier import TpuBlsVerifier
+    from lodestar_tpu.ops.tower import FQ12_ONE
 
     local = jax.devices("cpu")
     devices = local[: min(n_devices, len(local))] if n_devices > 1 else None
     v = TpuBlsVerifier(
-        buckets=(bucket,), devices=devices, fused=fused, host_final_exp=False,
+        buckets=(bucket,), devices=devices, fused=fused,
         quarantine_threshold=threshold, quarantine_backoff_s=backoff_s,
         native_verifier=_StubNative(),
         sharded=sharded or None, sharded_min_batch=bucket if sharded else None,
     )
+    one = np.asarray(FQ12_ONE, dtype=np.float32)
+
+    def program(*a):
+        # a split program's output: Miller product digits (one, so the
+        # host final exponentiation reads True) and the subgroup verdict
+        return one, _SlowVerdict(time.monotonic() + device_s)
+
     for ex in v._executors:
         for key_fused in ((False, True) if fused else (False,)):
-            ex.compiled[(bucket, False, key_fused)] = (
-                lambda *a: _SlowVerdict(time.monotonic() + device_s)
-            )
+            ex.compiled[(bucket, key_fused)] = program
     if sharded:
-        v._mesh_ex.compiled[(bucket, False, False)] = (
-            lambda *a: _SlowVerdict(time.monotonic() + device_s)
-        )
+        v._mesh_ex.compiled[(bucket, False)] = program
     return v
 
 
@@ -713,11 +718,13 @@ class _TinyKernelVerifier:
         import jax.numpy as jnp
 
         from lodestar_tpu.crypto.bls.tpu_verifier import TpuBlsVerifier
+        from lodestar_tpu.ops.tower import FQ12_ONE
 
-        v = TpuBlsVerifier(buckets=(4,), fused=False, host_final_exp=False,
-                           platform="cpu", aot_store=aot_store,
-                           native_verifier=_StubNative())
-        v._kernel = lambda key: (lambda *a: jnp.asarray(True))
+        v = TpuBlsVerifier(buckets=(4,), fused=False, platform="cpu",
+                           aot_store=aot_store, native_verifier=_StubNative())
+        v._kernel = lambda key: (
+            lambda *a: (jnp.asarray(FQ12_ONE, jnp.float32), jnp.asarray(True))
+        )
         return v
 
 
@@ -734,7 +741,7 @@ def _aot_midwrite_child(plan_json: str, store_dir: str) -> None:
 
     child_chaos.install(ChildPlan.from_json(plan_json))
     store = AotExecutableStore(path=store_dir)
-    store.save("xla_full", 4, "midwrite-victim", _tiny_compiled())
+    store.save("xla_split", 4, "midwrite-victim", _tiny_compiled())
     os._exit(7)  # plan did not fire: the parent treats this as a failure
 
 
@@ -750,14 +757,14 @@ def scenario_aot_corrupt(seed: int, out_dir: str, inspect_bundle,
     compiled = _tiny_compiled()
 
     # -- corrupt entry: checksum rejection + quarantine ----------------------
-    key = store.save("xla_full", 4, "default", compiled)
+    key = store.save("xla_split", 4, "default", compiled)
     if key is None:
         failures.append("store save failed (scenario setup)")
     else:
         fpath = os.path.join(store_dir, store.keys()[key]["file"])
         res["flipped_offsets"] = corrupt_file(fpath, seed=seed)[:8]
         fresh = AotExecutableStore(path=store_dir)
-        if fresh.load("xla_full", 4, "default") is not None:
+        if fresh.load("xla_split", 4, "default") is not None:
             failures.append("corrupt store entry still loaded")
         if fresh.corrupt != 1:
             failures.append("corrupt entry not counted as corrupt")
@@ -765,14 +772,14 @@ def scenario_aot_corrupt(seed: int, out_dir: str, inspect_bundle,
             failures.append("corrupt entry was not quarantined aside")
 
     # -- jax-version skew: eviction ------------------------------------------
-    key2 = store.save("fused_full", 4, "default", compiled)
+    key2 = store.save("fused_split", 4, "default", compiled)
     if key2 is not None:
         mpath = os.path.join(store_dir, "manifest.json")
         doc = json.load(open(mpath))
         doc["entries"][key2]["jax"] = "0.0.0-skewed"
         json.dump(doc, open(mpath, "w"))
         skewed = AotExecutableStore(path=store_dir)
-        if skewed.load("fused_full", 4, "default") is not None:
+        if skewed.load("fused_split", 4, "default") is not None:
             failures.append("version-skewed entry still loaded")
         if skewed.skew != 1:
             failures.append("skewed entry not counted as skew")
@@ -811,7 +818,7 @@ def scenario_aot_corrupt(seed: int, out_dir: str, inspect_bundle,
     if not orphans:
         failures.append("no orphan temp file from the killed writer")
     after_kill = AotExecutableStore(path=kill_dir)
-    if after_kill.load("xla_full", 4, "midwrite-victim") is not None:
+    if after_kill.load("xla_split", 4, "midwrite-victim") is not None:
         failures.append("half-written entry was loadable")
     if after_kill.corrupt:
         failures.append("orphan temp misclassified as corruption (must be a plain miss)")
@@ -873,7 +880,7 @@ def scenario_aot_corrupt(seed: int, out_dir: str, inspect_bundle,
 
 def _kill_child(plan_json: str, stage: str, base_dir: str) -> None:
     """Spawn-child entry for the bench-kill scenario: heartbeat once,
-    then die the way a wedged bench stage does (SIGKILL from outside has
+    then die the way a wedged stage child does (SIGKILL from outside has
     the same observable shape as this in-process one)."""
     import os as _os
 

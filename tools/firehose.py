@@ -17,8 +17,7 @@ sets/sec for a sustained window, and reports what the node would feel:
   (``network/gossip.sheddable_topic``), so an overload run shows intake
   slowing instead of the queue growing without bound.
 
-Every bench stage before this one was a throughput one-shot; this is the
-harness that measures the node under SUSTAINED load and proves the
+This is the harness that measures the node under SUSTAINED load and proves the
 overload machinery (lanes / deadline shedding / eviction / backpressure,
 docs/overload.md) actually survives offered load > capacity.
 
@@ -28,9 +27,8 @@ Usage (stub verifier, ~1M-validator storm shape):
     python tools/firehose.py --rate 5000 --seconds 10 --deadline-ms 500
     python tools/firehose.py --verifier native --rate 300 --seconds 5
 
-``bench.py``'s ``firehose`` stage drives ``run_firehose`` in-process to
-publish sustained sets/sec at a p99 queue-wait SLO plus an induced
-overload run; ``tests/test_overload.py`` runs a seconds-scale smoke.
+``tests/test_overload.py`` drives ``run_firehose`` in-process as a
+seconds-scale smoke.
 """
 
 from __future__ import annotations

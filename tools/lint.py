@@ -32,8 +32,7 @@ Usage:
                          [--skip-pallas] [--buckets 4,128] [--rules]
 
 Exit 0 when clean; exit 1 listing the violations.  tier-1 drives the same
-layers from tests/test_static_analysis.py; bench.py runs this as a
-pre-flight stage and records violations in extras.
+layers from tests/test_static_analysis.py.
 """
 
 from __future__ import annotations

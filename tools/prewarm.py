@@ -66,8 +66,7 @@ FARM_LOCK_NAME = "prewarm.lock"
 
 
 def prewarm(store_path: str, buckets, n_devices: int = 1,
-            fused: Optional[bool] = None, host_final_exp: bool = True,
-            lock_wait_s: float = 2.0, mesh: bool = False) -> Dict[str, Any]:
+            fused: Optional[bool] = None, lock_wait_s: float = 2.0, mesh: bool = False) -> Dict[str, Any]:
     """Populate ``store_path`` for this host's topology.  Returns the
     report dict; ``{"locked": True}`` when another prewarmer holds the
     farm lock (the caller exits 3 — never a stampede).
@@ -134,7 +133,7 @@ def prewarm(store_path: str, buckets, n_devices: int = 1,
             # the requested buckets ARE the eligible set (min = smallest)
             v = TpuBlsVerifier(
                 buckets=tuple(buckets), devices=devices,
-                fused=fused, host_final_exp=host_final_exp, aot_store=store,
+                fused=fused, aot_store=store,
                 sharded=True, sharded_min_batch=min(buckets),
             )
             wall = v.warmup_sharded()
@@ -147,7 +146,7 @@ def prewarm(store_path: str, buckets, n_devices: int = 1,
         else:
             v = TpuBlsVerifier(
                 buckets=tuple(buckets), devices=devices,
-                fused=fused, host_final_exp=host_final_exp, aot_store=store,
+                fused=fused, aot_store=store,
             )
             wall = v.warmup()
         return {
@@ -198,7 +197,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="build the ONE mesh-spanning sharded program per "
                     "bucket (stored under the mesh{k} key) instead of the "
                     "per-ordinal fan-out; requires --devices >= 2 or 0")
-    ap.add_argument("--host-final-exp", choices=("on", "off"), default="on")
     ap.add_argument("--lock-wait-s", type=float, default=2.0,
                     help="bounded wait for the farm lock before exiting 3")
     ap.add_argument("--verify", action="store_true",
@@ -230,7 +228,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     fused = None if args.fused == "auto" else args.fused == "on"
     report = prewarm(
         store_path, buckets, n_devices=args.devices, fused=fused,
-        host_final_exp=args.host_final_exp == "on",
         lock_wait_s=args.lock_wait_s, mesh=args.mesh,
     )
     if report.get("locked"):
