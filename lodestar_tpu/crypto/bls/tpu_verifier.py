@@ -1562,11 +1562,20 @@ class TpuBlsVerifier:
             # covers the async enqueue only (plus compile when cold); the
             # device compute itself surfaces as the gap before final_exp.
             # device/devices_total let tools/check_trace.py assert a
-            # multi-device dump actually spread across the pool
+            # multi-device dump actually spread across the pool;
+            # ladder_row_iters is the fused G2 ladder's work, summed over
+            # a mesh batch's shards
+            ladder = {}
+            if used_fused:
+                from ...ops import fused_verify as fv
+
+                shards = self.n_devices if sharded else 1
+                ladder["ladder_row_iters"] = shards * fv.ladder_row_iters(
+                    n // shards, packed[5].shape[-1])
             TRACER.add_span("bls.dispatch", "bls", t0_ns,
                             cid=cid, bucket=n, fused=used_fused,
                             device=ex.name, devices_total=self.n_devices,
-                            **mesh)
+                            **mesh, **ladder)
         # flight recorder: placement decision into the black box, the
         # batch into the in-flight table the watchdog scans — resolved by
         # the same exactly-once path that returns the executor slot, so a

@@ -1,11 +1,12 @@
 """Fused G2 ladder iteration: the complete double-and-add step in 3 Pallas
 kernels + one canonical reduction.
 
-Why: the phase probes put the merged 128-iteration G2 ladder at ~160 ms of
-the ~340 ms fused dispatch — ~13 kernel calls per iteration (6 add-core
-rounds, 2x3 double rounds, 1 canonical reduction) with ~10 XLA glue ops
-between every pair.  Per-call launch + glue overhead (~100 us effective)
-dwarfs the MXU compute.  This module re-partitions the SAME formulas
+Why: the merged 128-iteration G2 ladder is the device program's largest
+loop (about 318 ms of the 561 ms bucket-256 program on a TPU v5e) —
+~13 kernel calls per iteration (6 add-core rounds, 2x3 double rounds,
+1 canonical reduction) with ~10 XLA glue ops between every pair.
+Per-call launch + glue overhead (~100 us effective) dwarfs the MXU
+compute.  This module re-partitions the SAME formulas
 (fused_points.point_add_complete / point_double — identical algebra and
 edge-case semantics) into three multiply-round kernels whose inter-round
 glue (sums, doublings, subtraction pads) runs IN-KERNEL, leaving only the
@@ -20,9 +21,20 @@ predicate reduction and the select ladder in XLA:
 Inter-kernel arrays are semi-strict (m_fold on every kernel exit), so the
 scan carry is bound-stable by construction.  Differentially tested against
 fused_points.point_mul_bits in tests/test_fused_ladder.py.
+
+The merged ladder's lanes have scalars of different lengths (64-bit
+curve-parameter and RLC lanes beside the 128-bit cofactor lane).
+``point_mul_bits_lanes`` runs the schedule of ``ladder_schedule``: every
+lane through the short scalars' length, then the long lanes alone — an
+LSB-first ladder past a scalar's last set bit only re-selects its
+accumulator, so every limb matches the single scan.
 """
 
 from __future__ import annotations
+
+from typing import Sequence, Tuple
+
+import numpy as np
 
 import jax.numpy as jnp
 from jax import lax
@@ -255,18 +267,15 @@ def _ladder_step(acc, addend, bit, ns: FNS, interpret):
     )
 
 
-def point_mul_bits_ladder(
-    p: Point, bits: jnp.ndarray, ns: FNS, interpret=None
-) -> Point:
-    """[k]P over the fused complete ladder — fq2 ns only; the drop-in for
-    fused_points.point_mul_bits(..., complete=True) on the G2 path."""
-    assert ns.comp_ndim == 2, "fused ladder is the G2 path"
-    nbits = bits.shape[-1]
-    # the kernels grid over a FLAT row axis: collapse any leading lane/set
-    # axes (the merged 4-lane ladder arrives as (4, N, 2, 50))
+def _ladder_scan(acc, addend, bits, ns: FNS, interpret):
+    """Run the ladder over the iterations of ``bits`` (..., iters) from the
+    (acc, addend) carry, coordinates (..., 2, 50); returns the final
+    carry.  The kernels grid over a FLAT row axis, so any leading lane/set
+    axes collapse for the scan."""
     lead = bits.shape[:-1]
-    bits_f = bits.reshape((-1, nbits))
-    acc0 = point_infinity(ns, batch_shape=(bits_f.shape[0],))
+    iters = bits.shape[-1]
+    bits_f = bits.reshape((-1, iters))
+    flat = lambda pt: tuple(c.reshape((-1, 2, NL)) for c in pt)  # noqa: E731
 
     def body(carry, i):
         acc_a, add_a = carry
@@ -274,16 +283,114 @@ def point_mul_bits_ladder(
         acc_a, add_a = _ladder_step(acc_a, add_a, bit, ns, interpret)
         return (acc_a, add_a), None
 
+    carry, _ = lax.scan(body, (flat(acc), flat(addend)), jnp.arange(iters))
+    return tuple(
+        tuple(c.reshape(lead + (2, NL)) for c in pt) for pt in carry
+    )
+
+
+def _ladder_start(p: Point, lead, ns: FNS, interpret):
+    """(acc, addend) before the first iteration: infinity and P, both as
+    raw (*lead, 2, 50) coordinates."""
     # entry coordinates may carry loose bounds; one fold normalizes them
     from .fused_core import f_fold
 
+    acc0 = point_infinity(ns, batch_shape=lead)
     p0 = tuple(
-        jnp.broadcast_to(f_fold(c, interpret).a, lead + (2, NL)).reshape(
-            (-1, 2, NL)
+        jnp.broadcast_to(f_fold(c, interpret).a, lead + (2, NL)) for c in p
+    )
+    return tuple(c.a for c in acc0), p0
+
+
+def point_mul_bits_ladder(
+    p: Point, bits: jnp.ndarray, ns: FNS, interpret=None
+) -> Point:
+    """[k]P over the fused complete ladder — fq2 ns only; the drop-in for
+    fused_points.point_mul_bits(..., complete=True) on the G2 path."""
+    assert ns.comp_ndim == 2, "fused ladder is the G2 path"
+    lead = bits.shape[:-1]
+    acc, _ = _ladder_scan(*_ladder_start(p, lead, ns, interpret), bits, ns, interpret)
+    return tuple(lv(a) for a in acc)
+
+
+Phase = Tuple[Tuple[int, ...], int]
+
+
+def _blocks(rows: int) -> int:
+    return -(-rows // LAD_BLK)
+
+
+def ladder_schedule(lane_bits: Sequence, n: int) -> Tuple[Phase, ...]:
+    """The phases of the merged ladder over ``lane_bits`` at ``n`` rows a
+    lane: ((lanes, iterations), ...), each phase continuing the carry of
+    the one before.
+
+    ``lane_bits[l]`` is lane l's scalar, LSB first: a numpy (w,) array
+    that every row shares (a static scalar, which ends at its highest set
+    bit) or an (n, w) array of per-row bits (which end at w).  The cut is
+    the longest static scalar that ends before the longest lane; per-row
+    scalars must end by it, so the schedule rests on the static scalars
+    alone and a wider per-row scalar raises here, at trace time, instead
+    of being truncated.  The run of lanes from the first to the last that
+    pass the cut goes on alone where its rows fill fewer LAD_BLK row
+    blocks than every lane's rows; elsewhere (the rows fit one block
+    either way) the single phase saves a second set of kernel compiles."""
+    static = [len(b.shape) == 1 for b in lane_bits]
+    lens = [
+        int(np.max(np.flatnonzero(b), initial=-1)) + 1 if s else b.shape[-1]
+        for b, s in zip(lane_bits, static)
+    ]
+    nbits = max(b.shape[-1] for b in lane_bits)
+    cut = max((k for k, s in zip(lens, static) if s and k < max(lens)), default=nbits)
+    late = [l for l, k in enumerate(lens) if k > cut]
+    for l in late:
+        if not static[l]:
+            raise ValueError(
+                f"lane {l}: per-row bits of width {lens[l]} run past the "
+                f"ladder's cut at {cut} bits (the static scalars' schedule)"
+            )
+    every = tuple(range(len(lane_bits)))
+    if not late:
+        return ((every, nbits),)
+    late = tuple(range(late[0], late[-1] + 1))
+    if _blocks(len(late) * n) >= _blocks(len(every) * n):
+        return ((every, nbits),)
+    return ((every, cut), (late, nbits - cut))
+
+
+def schedule_row_iters(schedule: Sequence[Phase], n: int) -> int:
+    """Row-iterations a schedule runs at ``n`` rows a lane."""
+    return sum(len(lanes) * n * iters for lanes, iters in schedule)
+
+
+def point_mul_bits_lanes(
+    p: Point, lane_bits: Sequence, ns: FNS, interpret=None
+) -> Tuple[Point, ...]:
+    """[k_l]P_l for each lane l of the stacked points ``p`` (coordinates
+    (L, n, 2, 50)), with ``lane_bits`` as ``ladder_schedule`` takes them.
+    Returns one (n, 2, 50) point per lane, limb for limb the lane of
+    point_mul_bits_ladder over the bits zero-padded to the longest."""
+    assert ns.comp_ndim == 2, "fused ladder is the G2 path"
+    n = p[0].a.shape[1]
+    phases = ladder_schedule(lane_bits, n)
+    nbits = sum(iters for _, iters in phases)
+    bits = jnp.stack([
+        jnp.broadcast_to(jnp.asarray(np.pad(b, (0, nbits - b.shape[-1]))), (n, nbits))
+        if len(b.shape) == 1
+        else jnp.pad(b.astype(jnp.float32), ((0, 0), (0, nbits - b.shape[-1])))
+        for b in lane_bits
+    ])  # (L, n, nbits)
+    acc, addend = _ladder_start(p, bits.shape[:-1], ns, interpret)
+    (_, cut), *rest = phases
+    acc, addend = _ladder_scan(acc, addend, bits[..., :cut], ns, interpret)
+    out = [tuple(lv(c[l]) for c in acc) for l in range(len(lane_bits))]
+    if rest:
+        ((late, _),) = rest
+        lo, hi = late[0], late[-1] + 1
+        acc, _ = _ladder_scan(
+            tuple(c[lo:hi] for c in acc), tuple(c[lo:hi] for c in addend),
+            bits[lo:hi, :, cut:], ns, interpret,
         )
-        for c in p
-    )
-    (acc_a, _), _ = lax.scan(
-        body, (tuple(c.a for c in acc0), p0), jnp.arange(nbits)
-    )
-    return tuple(lv(a.reshape(lead + (2, NL))) for a in acc_a)
+        for l in late:
+            out[l] = tuple(lv(c[l - lo]) for c in acc)
+    return tuple(out)

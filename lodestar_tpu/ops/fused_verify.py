@@ -7,12 +7,14 @@ behind the reference worker pool, chain/bls/multithread/index.ts:39), but
 built on the fused Pallas kernel core, engineered for serial kernel-call
 count:
 
-- ONE merged 128-iteration complete-adder G2 ladder carries four scalar
+- ONE merged complete-adder G2 ladder carries four scalar
   multiplications per set at once on stacked lanes: the signature subgroup
   check ([z]sig), both Budroni-Pintore cofactor terms ([z^2-z-1]H and
   [z-1]psi(H)), and the RLC signature scaling ([c_i]sig) — replacing
   three separate ladders (64+128+64 iterations) plus their per-iteration
-  overhead.
+  overhead.  Only [z^2-z-1]H has bits past 64: where its rows alone fill
+  fewer row blocks (buckets 64 and up), iterations 64-127 run on its
+  rows alone (fused_ladder.ladder_schedule).
 - ONE merged Fermat inversion canonicalizes every affine conversion: the
   G2 z-norms (N+1 points) and the G1 z coordinates (N points) share a
   single windowed pow scan.
@@ -51,6 +53,7 @@ from .fused_core import (
 )
 from .fused_field import f2_is_zero, fi_inv
 from .fused_htc import hash_to_g2_pre_cofactor
+from .fused_ladder import ladder_schedule, point_mul_bits_lanes, schedule_row_iters
 from .fused_pairing import multi_miller_product
 from .fused_points import (
     G1_GEN_NEG_AFFINE,
@@ -88,6 +91,21 @@ _L0_BITS = _bits_lsb(_Z_ABS)
 _L1_BITS = _bits_lsb(BLS_X * BLS_X - BLS_X - 1)
 # lane 2: [z - 1]psi(H) as [|z - 1|](-psi(H))
 _L2_BITS = _bits_lsb(abs(BLS_X - 1))
+
+
+def _lane_bits(coeff_bits) -> tuple:
+    """The merged G2 ladder's four lanes' scalars, as
+    fused_ladder.ladder_schedule takes them: three static curve-parameter
+    scalars and the per-row RLC coefficient bits."""
+    return (_L0_BITS, _L1_BITS, _L2_BITS, coeff_bits)
+
+
+def ladder_row_iters(n: int, coeff_width: int) -> int:
+    """Row-iterations of the merged G2 ladder at ``n`` sets with
+    ``coeff_width``-bit RLC coefficients, from the schedule the program
+    traces."""
+    lanes = _lane_bits(np.empty((n, coeff_width), np.float32))
+    return schedule_row_iters(ladder_schedule(lanes, n), n)
 
 
 def _neg_point(p: Point) -> Point:
@@ -141,7 +159,7 @@ def miller_product_parts(
     with named_scope("hash_to_g2"):
         h_pre = hash_to_g2_pre_cofactor(lv(msg_u), interpret)
 
-    # --- the merged G2 ladder: 4 lanes per set, one 128-iteration scan ---
+    # --- the merged G2 ladder: 4 lanes per set, scheduled by scalar length ---
     with named_scope("g2_ladder"):
         psi_h = psi(h_pre, interpret)
         lanes = [
@@ -151,23 +169,9 @@ def miller_product_parts(
             sig_jac,  # RLC scaling
         ]
         stacked = tuple(lstack([lane[i] for lane in lanes], axis=0) for i in range(3))
-        cb = jnp.pad(coeff_bits.astype(jnp.float32), ((0, 0), (0, _NBITS - coeff_bits.shape[-1])))
-        bits = jnp.stack(
-            [
-                jnp.broadcast_to(jnp.asarray(_L0_BITS), (n, _NBITS)),
-                jnp.broadcast_to(jnp.asarray(_L1_BITS), (n, _NBITS)),
-                jnp.broadcast_to(jnp.asarray(_L2_BITS), (n, _NBITS)),
-                cb,
-            ],
-            axis=0,
-        )  # (4, N, 128)
-        from .fused_ladder import point_mul_bits_ladder
-
-        out = point_mul_bits_ladder(stacked, bits, ns2, interpret=interpret)
-        z_sig = tuple(LV(c.a[0], c.b) for c in out)
-        t1 = tuple(LV(c.a[1], c.b) for c in out)
-        t2 = tuple(LV(c.a[2], c.b) for c in out)
-        sig_scaled = tuple(LV(c.a[3], c.b) for c in out)
+        z_sig, t1, t2, sig_scaled = point_mul_bits_lanes(
+            stacked, _lane_bits(coeff_bits), ns2, interpret=interpret
+        )
 
     # signature subgroup check: psi(sig) == [z]sig (infinity passes)
     with named_scope("subgroup_check"):

@@ -73,7 +73,7 @@ def make_sets(n, start=0):
 
 
 def sharded_stub_verifier(n_devices=4, bucket=8, mesh_program=None,
-                          pool_program=None, **kw):
+                          pool_program=None, fused=False, **kw):
     """Real TpuBlsVerifier (real pack, real routing, real spans) with
     host stubs in BOTH the mesh pseudo-executor and the per-device
     executors, so every tier of the ladder is dispatchable without XLA."""
@@ -81,9 +81,9 @@ def sharded_stub_verifier(n_devices=4, bucket=8, mesh_program=None,
 
     v = TpuBlsVerifier(
         buckets=(bucket,), devices=jax.devices("cpu")[:n_devices],
-        fused=False, sharded=True, sharded_min_batch=bucket, **kw,
+        fused=fused, sharded=True, sharded_min_batch=bucket, **kw,
     )
-    key = (bucket, False)
+    key = (bucket, fused)
     if mesh_program is None:
         mesh_program = split_program()
     v._mesh_ex.compiled[key] = mesh_program
@@ -202,6 +202,17 @@ class TestShardedDispatch:
         # the mesh slot returned on first result()
         assert v._mesh_ex.inflight == 0
         assert "mesh4" in v.executor_health()
+
+    def test_mesh_span_ladder_row_iters_sum_the_shards(self):
+        """A fused mesh batch's span counts every shard's ladder: bucket
+        256 on four chips is four 64-set shards, each 256 rows x 64
+        iterations and 64 cofactor rows x 64 more."""
+        v = sharded_stub_verifier(n_devices=4, bucket=256, fused=True)
+        TRACER.enable(512)
+        assert v.dispatch(v.pack(make_sets(2))).result() is True
+        (span,) = [s for s in TRACER.spans() if s.name == "bls.dispatch"]
+        assert span.args["sharded"] is True
+        assert span.args["ladder_row_iters"] == 4 * (256 * 64 + 64 * 64)
 
     def test_host_final_exp_once_per_mesh_batch(self):
         """The behavioral half of the final-exp-once pin: a mesh-wide

@@ -65,6 +65,14 @@ def stub_verifier():
     v.close()
 
 
+def fused_stub_verifier(bucket):
+    """A fused-tier verifier at one bucket whose program is a host stub."""
+    v = TpuBlsVerifier(buckets=(bucket,), fused=True)
+    for ex in v._executors:
+        ex.compiled[(bucket, True)] = split_program()
+    return v
+
+
 def make_sets(n, start=0):
     out = []
     for i in range(start, start + n):
@@ -152,6 +160,30 @@ class TestHostPath:
         pack0 = stub_verifier.stage_seconds["pack"]
         assert stub_verifier.verify_signature_sets(make_sets(2))
         assert stub_verifier.stage_seconds["pack"] > pack0
+
+    # the merged G2 ladder's row-iterations: 4 lanes x 128 iterations
+    # where every lane's rows fit one 64-row block, else 64 iterations on
+    # every lane and 64 more on the cofactor lane alone
+    @pytest.mark.parametrize("bucket,row_iters", [
+        (4, 4 * 4 * 128), (16, 4 * 16 * 128),
+        (64, 4 * 64 * 64 + 64 * 64), (256, 4 * 256 * 64 + 256 * 64),
+    ])
+    def test_dispatch_span_carries_ladder_row_iters(self, bucket, row_iters):
+        from lodestar_tpu.ops import fused_verify as fv
+        from lodestar_tpu.tracing import TRACER
+
+        v = fused_stub_verifier(bucket)
+        TRACER.clear()
+        TRACER.enable(64)
+        try:
+            assert v.verify_signature_sets(make_sets(2))
+            (span,) = [s for s in TRACER.spans() if s.name == "bls.dispatch"]
+        finally:
+            TRACER.disable()
+            TRACER.clear()
+            v.close()
+        assert span.args["bucket"] == bucket
+        assert span.args["ladder_row_iters"] == row_iters == fv.ladder_row_iters(bucket, 64)
 
 
 @pytest.mark.slow
